@@ -86,8 +86,6 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
 
-    from mxnet_tpu.util import honor_platform_env
-    honor_platform_env()  # JAX_PLATFORMS=cpu + virtual devices work
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -28,9 +28,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import numpy as np
 
-from mxnet_tpu.util import honor_platform_env
-honor_platform_env()  # respect JAX_PLATFORMS even under a sitecustomize
-
 import mxnet_tpu as mx
 from mxnet_tpu import fault, gluon, nd
 from mxnet_tpu.gluon import loss as gloss

@@ -185,12 +185,16 @@ env.declare("MXNET_CONV_COMPUTE", str, "",
 env.declare("MXNET_CONV_INT8_RANGE", float, 8.0,
             "Symmetric activation clip range for MXNET_CONV_COMPUTE=int8 "
             "(post-BN/ReLU activations are O(1); widen if a model clips).")
-env.declare("MXTPU_FUSED_EPILOGUE", bool, True,
-            "Route the fused conv-epilogue ops (_contrib_fused_bn_relu / "
-            "_contrib_fused_bn_add_relu) through the Pallas BN(+add)+ReLU "
-            "kernels (compiled on TPU, interpret mode elsewhere). Set 0 "
-            "to fall back to the composed unfused lowering. Read at "
-            "trace time — part of every op jit-cache key.")
+env.declare("MXTPU_FUSED_EPILOGUE", bool, False,
+            "Set 1 to route the fused conv-epilogue ops "
+            "(_contrib_fused_bn_relu / _contrib_fused_bn_add_relu) through "
+            "the Pallas BN(+add)+ReLU kernels (compiled on TPU, interpret "
+            "mode elsewhere) instead of the composed XLA lowering. Off by "
+            "default: a Mosaic kernel cannot be partitioned over a mesh "
+            "(SPMDTrainer(mesh=...) is refused by the compiler) and the "
+            "b256 ResNet-50 step needs 15.0 GB of temporaries against "
+            "8.5 GB composed on a 16 GB chip. Read at trace time — part "
+            "of every op jit-cache key.")
 env.declare("MXTPU_CACHEDOP_CACHE_SIZE", int, 256,
             "LRU bound on CachedOp's per-signature compiled-program cache "
             "(each entry is a full XLA executable). 0 = unbounded. "
@@ -212,10 +216,11 @@ env.declare("MXTPU_SERVE_REGISTRY", str, "",
             "pointer. Empty = <cwd>/registry.")
 env.declare("MXTPU_COMPILE_CACHE", str, "",
             "Persistent on-disk XLA compilation cache directory. "
-            "serving.enable_compile_cache honors it on every backend "
-            "(namespaced by jaxlib/backend fingerprint) so a replica "
-            "restart recompiles nothing; util.enable_compile_cache "
-            "(bench/tools) skips CPU unless this is set explicitly. "
+            "serving.enable_compile_cache honors it on every backend so a "
+            "replica restart recompiles nothing; util.enable_compile_cache "
+            "(scripts) defaults to <checkout>/.jax_cache and skips CPU "
+            "unless a directory is named. Where JAX_COMPILATION_CACHE_DIR "
+            "is set, JAX reads that and no directory is set in code. "
             "'0'/'off' disables.")
 env.declare("MXTPU_SERVE_REPLAY", str, "",
             "Signature-replay file: when set, ModelServer appends one "
@@ -452,10 +457,11 @@ env.declare("MXTPU_EFFICIENCY", str, "",
             "cached env check per hook. Unknown tokens raise.")
 env.declare("MXTPU_DEVICE_PEAK", str, "",
             "Device peak table for the efficiency plane: "
-            "'flops=<FLOP/s>,bw=<bytes/s>' (e.g. flops=73e12,bw=9e11). "
+            "'flops=<FLOP/s>,bw=<bytes/s>' (e.g. flops=197e12,bw=819e9). "
             "Strict parse — typos/partial tables raise at fit() start. "
-            "Empty = per-backend defaults, with every result marked "
-            "'estimate' on CPU (no meaningful host peak exists).")
+            "Empty = the published peaks of the device's device_kind "
+            "(telemetry/efficiency.py DEVICE_PEAKS); a kind that is not "
+            "in that table, the CPU included, gets no MFU.")
 env.declare("MXTPU_RUN_REPORT_DIR", str, "",
             "Directory fit.FitLoop writes one persistent run report "
             "into at fit end (run_<pid>_<ts>.json, tmp+rename, shared "

@@ -895,7 +895,7 @@ def make_scan_forward(block, training: bool = False):
 
     The inference-side analog of SPMDTrainer.run_steps: lax.scan replays
     the compiled forward K times per dispatch, amortizing per-dispatch
-    host/relay overhead — the serving pattern for batch scoring
+    host overhead — the serving pattern for batch scoring
     (ref: the engine's bulk-exec of inference graphs,
     MXNET_EXEC_BULK_EXEC_INFERENCE). The returned callable holds the
     compiled program; build it ONCE and reuse it (rebuilding re-traces).

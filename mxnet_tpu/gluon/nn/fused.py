@@ -4,10 +4,10 @@ The ResNet bottleneck hot path writes the conv output to HBM and then
 reads it back for BatchNorm statistics, again for the normalize, and the
 normalized copy again for the ReLU/add — the traffic docs/perf.md's
 roofline names as the training-step ceiling. These layers route the
-whole epilogue through the fused Pallas kernels
-(ops/pallas_kernels.py fused_bn_act via the _contrib_fused_bn_relu /
-_contrib_fused_bn_add_relu ops), gated at trace time by
-MXTPU_FUSED_EPILOGUE.
+whole epilogue through one op (_contrib_fused_bn_relu /
+_contrib_fused_bn_add_relu), which lowers to the composed XLA chain by
+default and to the fused Pallas kernels (ops/pallas_kernels.py
+fused_bn_act) where MXTPU_FUSED_EPILOGUE=1 at trace time.
 
 Both subclass BatchNorm so they hold the standard gamma/beta/running_*
 parameters and so graph passes that match

@@ -3,7 +3,7 @@
 Reference: src/storage/pinned_memory_storage.h + iter_prefetcher.h — the
 reference stages batches through pinned host buffers so H2D DMA overlaps
 compute. The TPU-native analog: start the (async) `jax.device_put` of
-batch k+1 while the trainer computes on batch k, so the PCIe/relay
+batch k+1 while the trainer computes on batch k, so the PCIe
 transfer hides behind the step instead of serializing in front of it.
 
 ``DeviceStagingIter`` wraps any DataIter; batches come out as NDArrays

@@ -54,11 +54,6 @@ def _dt(config):
     return config.dtype or jnp.float32
 
 
-def _on_tpu() -> bool:
-    import jax
-    return jax.devices()[0].platform == "tpu"
-
-
 def init_params(key, config: TransformerConfig) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
@@ -168,13 +163,15 @@ def _block(x, lp, config: TransformerConfig, mesh, act_spec):
     q = q.reshape(b, t, h, hd)
     k = k.reshape(b, t, h, hd)
     v = v.reshape(b, t, h, hd)
+    from ..ops.pallas_kernels import _interpret_for, flash_attention
     from ..parallel.ring_attention import attention, ring_attention
     if mesh is not None and "sp" in mesh.axis_names and \
             dict(zip(mesh.axis_names, mesh.devices.shape))["sp"] > 1:
         attn = ring_attention(q, k, v, mesh, axis="sp", causal=config.causal)
-    elif _on_tpu() and t % 128 == 0 and hd >= 64:
-        # single-chip hot path: fused Pallas attention (no (T,T) in HBM)
-        from ..ops.pallas_kernels import flash_attention
+    elif not _interpret_for(q) and t % 128 == 0 and hd >= 64:
+        # single-chip hot path: fused Pallas attention (no (T,T) in HBM),
+        # taken exactly where the kernel runs compiled — the one platform
+        # rule of ops/pallas_kernels.py; interpret mode would only be slow
         attn = flash_attention(q, k, v, causal=config.causal)
     else:
         attn = attention(q, k, v, causal=config.causal)

@@ -383,8 +383,9 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
 # ops/pallas_kernels.py). The separate BatchNorm/add/Activation ops leave
 # XLA free to materialize the intermediate activations between them —
 # measured as the dominant HBM traffic of the ResNet-50 train step
-# (docs/perf.md roofline). MXTPU_FUSED_EPILOGUE=0 (trace-time flag, part
-# of every jit-cache key) falls back to the composed unfused lowering.
+# (docs/perf.md roofline). The kernels are opt-in: MXTPU_FUSED_EPILOGUE=1
+# (trace-time flag, part of every jit-cache key); the default is the
+# composed unfused lowering, which XLA can partition over a mesh.
 # ---------------------------------------------------------------------------
 
 def _fused_epilogue_enabled() -> bool:
@@ -404,7 +405,7 @@ def _fused_bn_act_impl(data, residual, gamma, beta, moving_mean, moving_var,
         if ax == data.ndim - 1 and is_float and _fused_epilogue_enabled():
             from .pallas_kernels import fused_bn_act
             return fused_bn_act(data, residual, g32, b32, float(eps))
-        # composed fallback: exactly the unfused BatchNorm -> (add) ->
+        # composed lowering: exactly the unfused BatchNorm -> (add) ->
         # ReLU chain, including the fp8-residual lowering of each piece
         from . import resid8
         rdt = resid8.resid_dtype() if is_float else None

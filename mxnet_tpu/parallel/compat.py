@@ -1,75 +1,21 @@
-"""jax API compatibility shims for the parallel subsystem.
+"""The one place the parallel subsystem takes ``shard_map`` from.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-``jax.shard_map`` top-level alias (and renamed ``check_rep`` ->
-``check_vma``, ``auto=frozenset`` -> ``axis_names=set``) across jax
-releases; the container may carry either vintage. Every in-tree user goes
-through :func:`shard_map` here, which presents the NEW calling convention
-(``check_vma``/``axis_names``) and translates down when only the
-experimental API exists.
+Every in-tree user goes through :func:`shard_map` here: ``jax.shard_map``
+with the mesh, specs, ``check_vma`` and ``axis_names`` (the subset of mesh
+axes mapped manually) passed by keyword, and the two optional ones left
+at jax's defaults when not given.
 """
 from __future__ import annotations
 
-import inspect
-
-from ..base import MXNetError
-
-__all__ = ["shard_map", "HAVE_SHARD_MAP"]
-
-
-def _resolve():
-    import jax
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, True
-    try:
-        from jax.experimental.shard_map import shard_map as fn
-        return fn, False
-    except ImportError:
-        return None, False
-
-
-_FN, _IS_MODERN = _resolve()
-HAVE_SHARD_MAP = _FN is not None
-# probe the rep-check kwarg name once: a per-call inspect.signature would
-# tax every pipeline step for a property of the jax build that never changes
-_MODERN_CHECK_KW = None
-_MODERN_HAS_AXIS_NAMES = False
-if _IS_MODERN:
-    _params = inspect.signature(_FN).parameters
-    _MODERN_CHECK_KW = ("check_vma" if "check_vma" in _params
-                        else "check_rep")
-    # the check_rep vintage of the top-level alias also predates
-    # axis_names= (it takes auto=) — probe both independently
-    _MODERN_HAS_AXIS_NAMES = "axis_names" in _params
+__all__ = ["shard_map"]
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=None, axis_names=None):
-    """``jax.shard_map`` with graceful fallback to the experimental API.
-
-    ``axis_names`` (modern): the subset of mesh axes mapped manually; the
-    experimental equivalent is ``auto = all_axes - axis_names``.
-    ``check_vma`` (modern) maps to the experimental ``check_rep``.
-    """
-    if _FN is None:
-        raise MXNetError(
-            "this jax build provides neither jax.shard_map nor "
-            "jax.experimental.shard_map.shard_map — multi-device "
-            "shard_map collectives are unavailable")
+    import jax
     kwargs = {}
-    if _IS_MODERN:
-        if check_vma is not None:
-            kwargs[_MODERN_CHECK_KW] = check_vma
-        if axis_names is not None:
-            if _MODERN_HAS_AXIS_NAMES:
-                kwargs["axis_names"] = set(axis_names)
-            else:
-                kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-        return _FN(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kwargs)
     if check_vma is not None:
-        kwargs["check_rep"] = check_vma
+        kwargs["check_vma"] = check_vma
     if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _FN(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kwargs)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

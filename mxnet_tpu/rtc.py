@@ -23,11 +23,6 @@ from .base import MXNetError, check
 __all__ = ["PallasModule", "PallasKernel", "CudaModule"]
 
 
-def _interpret_for(x) -> bool:
-    from .ops.pallas_kernels import _interpret_for as probe
-    return probe(x)
-
-
 class PallasKernel:
     """A launchable kernel (ref: rtc.py CudaKernel).
 
@@ -91,11 +86,12 @@ class PallasKernel:
                                                        (list, tuple)):
             args = [args]
         arrs = [a._data if isinstance(a, NDArray) else a for a in args]
+        from .ops.pallas_kernels import _interpret_for
         grid = tuple(grid_dims) if grid_dims else (self._grid or ())
         jitted = self._compiled(tuple(a.shape for a in arrs),
                                 tuple(str(a.dtype) for a in arrs),
                                 tuple(grid),
-                                _interpret_for(arrs[0]) if arrs else True)
+                                _interpret_for(arrs[0] if arrs else None))
         out = jitted(*arrs)
         if isinstance(out, (list, tuple)):
             return [from_jax(o) for o in out]

@@ -8,8 +8,6 @@ without compiling anything:
 1. **Persistent compile cache** (:func:`enable_compile_cache`): jax's
    on-disk compilation cache, keyed by (program, jaxlib version, backend) —
    a recompile of a signature any previous process compiled is a disk read.
-   The cache directory is namespaced by :func:`runtime_fingerprint` so a
-   jaxlib upgrade starts a fresh cache instead of colliding.
 2. **AOT executable bundles**: ``CachedOp.aot_export`` serializes the
    compiled executables of the closed ``bucket_shapes x batch-bucket``
    signature set (``jax.experimental.serialize_executable``); published
@@ -53,60 +51,38 @@ def runtime_fingerprint() -> dict:
         return {"jax": "none", "jaxlib": "none", "backend": "none"}
 
 
-def fingerprint_token(fp: Optional[dict] = None) -> str:
-    """Filesystem-safe string form of the fingerprint (cache subdir key)."""
-    fp = fp or runtime_fingerprint()
-    return "-".join(str(fp.get(k, "none")).replace("/", "_")
-                    for k in ("jaxlib", "backend"))
-
-
 def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Wire jax's persistent on-disk compilation cache for serving.
+    """Wire jax's persistent on-disk compilation cache.
 
-    Resolution order: explicit ``cache_dir`` > ``MXTPU_COMPILE_CACHE`` env.
-    Returns the effective cache directory (namespaced by the runtime
-    fingerprint), or None when disabled (no dir configured, or an explicit
-    ``0``/``off``). Every compile-time knob is forced to cache-everything
-    (min compile time / entry size 0): a serving replica's goal is zero
-    compile seconds on restart, not disk thrift. This is also the ONE
-    wiring implementation: ``util.enable_compile_cache`` (bench/tools)
-    delegates here after applying its own policy (default repo-wide dir,
-    CPU skipped unless the variable is set explicitly); the serving path
-    honors an explicitly configured cache on every backend — the
-    cold-start contract must be testable on CPU CI.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache is placed from
+    outside: JAX reads the variable itself and no directory is set here.
+    Otherwise explicit ``cache_dir`` > ``MXTPU_COMPILE_CACHE``, and with
+    neither the cache stays off. An explicit ``0``/``off`` switches this
+    call off either way. Returns the directory in effect, or None.
+
+    Every compile-time knob is forced to cache-everything (min compile
+    time / entry size 0): a serving replica's goal is zero compile seconds
+    on restart, not disk thrift. This is the ONE wiring implementation:
+    ``util.enable_compile_cache`` (scripts) delegates here after applying
+    its own policy (default ``<checkout>/.jax_cache``, CPU skipped unless a
+    directory is named); the serving path honors a configured cache on
+    every backend — the cold-start contract must be testable on CPU CI.
+    The directory is used as given: jax's cache key already holds the
+    jaxlib version, the platform and the device kind.
     """
-    if cache_dir is None:
-        cache_dir = env.get("MXTPU_COMPILE_CACHE")
-    if not cache_dir or str(cache_dir).lower() in ("0", "off", "disabled",
-                                                   "none"):
+    import jax
+    cache_dir = str(cache_dir or env.get("MXTPU_COMPILE_CACHE") or "")
+    if cache_dir.lower() in ("0", "off", "disabled", "none"):
         return None
-    try:
-        import jax
-        effective = os.path.join(str(cache_dir), fingerprint_token())
-        os.makedirs(effective, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", effective)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception:
-            pass  # knob absent on older jaxlibs
-        try:
-            # jax latches the cache object at the FIRST compile of the
-            # process; anything that compiled before this call (op
-            # registry warmup during import, a publish step) initialized
-            # it with no directory — leaving the cache silently disabled
-            # for the replica's whole life. Un-latch so the next compile
-            # re-initializes from the config we just set.
-            from jax._src import compilation_cache as _cc
-            if _cc._cache_initialized and _cc._cache is None:
-                _cc.reset_cache()
-        except Exception:
-            pass
-        _LOG.info("persistent compile cache at %s", effective)
-        return effective
-    except Exception as e:
-        _LOG.warning("compile cache unavailable: %s", e)
-        return None
+    if not env.raw("JAX_COMPILATION_CACHE_DIR"):
+        if not cache_dir:
+            return None
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    effective = jax.config.jax_compilation_cache_dir
+    _LOG.info("persistent compile cache at %s", effective)
+    return effective
 
 
 class ReplayLog:

@@ -2,7 +2,7 @@
 
 The serving value proposition of the reference's model server (MMS) is
 dynamic batching: concurrent single-example requests are coalesced into one
-model dispatch so per-dispatch fixed costs (host relay, XLA dispatch,
+model dispatch so per-dispatch fixed costs (host work, XLA dispatch,
 kernel launch) amortize. On TPU there is a second, sharper reason: XLA
 compiles one executable per input signature, so free-form request shapes
 mean a compile per shape. The batcher therefore maps every request into a

@@ -53,9 +53,10 @@ as are warm-step dispatch/launch counts.
 
 **Honest peaks**: the peak table comes from ``MXTPU_DEVICE_PEAK``
 (strict parse — a typo'd peak raises before step 0, never silently
-grades against garbage). Without it, per-backend defaults apply; on CPU
-(no meaningful peak exists) every result is marked ``estimate`` until
-the operator supplies real numbers.
+grades against garbage). Without it, the published peaks of the device's
+``device_kind`` apply (:data:`DEVICE_PEAKS`, each entry with its
+source); a kind that is not in the table — the CPU included — gets no
+MFU at all, only achieved FLOP/s and bytes/s.
 """
 from __future__ import annotations
 
@@ -177,14 +178,17 @@ def enabled() -> bool:
 # Device peak table (MXTPU_DEVICE_PEAK=flops=F,bw=B)
 # ---------------------------------------------------------------------------
 
-#: rough per-backend peaks used when the operator declares none.
-#: tpu: the one v5e chip this repo's bench measured (73 TFLOP/s
-#: demonstrated MXU peak, ~0.9 TB/s measured HBM stream — see
-#: docs/ROOFLINE.json); cpu/gpu: placeholders, always marked estimate.
-_DEFAULT_PEAKS = {
-    "tpu": (73.0e12, 900.0e9),
-    "gpu": (50.0e12, 1000.0e9),
-    "cpu": (1.0e11, 5.0e10),
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``,
+#: each with its source. A kind that is not here has no peak: its steps
+#: get achieved FLOP/s and bytes/s but no MFU — never a guess.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "flops": 197.0e12,      # bf16
+        "bw": 819.0e9,          # HBM bytes/s
+        "hbm_bytes": 16.0e9,
+        "source": "Google Cloud documentation, \"TPU v5e\": 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s per chip",
+    },
 }
 
 
@@ -228,9 +232,10 @@ _peak_cached: Optional[Tuple[Optional[str],
 
 def device_peak() -> Dict[str, Any]:
     """The active peak table: ``{"flops", "bw", "source", "estimate"}``.
-    ``MXTPU_DEVICE_PEAK`` wins (strict parse, ``estimate`` False);
-    otherwise the backend default applies and results are marked
-    ``estimate`` — a defaulted peak grades the trend, not the truth."""
+    ``MXTPU_DEVICE_PEAK`` wins (strict parse); otherwise the published
+    peaks of the default device's ``device_kind`` (:data:`DEVICE_PEAKS`).
+    A kind with no entry gives ``flops``/``bw`` None and ``estimate``
+    True: the rollup then reports no MFU at all."""
     global _peak_cached
     raw = env.raw("MXTPU_DEVICE_PEAK")
     c = _peak_cached
@@ -243,15 +248,26 @@ def device_peak() -> Dict[str, Any]:
     if parsed is not None:
         return {"flops": parsed[0], "bw": parsed[1], "source": "env",
                 "estimate": False}
-    backend = "cpu"
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        pass
-    flops, bw = _DEFAULT_PEAKS.get(backend, _DEFAULT_PEAKS["cpu"])
-    return {"flops": flops, "bw": bw, "source": f"default:{backend}",
-            "estimate": True}
+    import jax
+    kind = jax.devices()[0].device_kind
+    row = DEVICE_PEAKS.get(kind)
+    if row is None:
+        return {"flops": None, "bw": None, "source": f"unknown:{kind}",
+                "estimate": True}
+    return {"flops": row["flops"], "bw": row["bw"],
+            "source": f"table:{kind}", "estimate": False}
+
+
+def _utilization(flops: float, byts: float, wall_s: float,
+                 peak: Dict[str, Any]) -> Dict[str, float]:
+    """``{"mfu", "bw_util"}`` against ``peak`` — empty when the device
+    has no known peak."""
+    if peak["flops"] is None:
+        return {}
+    if wall_s <= 0:
+        return {"mfu": 0.0, "bw_util": 0.0}
+    return {"mfu": flops / wall_s / peak["flops"],
+            "bw_util": byts / wall_s / peak["bw"]}
 
 
 # ---------------------------------------------------------------------------
@@ -390,17 +406,14 @@ class EfficiencyRollup:
             flops += count * f
             byts += count * b
             resolved_rows.append((token, kind, label, count, f, b))
-        peak = device_peak()
-        mfu = (flops / wall_s / peak["flops"]) if wall_s > 0 else 0.0
-        bw_util = (byts / wall_s / peak["bw"]) if wall_s > 0 else 0.0
+        util = _utilization(flops, byts, wall_s, device_peak())
         sps = (samples / wall_s) if (wall_s > 0 and useful) else 0.0
         rec = {
             "step": step,
             "wall_s": wall_s,
             "flops": flops,
             "bytes_accessed": byts,
-            "mfu": mfu,
-            "bw_util": bw_util,
+            **util,
             "achieved_flops_per_s": flops / wall_s if wall_s > 0 else 0.0,
             "achieved_bytes_per_s": byts / wall_s if wall_s > 0 else 0.0,
             "samples_per_s": sps,
@@ -437,14 +450,17 @@ class EfficiencyRollup:
             self.unresolved_dispatches += unresolved
         try:
             g_mfu, g_sps = _gauges()
-            g_mfu.set(mfu)
+            if util:
+                g_mfu.set(util["mfu"])
             g_sps.set(sps)
         except Exception:
             pass
         try:
             from .tracer import tracer as _tr
             if _tr.enabled:
-                _tr.counter_event("mfu", mfu, category="efficiency")
+                if util:
+                    _tr.counter_event("mfu", util["mfu"],
+                                      category="efficiency")
                 _tr.counter_event("samples_per_s", sps,
                                   category="efficiency")
         except Exception:
@@ -460,10 +476,8 @@ class EfficiencyRollup:
                 return None
             wall = self.wall_total
             sps = (self.useful_samples_total / wall) if wall > 0 else 0.0
-            mfu = (self.flops_total / wall / peak["flops"]) \
-                if wall > 0 else 0.0
-            bw_util = (self.bytes_total / wall / peak["bw"]) \
-                if wall > 0 else 0.0
+            util = _utilization(self.flops_total, self.bytes_total, wall,
+                                peak)
             progs = sorted(
                 (dict(p) for p in self.programs.values()),
                 key=lambda p: -(p["flops"] * p["dispatches"]))
@@ -479,18 +493,18 @@ class EfficiencyRollup:
                 if wall > 0 else 0.0,
                 "achieved_bytes_per_s": self.bytes_total / wall
                 if wall > 0 else 0.0,
-                "mfu": mfu,
-                "bw_util": bw_util,
+                **util,
                 # which ceiling is the run actually pressed against —
                 # the standard roofline verdict (whichever utilization
                 # is higher is the binding constraint). With NOTHING
                 # attributed there is no verdict to give: a definitive
                 # "compute_bound" over zero measured FLOPs would be a
                 # lie (the un-hybridized-net case)
-                "roofline": ("compute_bound" if mfu >= bw_util
-                             else "bandwidth_bound")
-                if (self.flops_total > 0 or self.bytes_total > 0)
-                else "unattributed",
+                "roofline": "unattributed"
+                if not (self.flops_total > 0 or self.bytes_total > 0)
+                else "no_peak" if not util
+                else "compute_bound" if util["mfu"] >= util["bw_util"]
+                else "bandwidth_bound",
                 "samples_per_s": sps,
                 "samples_total": self.samples_total,
                 "useful_samples_total": self.useful_samples_total,

@@ -39,6 +39,7 @@ def _run_bench(deadline_s, timeout, extra_env=None):
         capture_output=True, text=True, timeout=timeout, env=env, cwd=ROOT)
 
 
+@pytest.mark.heavy
 def test_bench_tiny_deadline_emits_full_headline_json():
     res = _run_bench(deadline_s=300, timeout=360)
     assert res.returncode == 0, res.stderr[-1000:]
@@ -85,8 +86,9 @@ def test_bench_tiny_deadline_emits_full_headline_json():
     # the zero_overlap row: with MXTPU_COMM_OVERLAP=on the grad-finality
     # reduce-scatter + allgather prefetch move the launches under
     # comm_overlapped, so the EXPOSED comm share strictly drops vs the
-    # barrier plane on the same workload, with MFU held (loose fence:
-    # CPU child, absolute MFU is noise — the attribution move is the pin)
+    # barrier plane on the same workload (CPU child: the CPU is in no
+    # peaks table, so the row's MFU fields are bench.py's 0.0 default —
+    # the attribution move is the pin)
     zorow = payload["zero_overlap"]
     assert zorow["world"] == 2
     assert zorow["step_ms_barrier"] > 0 and zorow["step_ms_overlap"] > 0
@@ -95,8 +97,7 @@ def test_bench_tiny_deadline_emits_full_headline_json():
         zorow["exposed_comm_share_barrier"]
     assert zorow["total_comm_share_overlap"] >= \
         zorow["comm_overlapped_share"]
-    assert zorow["mfu_barrier"] > 0
-    assert zorow["mfu_overlap"] >= 0.5 * zorow["mfu_barrier"]
+    assert zorow["mfu_barrier"] == zorow["mfu_overlap"] == 0
     assert zorow["collectives_per_step"] >= 2  # rs + ag per bucket
     # the megastep row: one jitted donated-buffer program per step —
     # bitwise loss parity with the composed path, a single fully
@@ -134,13 +135,14 @@ def test_bench_tiny_deadline_emits_full_headline_json():
     assert nrow["nonfinite_steps"] == [2]
     assert nrow["culprit"]
     assert nrow["loss_scale_events"] == 1
-    # the efficiency row: nonzero MFU from the cost-model FLOPs of the
-    # dispatched programs, full attribution on the hybridized smoke MLP,
+    # the efficiency row: cost-model FLOPs of the dispatched programs
+    # (no MFU: the CPU has no peak), full attribution on the hybridized
+    # smoke MLP,
     # and the persistent run-report round-trip (parse + manifest verify)
     # — the carried hygiene item: the first artifact reflecting
     # PRs 6-14 parses with every plane's row present
     erow = payload["efficiency"]
-    assert erow["mfu"] > 0
+    assert erow["mfu"] == 0 and erow["roofline"] == "no_peak"
     assert erow["samples_per_s"] > 0
     assert erow["flops_per_step"] > 0
     assert erow["unattributed_dispatches"] == 0

@@ -593,6 +593,7 @@ def test_fleet_trace_aligns_anchored_clocks(tmp_path):
 # the 2-process proof: surviving rank's flight record names the absentee
 # ---------------------------------------------------------------------------
 
+@pytest.mark.heavy
 def test_two_process_kv_hang_flight_record_and_fleet_skew(tmp_path):
     """tools/launch.py forks 2 workers; rank 1 straggles then withholds
     one exchange (chaos kv_hang). Every surviving rank must write a

@@ -249,12 +249,36 @@ def test_tokens_per_s(monkeypatch):
         e["samples_per_s"] * 128.0)
 
 
-def test_cpu_default_peak_marks_estimate(monkeypatch):
+def test_unknown_device_kind_gives_no_mfu(monkeypatch):
+    """The CPU is in no peaks table: achieved rates, no MFU, no guess."""
     monkeypatch.setenv("MXTPU_EFFICIENCY", "on")
     res, _ = _fit(_mlp(), steps=2)
     e = res.efficiency
     assert e["estimate"] is True
-    assert e["peak"]["source"].startswith("default:")
+    assert e["peak"] == {"flops": None, "bw": None,
+                         "source": "unknown:cpu", "estimate": True}
+    assert "mfu" not in e and "bw_util" not in e
+    assert e["roofline"] == "no_peak"
+    assert e["achieved_flops_per_s"] > 0
+    assert all("mfu" not in r for r in e["recent"])
+
+
+def test_peaks_table_keyed_by_device_kind(monkeypatch):
+    """A kind in DEVICE_PEAKS is graded against its published peaks,
+    each entry naming its source."""
+    import jax
+    for row in eff.DEVICE_PEAKS.values():
+        assert row["flops"] > 0 and row["bw"] > 0 and row["source"]
+    v5e = eff.DEVICE_PEAKS["TPU v5 lite"]
+    assert (v5e["flops"], v5e["bw"], v5e["hbm_bytes"]) == \
+        (197e12, 819e9, 16e9)
+
+    class _Dev:
+        device_kind = "TPU v5 lite"
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    assert eff.device_peak() == {"flops": 197e12, "bw": 819e9,
+                                 "source": "table:TPU v5 lite",
+                                 "estimate": False}
 
 
 def test_zero_attribution_reports_unattributed_not_compute_bound(
@@ -263,6 +287,7 @@ def test_zero_attribution_reports_unattributed_not_compute_bound(
     NOTHING — the roofline verdict must say so, not claim a definitive
     'compute_bound' over zero measured FLOPs."""
     monkeypatch.setenv("MXTPU_EFFICIENCY", "on")
+    monkeypatch.setenv("MXTPU_DEVICE_PEAK", "flops=1e12,bw=1e12")
     monkeypatch.setenv("MXTPU_OPTIMIZER_AGGREGATION", "0")
     res, _ = _fit(_mlp(hybridize=False), steps=2)
     e = res.efficiency
@@ -361,6 +386,7 @@ def test_warm_dispatch_counts_equal_plane_off(monkeypatch):
 def test_run_report_round_trip_with_manifest(monkeypatch, tmp_path):
     rdir = tmp_path / "reports"
     monkeypatch.setenv("MXTPU_EFFICIENCY", "on")
+    monkeypatch.setenv("MXTPU_DEVICE_PEAK", "flops=1e12,bw=1e12")
     monkeypatch.setenv("MXTPU_RUN_REPORT_DIR", str(rdir))
     res, _ = _fit(_mlp(), steps=4)
     assert res.run_report and os.path.exists(res.run_report)
@@ -569,7 +595,7 @@ def test_roofline_from_report(monkeypatch, tmp_path):
         rep["efficiency"]["samples_per_s"], rel=0.01)
     assert row["program_flops_per_step"] == \
         rep["efficiency"]["flops_per_step"]
-    assert row["mfu_estimate"] is True  # CPU defaulted peak
+    assert "mfu" not in row  # the CPU has no peak to grade against
     assert "run report" in \
         ledger["modes_provenance"]["measured_imgs_per_sec_source"]
     # a NEWER-format report is rejected, not stamped as a null row
@@ -595,6 +621,8 @@ def test_trace_report_mfu_column_round_trip(monkeypatch, tmp_path):
     column and the key entirely."""
     from mxnet_tpu import telemetry
     from tools import trace_report as tre
+
+    monkeypatch.setenv("MXTPU_DEVICE_PEAK", "flops=1e12,bw=1e12")
 
     def dump(plane_on, name):
         if plane_on:
@@ -641,6 +669,7 @@ def test_trace_report_mfu_column_round_trip(monkeypatch, tmp_path):
 
 def test_cost_registry_and_gauges(monkeypatch):
     monkeypatch.setenv("MXTPU_EFFICIENCY", "on")
+    monkeypatch.setenv("MXTPU_DEVICE_PEAK", "flops=1e12,bw=1e12")
     _fit(_mlp(), steps=2)
     rows = eff.cost_report()
     assert rows and all(r["flops"] > 0 for r in rows)

@@ -49,6 +49,7 @@ def test_fleet_demo_example_smoke():
     assert "SMOKE OK" in r.stdout
 
 
+@pytest.mark.heavy
 def test_tpu_fast_training_example(tmp_path):
     """The round-2 fast-training recipe (run_steps + DeviceStagingIter +
     async checkpoints + remat) runs end to end."""

@@ -111,8 +111,9 @@ def test_fused_op_nonlast_axis_falls_back_and_matches():
 
 
 def test_flag_off_composed_fallback_matches(monkeypatch):
-    """MXTPU_FUSED_EPILOGUE=0 must actually switch lowerings (the flag
-    is in the jit-cache key) and keep identical semantics."""
+    """MXTPU_FUSED_EPILOGUE must actually switch lowerings (the flag
+    is in the jit-cache key) and keep identical semantics; unset means
+    the composed lowering."""
     opdef = reg.get_op("_contrib_fused_bn_add_relu")
     x = nd.array(RS.randn(2, 6, 6, 4).astype(np.float32))
     r = nd.array(RS.randn(2, 6, 6, 4).astype(np.float32))
@@ -127,7 +128,7 @@ def test_flag_off_composed_fallback_matches(monkeypatch):
         return out[0].asnumpy()
 
     opdef._jit_cache.clear()
-    monkeypatch.delenv("MXTPU_FUSED_EPILOGUE", raising=False)
+    monkeypatch.setenv("MXTPU_FUSED_EPILOGUE", "1")
     on = run()
     n_on = len(opdef._jit_cache)
     monkeypatch.setenv("MXTPU_FUSED_EPILOGUE", "0")
@@ -135,9 +136,15 @@ def test_flag_off_composed_fallback_matches(monkeypatch):
     assert len(opdef._jit_cache) > n_on, \
         "flag toggle did not create a new jit-cache entry (stale program)"
     np.testing.assert_allclose(on, off, rtol=2e-5, atol=2e-5)
+    monkeypatch.delenv("MXTPU_FUSED_EPILOGUE")
+    n_off = len(opdef._jit_cache)
+    np.testing.assert_array_equal(run(), off)
+    assert len(opdef._jit_cache) == n_off, "unset must mean composed"
 
 
-def test_gluon_fused_blocks_match_composed_blocks():
+@pytest.mark.parametrize("flag", ["0", "1"])
+def test_gluon_fused_blocks_match_composed_blocks(flag, monkeypatch):
+    monkeypatch.setenv("MXTPU_FUSED_EPILOGUE", flag)
     x = RS.randn(3, 8, 8, 6).astype(np.float32)
     res = RS.randn(3, 8, 8, 6).astype(np.float32)
     mx.random.seed(0)
@@ -175,10 +182,14 @@ def test_gluon_fused_blocks_match_composed_blocks():
                                atol=2e-5)
 
 
-def test_resnet_channel_last_uses_fused_blocks_and_trains():
-    """The bench model family adopts the fused epilogues channel-last;
+@pytest.mark.heavy
+def test_resnet_channel_last_uses_fused_blocks_and_trains(monkeypatch):
+    """The bench model family adopts the fused epilogue blocks
+    channel-last, here with their op lowered to the Pallas kernels (the
+    composed default trains in tests/test_chip_smoke.py's rehearsal);
     channel-first keeps the composed structure (and the kernels' NHWC
     requirement never sees an NCHW tensor)."""
+    monkeypatch.setenv("MXTPU_FUSED_EPILOGUE", "1")
     from mxnet_tpu.gluon.model_zoo.vision.resnet import (BottleneckV1,
                                                          get_resnet)
     from mxnet_tpu import gluon

@@ -473,6 +473,7 @@ def test_cached_op_memory_analysis():
     assert g is not None and g.value > 0
 
 
+@pytest.mark.heavy
 def test_grouped_program_memory():
     rs = np.random.RandomState(5)
     params = _make_params(rs, n=3)

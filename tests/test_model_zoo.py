@@ -1,5 +1,6 @@
 """Model zoo tests (ref: tests/python/unittest/test_gluon_model_zoo.py)."""
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import nd
@@ -13,6 +14,7 @@ def test_get_model_names():
         assert net is not None
 
 
+@pytest.mark.heavy
 def test_inception_v3_forward():
     net = get_model("inceptionv3", classes=10)
     net.initialize()
@@ -20,6 +22,7 @@ def test_inception_v3_forward():
     assert net(x).shape == (1, 10)
 
 
+@pytest.mark.heavy
 def test_mobilenet_v2_width_variants():
     """All four MobileNetV2 width multipliers (reference zoo parity);
     the multiplier must actually shrink the stem conv channels."""
@@ -64,6 +67,7 @@ def test_space_to_depth_stem_exact_reparametrization():
     np.testing.assert_allclose(out, ref, atol=2e-4)
 
 
+@pytest.mark.heavy
 def test_resnet50_s2d_trains():
     from mxnet_tpu import autograd
     # s2d variant builds, runs forward/backward at thumbnail-free shape

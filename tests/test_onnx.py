@@ -122,6 +122,7 @@ def test_cnn_roundtrip(tmp_path):
     _roundtrip(net, (2, 3, 8, 8), tmp_path, "cnn")
 
 
+@pytest.mark.heavy
 def test_resnet18_roundtrip(tmp_path):
     from mxnet_tpu.gluon.model_zoo.vision import resnet18_v1
     _roundtrip(resnet18_v1(), (1, 3, 32, 32), tmp_path, "resnet18",

@@ -93,3 +93,58 @@ def test_interleaved_matmul_selfatt_roundtrip():
                          weights.reshape(B, H, T, T), v)
     assert_almost_equal(out, want_out.reshape(T, B, H * D), rtol=1e-4,
                         atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the interpret-mode decision (ops/pallas_kernels.py _interpret_for): the
+# platform decides, alone and the same way every time
+# ---------------------------------------------------------------------------
+
+def test_interpret_decision_same_under_trace_and_eager():
+    """The first call in a real program comes from inside a jitted step,
+    where the operand is a tracer. It must answer as the eager call does
+    and leave nothing behind that changes a later answer."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    x = jnp.zeros((8, 128), jnp.float32)
+    seen = []
+
+    @jax.jit
+    def step(a):
+        seen.append(pk._interpret_for(a))
+        return a + 1
+
+    step(x)
+    eager = pk._interpret_for(x)
+    assert seen == [eager]
+    assert eager is (jax.default_backend() != "tpu")
+    # a host array or no operand at all lowers for the default backend
+    assert pk._interpret_for(np.zeros(3)) is eager
+    assert pk._interpret_for(None) is eager
+    step(x + 1)  # cached trace: the decision does not drift
+    assert pk._interpret_for(x) is eager
+
+
+def test_pallas_refusal_raises_and_is_not_retried_interpreted(monkeypatch):
+    """A kernel the backend's compiler refuses must raise. Compiled
+    Pallas on the CPU backend is such a refusal; it must neither fall
+    back to interpret mode nor change what the decision says next."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    x = jnp.asarray(RS.randn(16, 128).astype(np.float32))
+    g, b = jnp.ones(128), jnp.zeros(128)
+    before = pk._interpret_for(x)
+    assert before is True  # the suite runs on the CPU
+    monkeypatch.setattr(pk, "_interpret_for", lambda _x: False)
+    with pytest.raises(ValueError, match="interpret mode"):
+        pk.fused_bn_act(x, None, g, b, 1e-5)
+    with pytest.raises(ValueError, match="interpret mode"):
+        pk.flash_attention(x.reshape(1, 16, 1, 128), x.reshape(1, 16, 1, 128),
+                           x.reshape(1, 16, 1, 128))
+    monkeypatch.undo()
+    assert pk._interpret_for(x) is before
+    out, _, _ = pk.fused_bn_act(x, None, g, b, 1e-5)
+    assert np.isfinite(np.asarray(out)).all()

@@ -231,6 +231,7 @@ def test_bandwidth_measure_runs():
     assert bw > 0
 
 
+@pytest.mark.heavy
 def test_pipeline_matches_sequential():
     """GPipe pipeline over pp must be numerically identical to running
     the stages back-to-back (fwd and bwd)."""
@@ -309,13 +310,6 @@ def test_moe_transformer_ep_sharded_step():
 def test_pipeline_transformer_step():
     import jax
     import jax.numpy as jnp
-    if not hasattr(jax, "shard_map"):
-        # the experimental-shard_map fallback maps axis_names= to auto=,
-        # whose partial-manual lowering emits PartitionId — UNIMPLEMENTED
-        # for SPMD partitioning in this jax/XLA vintage
-        pytest.skip("partial-manual shard_map (axis_names=) needs "
-                    "top-level jax.shard_map; experimental fallback "
-                    "cannot partition PartitionId")
     from mxnet_tpu.models import transformer as tfm
     mesh = _mesh(dp=2, pp=2, ep=2)
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
@@ -330,3 +324,29 @@ def test_pipeline_transformer_step():
     for _ in range(5):
         loss, pparams = step(pparams, toks, toks)
     assert float(loss) < float(loss0)
+
+
+def test_spmd_mesh_trainer_compiles_its_step_once():
+    """Optimizer state is created where its parameter lives — on every
+    device of the mesh, not on the first — so the second step finds the
+    arguments laid out as the first did and reuses its program."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import gluon
+    from mxnet_tpu.parallel import SPMDTrainer
+    mesh = _mesh(dp=4)
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(16, activation="relu"), gluon.nn.Dense(4))
+    net.initialize(mx.init.Xavier())
+    tr = SPMDTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(), mesh=mesh,
+                     optimizer="adam",
+                     optimizer_params={"learning_rate": 1e-2})
+    rs = np.random.RandomState(0)
+    x = jnp.asarray(rs.randn(8, 12).astype(np.float32))
+    y = jnp.asarray(rs.randint(0, 4, (8,)).astype(np.float32))
+    for _ in range(3):
+        tr.step(x, y)
+    (fn,) = tr._step_fns.values()
+    assert fn._cache_size() == 1
+    for a in jax.tree_util.tree_leaves(tr._opt_state):
+        assert len(a.sharding.device_set) == 4

@@ -3,14 +3,6 @@ the reference's server-side row-sparse sharding,
 kvstore_dist_server.h:331, redesigned as mesh-sharded jax Arrays).
 """
 import numpy as np
-import pytest
-
-from mxnet_tpu.parallel.compat import HAVE_SHARD_MAP
-
-if not HAVE_SHARD_MAP:  # pragma: no cover - depends on container jax
-    pytest.skip("this jax build has neither jax.shard_map nor "
-                "jax.experimental.shard_map (sharded tables need one)",
-                allow_module_level=True)
 
 import mxnet_tpu as mx
 from mxnet_tpu import nd

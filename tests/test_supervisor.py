@@ -329,6 +329,7 @@ def test_supervisor_excludes_crash_looping_slot(tmp_path):
 
 # ----------------------------------------------- the chaos soak (tentpole)
 
+@pytest.mark.heavy
 def test_selfheal_chaos_soak(tmp_path):
     """Acceptance: a supervised 2-worker fleet survives three scripted
     chaos events — rank kill, hung collective (kv_hang + watchdog

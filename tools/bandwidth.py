@@ -64,8 +64,6 @@ def main():
     args = ap.parse_args()
 
     import jax
-    from mxnet_tpu.util import honor_platform_env
-    honor_platform_env()
     from mxnet_tpu.parallel import make_mesh, measure_allreduce_bandwidth
 
     n = args.num_devices or len(jax.devices())
