@@ -1,0 +1,82 @@
+"""Every (path, configuration) pair end to end on the CPU at a tiny size,
+through ``run.py --rehearse``: the control flow, the contract of the last
+line, and the refusal to measure without a TPU. No timing read here means
+anything."""
+import json
+import os
+import pathlib
+import subprocess
+import sys
+
+import pytest
+
+HERE = pathlib.Path(__file__).resolve().parent
+ROOT = HERE.parents[2]
+RUN = [sys.executable, str(HERE.parent / "run.py")]
+CELLS = json.loads((HERE / "rehearse" / "workloads.json").read_text())
+REAL = json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+def run(args, devices=1):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    return subprocess.run(RUN + args, env=env, capture_output=True, text=True,
+                          timeout=900)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("cell", CELLS, ids=lambda c: c["name"])
+def test_cell_end_to_end(cell, trace):
+    if trace and cell["config"] != "resnet50_v1":
+        pytest.skip("the traced flow is the same for every configuration")
+    done = run(["--rehearse", str(HERE / "rehearse"), "--workload",
+                cell["name"], "--seed", str(2**31 + 11), "--seconds", "8",
+                "--trace", str(trace)], devices=cell["chips"])
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
+    assert line["correct"] is True, line
+    assert line["failed"] == 0 and line["attempted"] >= 4
+    assert line["device"]["platform"] == "cpu"
+    assert line["device"]["count"] == cell["chips"]
+    assert set(line["device"]) >= {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    wanted = {m["name"] for m in REAL["per_layer" if trace else "end_to_end"]
+              if cell["name"] in m.get("workloads", [cell["name"]])}
+    # a CPU has no device plane in its trace and no peak in the table: the
+    # readers of those return nothing and the line leaves them out
+    assert set(line["metrics"]) <= wanted
+    assert set(line["metrics"]) >= (
+        {"host_dispatch_ms"} if trace else wanted)
+    for name, m in line["metrics"].items():
+        assert set(m) == {"value", "unit"} and m["value"] > 0, (name, m)
+    assert not (ROOT / ".bench_trace" / cell["name"]).exists()
+
+
+def test_no_tpu_no_result():
+    done = run(["--workload", REAL["workloads"][0]["name"], "--seed", "1",
+                "--seconds", "1", "--trace", "0"])
+    assert done.returncode != 0 and done.stdout.strip() == ""
+    assert "no TPU" in done.stderr
+
+
+def test_benchmark_json_resolves_to_files():
+    """Every name in BENCHMARK.json is a file under benchmark/chip. (A file
+    may wait there for its cell: PERF.md section 7.)"""
+    chip = HERE.parent
+    assert REAL["paths"] == [str(chip.relative_to(ROOT))]
+    for c in REAL["configs"]:
+        assert c["file"] == f"{REAL['paths'][0]}/configs/{c['name']}.json"
+        config = json.loads((ROOT / c["file"]).read_text())
+        assert config["reduced"] == c["reduced"]
+        assert (chip / "models" / f"{c['name']}.py").exists()
+    for w in REAL["workloads"]:
+        traffic = json.loads(
+            (chip / "traffic" / f"{w['traffic']}.json").read_text())
+        assert traffic["chips"] == w["chips"]
+        assert (chip / "paths" / f"{traffic['path']}.py").exists()
+    assert {m["name"] for m in REAL["per_layer"] + REAL["end_to_end"]} <= {
+        p.name[:-3] for p in (chip / "metrics").glob("*.py")}
+    e2e = {m["name"] for m in REAL["end_to_end"]}
+    assert all(m["moves"] in e2e for m in REAL["per_layer"])
