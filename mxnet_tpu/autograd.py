@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as _np
 
 from .base import MXNetError, check, hashable_params
+from .telemetry.tracer import span as _span
 
 __all__ = ["record", "pause", "train_mode", "predict_mode", "is_recording",
            "is_training", "set_recording", "set_training", "mark_variables",
@@ -388,13 +389,15 @@ def grad(heads, variables, head_grads=None, retain_graph=None,
 def _deliver_grad(e: "_VariableEntry", g):
     """Write one accumulated cotangent into a variable's attached grad
     buffer (honoring grad_req and row_sparse buffers). Returns the buffer
-    written, or None when the variable has no live buffer."""
+    written, or None when the variable has no live buffer, and the number
+    of programs that took (the add of grad_req='add', a cast to the
+    buffer's dtype)."""
     var = e.array_ref()
     if var is None or e.grad_ref is None:
-        return None
+        return None, 0
     gbuf = e.grad_ref()
     if gbuf is None or e.grad_req == "null":
-        return None
+        return None, 0
     from .ndarray.sparse import RowSparseNDArray
     if isinstance(gbuf, RowSparseNDArray):
         # row_sparse grad buffer (attach_grad(stype='row_sparse') /
@@ -409,19 +412,30 @@ def _deliver_grad(e: "_VariableEntry", g):
         data, uniq = g.compact()
         gbuf._update(data.astype(gbuf._data.dtype), uniq)
         gbuf._fresh_grad = True
-        return gbuf
+        return gbuf, 0  # host-side packing: its launches are not counted
     if isinstance(g, _RspGrad):
         g = g.densify()
     if e.grad_req == "add":
         gbuf._rebind(gbuf._data + g)
+        launched = 1
     else:
+        launched = int(g.dtype != gbuf._data.dtype)
         gbuf._rebind(g.astype(gbuf._data.dtype))
     gbuf._fresh_grad = True
-    return gbuf
+    return gbuf, launched
 
 
 def _backward_impl(heads, head_grads, retain_graph, train_mode_flag,
                    variables=None):
+    with _span("mx.autograd.backward", "step") as sp:
+        return _backward_walk(heads, head_grads, retain_graph, variables, sp)
+
+
+def _backward_walk(heads, head_grads, retain_graph, variables, sp):
+    """The reverse pass. ``sp`` is its span: it learns how many tape nodes
+    were walked and how many programs the walk itself launched (head
+    gradients, zero cotangents, the eager ops' vjps, sums of cotangents;
+    not a CachedOp's backward, which has its own span)."""
     import jax.numpy as jnp
     from .ndarray.ndarray import NDArray
 
@@ -438,18 +452,26 @@ def _backward_impl(heads, head_grads, retain_graph, train_mode_flag,
     acc: Dict[int, Any] = {}
     entry_of: Dict[int, Any] = {}
 
+    launched = 0
+
     def add_grad(entry, g):
+        nonlocal launched
         k = id(entry)
         entry_of[k] = entry
         if k in acc:
             acc[k] = _grad_sum(acc[k], g)
+            launched += 1
         else:
             acc[k] = g
 
     root_nodes = []
     for h, hg in zip(heads, head_grads):
         e = h._tape_entry
-        g = hg._data if hg is not None else jnp.ones(h.shape, h._data.dtype)
+        if hg is not None:
+            g = hg._data
+        else:
+            g = jnp.ones(h.shape, h._data.dtype)
+            launched += 1
         add_grad(e, g)
         if isinstance(e, _OutputEntry):
             root_nodes.append(e.node)
@@ -486,6 +508,7 @@ def _backward_impl(heads, head_grads, retain_graph, train_mode_flag,
                             else found)
             else:
                 cots.append(jnp.zeros(shape, dtype))
+                launched += 1
         if has_any:
             if node.input_vals is None:
                 raise MXNetError("graph has already been freed; pass "
@@ -507,6 +530,7 @@ def _backward_impl(heads, head_grads, retain_graph, train_mode_flag,
                                            weight_in.shape))
             else:
                 in_grads = _vjp_call(node, tuple(cots))
+                launched += 1
             for e, g in zip(node.input_entries, in_grads):
                 if e is not None and g is not None:
                     add_grad(e, g)
@@ -522,7 +546,8 @@ def _backward_impl(heads, head_grads, retain_graph, train_mode_flag,
             pending[k] -= 1
             if pending[k] == 0 and k in acc and k not in delivered:
                 delivered.add(k)
-                gbuf = _deliver_grad(e, acc[k])
+                gbuf, n = _deliver_grad(e, acc[k])
+                launched += n
                 if gbuf is not None:
                     hook(gbuf)
 
@@ -547,9 +572,15 @@ def _backward_impl(heads, head_grads, retain_graph, train_mode_flag,
     # accumulate into attached grad buffers (entries already delivered
     # early by the grad-ready path are skipped — delivering twice would
     # double-accumulate a grad_req='add' buffer)
-    for k, e in entry_of.items():
-        if isinstance(e, _VariableEntry) and k not in delivered:
-            _deliver_grad(e, acc[k])
+    sp.set(nodes=len(order), programs=launched)
+    with _span("mx.autograd.deliver", "step") as deliver:
+        grads = programs = 0
+        for k, e in entry_of.items():
+            if isinstance(e, _VariableEntry) and k not in delivered:
+                gbuf, n = _deliver_grad(e, acc[k])
+                grads += gbuf is not None
+                programs += n
+        deliver.set(grads=grads, programs=programs)
 
     if not retain_graph:
         for node in order:

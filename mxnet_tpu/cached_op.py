@@ -27,6 +27,7 @@ from collections import OrderedDict, namedtuple
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from .base import MXNetError, env
+from .telemetry.tracer import span as _span
 
 __all__ = ["CachedOp", "CacheInfo", "SignatureLRU", "make_scan_forward",
            "scan_forward"]
@@ -350,6 +351,11 @@ class _CachedOpGrad:
             pass  # observability must not take down the backward
 
     def _run_backward(self, cotangents):
+        with _span("mx.cached_op.vjp", "step",
+                   {"block": type(self.op.block).__name__, "programs": 1}):
+            return self._vjp(cotangents)
+
+    def _vjp(self, cotangents):
         import jax
         entry = self.entry
         if _eff().enabled():
@@ -775,8 +781,8 @@ class CachedOp:
     # -----------------------------------------------------------------
     def __call__(self, *args):
         import jax
-        from . import autograd, random as _random
-        from .ndarray.ndarray import NDArray, from_jax
+        from . import autograd
+        from .ndarray.ndarray import NDArray
 
         flat_in, in_treedef = jax.tree_util.tree_flatten(
             args, is_leaf=lambda x: isinstance(x, NDArray))
@@ -796,6 +802,17 @@ class CachedOp:
                 return self.block._imperative_call(*args)
         elif not env.get("MXNET_EXEC_BULK_EXEC_INFERENCE"):
             return self.block._imperative_call(*args)
+
+        with _span("mx.cached_op.forward", "step") as sp:
+            return self._replay(flat_in, in_treedef, in_arrays, sp)
+
+    def _replay(self, flat_in, in_treedef, in_arrays, sp):
+        """Run the block's compiled program for these inputs (tracing and
+        compiling it first on a new signature). ``sp`` is the call's
+        span."""
+        import jax
+        from . import autograd, random as _random
+        from .ndarray.ndarray import NDArray
 
         params = self._params()
         for p in params:
@@ -832,6 +849,8 @@ class CachedOp:
                 return e
 
             entry = self._cache.get_or_insert(key_sig, _new_entry)
+            sp.set(block=type(self.block).__name__, programs=1,
+                   cache="hit" if entry.warm else "miss")
             if not entry.warm:
                 # cold entry (ours or a concurrent thread's): the first
                 # execution runs the python trace, which swaps Parameter

@@ -21,6 +21,7 @@ import contextlib
 import os
 
 from .base import env
+from .telemetry.tracer import span as _span
 
 __all__ = ["set_bulk_size", "bulk", "wait_for_all", "engine_type",
            "set_engine_type", "NativeEngine", "shared_engine"]
@@ -137,13 +138,7 @@ class NativeEngine:
         import ctypes
 
         def tramp(_):
-            from . import profiler as _prof
-            if _prof.is_active():
-                import time as _time
-                t0 = _time.perf_counter()
-                fn()
-                _prof.record_span(name, "engine", t0, _time.perf_counter())
-            else:
+            with _span(name, "engine"):
                 fn()
 
         cb = self._cb_type(tramp)

@@ -65,6 +65,7 @@ from ..optimizer import grouped as _grouped
 from ..telemetry import memory as _memory
 from ..telemetry import numerics as _numerics
 from ..telemetry.step_breakdown import segment as _bd_segment
+from ..telemetry.tracer import span as _span, tracer as _tracer
 from .parameter import Parameter, ParameterDict
 
 __all__ = ["Trainer"]
@@ -177,6 +178,7 @@ class Trainer:
         # per-call observability for the aggregated paths (bench + the
         # dispatch-count regression test read these)
         self.last_update_dispatches = 0
+        self._update_buckets = 0
         self.last_allreduce_collectives = 0
         self.last_reduce_scatter_collectives = 0
         self.last_allgather_collectives = 0
@@ -307,6 +309,11 @@ class Trainer:
         per-key mask-pack path. Under an active :meth:`overlap_scope` the
         collectives were already launched during backward; this call
         flushes the remainder and completes the splits."""
+        with _span("mx.trainer.allreduce", "step") as sp:
+            self._allreduce_grads()
+            sp.set(collectives=self.last_allreduce_collectives)
+
+    def _allreduce_grads(self):
         st = self._overlap_state
         if st is not None:
             self._overlap_state = None
@@ -484,6 +491,11 @@ class Trainer:
     def step(self, batch_size, ignore_stale_grad=False):
         """One optimization step: rescale by 1/batch_size, allreduce, update
         (ref: trainer.py:298)."""
+        with _span("mx.trainer.step", "step", {"params": len(self._params)}):
+            self._step(batch_size, ignore_stale_grad)
+        _tracer.end_step()
+
+    def _step(self, batch_size, ignore_stale_grad):
         if not self._kv_initialized:
             self._init_kvstore()
         self._optimizer.rescale_grad = self._scale / batch_size
@@ -511,6 +523,7 @@ class Trainer:
     def update(self, batch_size, ignore_stale_grad=False):
         self._optimizer.rescale_grad = self._scale / batch_size
         self._update(ignore_stale_grad)
+        _tracer.end_step()
 
     def update_with_sentinel(self, batch_size, ignore_stale_grad=False):
         """Aggregated update with the global-finiteness sentinel folded
@@ -521,7 +534,9 @@ class Trainer:
         None when the fused path cannot cover the whole parameter set —
         the caller must then use the classic check-then-update flow."""
         self._optimizer.rescale_grad = self._scale / batch_size
-        return self._update(ignore_stale_grad, sentinel=True)
+        flag = self._update(ignore_stale_grad, sentinel=True)
+        _tracer.end_step()
+        return flag
 
     def rollback_step(self):
         """Undo the host-side effects of the last fused sentinel step (the
@@ -592,9 +607,17 @@ class Trainer:
         return live, rank_chunks, lr_list, wd_list
 
     def _update(self, ignore_stale_grad=False, sentinel=False):
+        with _span("mx.trainer.update", "step") as sp:
+            flag = self._apply_update(ignore_stale_grad, sentinel)
+            sp.set(programs=self.last_update_dispatches,
+                   buckets=self._update_buckets)
+        return flag
+
+    def _apply_update(self, ignore_stale_grad, sentinel):
         # stale sampled stats must not outlive their step: FitLoop reads
         # this attribute right after the update call
         self.last_numerics_stats = None
+        self._update_buckets = 0  # grouped bucket programs, for the span
         plane = self._zero_step
         self._zero_step = None
         if plane is not None:
@@ -674,6 +697,7 @@ class Trainer:
                 self._last_fused_indices = idxs
                 self._last_fused_created = created
                 self.last_update_dispatches += n + 1  # + finite reduction
+                self._update_buckets += n
             else:
                 dense = [(i, p) for i, p in todo
                          if _grouped.eligible(updater, [(i, p)])]
@@ -690,6 +714,7 @@ class Trainer:
                         updater, dense, agg, stats_out=stats_out)
                     handled = set(idxs)
                     self.last_update_dispatches += n
+                    self._update_buckets += n
         if stats_out:
             self.last_numerics_stats = stats_out
         for i, p in todo:
@@ -789,6 +814,7 @@ class Trainer:
                     handled += idxs
                     created += cr
                     n_disp += n
+                    self._update_buckets += n
                 with _bd_segment("comm_overlapped"):
                     plane.launch_allgather_bucket(self, key, bucket)
             plane.seal_allgather(self)
@@ -809,6 +835,7 @@ class Trainer:
             handled += idxs
             created += cr
             n_disp += n
+            self._update_buckets += n
         if stats_out is not None:
             # park even an EMPTY list (a distributed rank owning zero
             # params this step): record_step's cross-rank stats merge is

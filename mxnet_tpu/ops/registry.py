@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as _np
 
 from ..base import MXNetError, env, hashable_params, coerce_param
+from ..telemetry.tracer import span as _span
 
 __all__ = ["OpDef", "register", "get_op", "list_ops", "invoke_jax",
            "eval_shape", "alias", "register_sparse", "stype_dispatch",
@@ -278,6 +279,11 @@ def _trace_time_flags() -> Tuple:
             bool(env.get("MXTPU_FUSED_EPILOGUE")))
 
 
+#: span args of an eager op: the one executable it launches (shared: the
+#: tracer copies)
+_ONE_PROGRAM = {"programs": 1}
+
+
 def invoke_jax(opdef: OpDef, arrays: Sequence, params: Dict[str, Any]):
     """Execute an op on raw jax arrays through the jit cache.
 
@@ -301,23 +307,18 @@ def invoke_jax(opdef: OpDef, arrays: Sequence, params: Dict[str, Any]):
                 # a strong f32 scalar would silently promote the update
                 dyn[n] = float(params.pop(n))
     key = hashable_params(params) + _trace_time_flags()
-    from .. import profiler as _prof
-    profiling = _prof.is_active()
-    t0 = __import__("time").perf_counter() if profiling else 0.0
-    try:
-        out = opdef.jitted(key, tuple(dyn))(tuple(dyn.values()), *arrays)
-    except TypeError:
-        # Non-jittable param combination (e.g. python callable param):
-        # fall back to direct tracing-free eval.
-        out = opdef.fn(*arrays, **params, **dyn)
-    if op_islands_active():
-        out = _island(out)
-    if _naive_engine():
-        import jax
-        jax.block_until_ready(out)
-    if profiling:
-        _prof.record_span(opdef.name, "operator", t0,
-                          __import__("time").perf_counter())
+    with _span(opdef.name, "operator", _ONE_PROGRAM):
+        try:
+            out = opdef.jitted(key, tuple(dyn))(tuple(dyn.values()), *arrays)
+        except TypeError:
+            # Non-jittable param combination (e.g. python callable param):
+            # fall back to direct tracing-free eval.
+            out = opdef.fn(*arrays, **params, **dyn)
+        if op_islands_active():
+            out = _island(out)
+        if _naive_engine():
+            import jax
+            jax.block_until_ready(out)
     return out
 
 

@@ -18,8 +18,14 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..base import MXNetError, check
+from ..telemetry.tracer import span as _span, tracer as _tracer
 
 __all__ = ["SPMDTrainer"]
+
+# ``programs`` of the step's spans (shared: the tracer copies). A launch is
+# two: the cast that makes the step counter a device scalar, and the step.
+_NO_PROGRAM = {"programs": 0}
+_TWO_PROGRAMS = {"programs": 2}
 
 
 class SPMDTrainer:
@@ -391,18 +397,23 @@ class SPMDTrainer:
     def step(self, data, label):
         """Run one training step; returns the (device) scalar loss."""
         import jax.numpy as jnp
-        data, label, train_arrays, aux_arrays, key = self._prepare(
-            data, label)
-        self._t += 1
-        sig = (tuple((a.shape, str(a.dtype)) for a in (data, label)),)
-        fn = self._step_fns.get(sig)
-        if fn is None:
-            fn = self._step_fns[sig] = self._make_step(sig)
-        args = (train_arrays, aux_arrays, self._opt_state, key,
-                jnp.asarray(self._t, jnp.int32), data, label)
-        self._record_program(fn, args)
-        loss, new_params, new_aux, new_opt = fn(*args)
-        self._finish(new_params, new_aux, new_opt)
+        with _span("mx.spmd.step", "step", _NO_PROGRAM):
+            with _span("mx.spmd.prepare", "step", _NO_PROGRAM):
+                data, label, train_arrays, aux_arrays, key = self._prepare(
+                    data, label)
+            self._t += 1
+            sig = (tuple((a.shape, str(a.dtype)) for a in (data, label)),)
+            fn = self._step_fns.get(sig)
+            if fn is None:
+                fn = self._step_fns[sig] = self._make_step(sig)
+            with _span("mx.spmd.launch", "step", _TWO_PROGRAMS):
+                args = (train_arrays, aux_arrays, self._opt_state, key,
+                        jnp.asarray(self._t, jnp.int32), data, label)
+                self._record_program(fn, args)
+                loss, new_params, new_aux, new_opt = fn(*args)
+            with _span("mx.spmd.finish", "step", _NO_PROGRAM):
+                self._finish(new_params, new_aux, new_opt)
+        _tracer.end_step()
         return loss
 
     def run_steps(self, data, label):
@@ -417,19 +428,24 @@ class SPMDTrainer:
         per-dispatch host overhead matters or to let XLA overlap the
         optimizer update of step i with the forward of step i+1."""
         import jax.numpy as jnp
-        data, label, train_arrays, aux_arrays, key = self._prepare(
-            data, label, batch_dim=1)
-        k_steps = data.shape[0]
-        sig = ("multi", tuple((a.shape, str(a.dtype))
-                              for a in (data, label)))
-        fn = self._step_fns.get(sig)
-        if fn is None:
-            fn = self._step_fns[sig] = self._make_multi_step(sig)
-        t0 = jnp.asarray(self._t + 1, jnp.int32)
-        args = (train_arrays, aux_arrays, self._opt_state, key, t0, data,
-                label)
-        self._record_program(fn, args)
-        losses, new_params, new_aux, new_opt = fn(*args)
-        self._t += int(k_steps)
-        self._finish(new_params, new_aux, new_opt)
+        with _span("mx.spmd.step", "step", _NO_PROGRAM):
+            with _span("mx.spmd.prepare", "step", _NO_PROGRAM):
+                data, label, train_arrays, aux_arrays, key = self._prepare(
+                    data, label, batch_dim=1)
+            k_steps = data.shape[0]
+            sig = ("multi", tuple((a.shape, str(a.dtype))
+                                  for a in (data, label)))
+            fn = self._step_fns.get(sig)
+            if fn is None:
+                fn = self._step_fns[sig] = self._make_multi_step(sig)
+            with _span("mx.spmd.launch", "step", _TWO_PROGRAMS):
+                t0 = jnp.asarray(self._t + 1, jnp.int32)
+                args = (train_arrays, aux_arrays, self._opt_state, key, t0,
+                        data, label)
+                self._record_program(fn, args)
+                losses, new_params, new_aux, new_opt = fn(*args)
+            self._t += int(k_steps)
+            with _span("mx.spmd.finish", "step", _NO_PROGRAM):
+                self._finish(new_params, new_aux, new_opt)
+        _tracer.end_step()
         return losses

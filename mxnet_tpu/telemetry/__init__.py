@@ -9,7 +9,9 @@ is the TPU-native generalization; the whole stack reports into it:
 
 - :mod:`.tracer` — thread-safe structured span tracer with a bounded ring
   buffer, category filtering and the ``MXTPU_PROFILE`` env grammar. Near-zero
-  overhead when off (one flag check per span).
+  overhead when off (one flag check per span). Spans carry their parent and
+  step number; while a JAX profiler session runs the tracer is on and the
+  spans are in the device trace too, as ``mx.*`` annotations.
 - :mod:`.chrome_trace` — strict Chrome trace-event JSON exporter (loadable in
   Perfetto / chrome://tracing) plus the validator the test-suite enforces it
   with.
@@ -75,7 +77,7 @@ collect per-rank chrome traces without a shared filesystem.
 from __future__ import annotations
 
 from .tracer import (Tracer, tracer, span, instant, counter_event, enabled,
-                     configure, enable, disable)
+                     configure, enable, disable, end_step)
 from .chrome_trace import (chrome_trace_events, dump_chrome_trace,
                            validate_chrome_trace)
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
@@ -97,7 +99,7 @@ from .run_report import write_run_report, load_run_report
 
 __all__ = [
     "Tracer", "tracer", "span", "instant", "counter_event", "enabled",
-    "configure", "enable", "disable",
+    "configure", "enable", "disable", "end_step",
     "chrome_trace_events", "dump_chrome_trace", "validate_chrome_trace",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_registry",
     "StepBreakdown", "segment", "current_breakdown", "SEGMENTS",

@@ -91,24 +91,24 @@ class _Segment:
     """Context manager: tracer span + exclusive-time charge to the active
     breakdown. Nested segments subtract their time from the enclosing one
     (self-time accounting), so one wall-second is never charged twice."""
-    __slots__ = ("_name", "_args", "_t0", "_child")
+    __slots__ = ("_name", "_span", "_t0", "_child")
 
     def __init__(self, name: str, args: Optional[dict]):
         self._name = name
-        self._args = args
+        self._span = _tracer.span(name, name, args)
         self._child = 0.0
 
     def __enter__(self):
         bd = getattr(_tls, "active", None)
         if bd is not None:
             bd._stack.append(self)
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *a):
-        t1 = time.perf_counter()
-        dt = t1 - self._t0
-        _tracer.record(self._name, self._name, self._t0, t1, self._args)
+        dt = time.perf_counter() - self._t0
+        self._span.__exit__(*a)
         bd = getattr(_tls, "active", None)
         if bd is not None and bd._stack and bd._stack[-1] is self:
             bd._stack.pop()
@@ -118,24 +118,13 @@ class _Segment:
         return False
 
 
-class _NoopSegment:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
-
-
-_NOOP = _NoopSegment()
-
-
 def segment(name: str, args: Optional[dict] = None):
-    """Bracket one step phase. No-op (no clock reads) unless the tracer is
-    enabled or a StepBreakdown is collecting on this thread."""
-    if not _tracer.enabled and getattr(_tls, "active", None) is None:
-        return _NOOP
+    """Bracket one step phase: a tracer span (category = the segment's
+    name) and, where a StepBreakdown is collecting on this thread, its
+    exclusive-time charge. With neither listening it is the tracer's no-op
+    and reads no clock."""
+    if getattr(_tls, "active", None) is None:
+        return _tracer.span(name, name, args)
     return _Segment(name, args)
 
 

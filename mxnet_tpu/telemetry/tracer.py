@@ -7,9 +7,22 @@ straight serialization (:mod:`.chrome_trace`). ``pid`` is the worker rank
 (the reference tags profiler output per process; rank comes from
 ``MXTPU_WORKER_ID``), ``tid`` a dense per-thread id.
 
-Overhead contract: when tracing is off, :func:`span` costs one attribute
-check and returns a shared no-op context manager — no clock reads, no
-allocation. The test-suite holds this to <1% on a tight step loop.
+:func:`span` is the one way to write a span. Every span it records carries
+in its ``args`` an ``id``, the ``parent`` id (the span open around it on the
+same thread; absent on a root) and the thread's ``step`` number at entry
+(:func:`end_step` advances it: ``Trainer.step`` and ``SPMDTrainer.step`` do),
+so a reader can group the ring by step and compute self times.
+
+The tracer follows the device trace: it counts as on while a JAX profiler
+session runs, whoever started it, and every span is then also entered as a
+``jax.profiler.TraceAnnotation`` named ``mx.*`` (:func:`xplane_name`), so the
+session's ``.xplane.pb`` holds the program's spans on the device ops' clock.
+When the session stops the tracer is what it was before.
+
+Overhead contract: when tracing is off, :func:`span` costs the on-flag test
+and the profiler's (about 20 ns) and returns a shared no-op context manager:
+no clock reads, no allocation. The test-suite holds this to <1% on a tight
+step loop.
 
 ``MXTPU_PROFILE`` grammar (comma-separated tokens):
 
@@ -33,12 +46,31 @@ import time
 from collections import defaultdict, deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from ..base import MXNetError, env
 
 __all__ = ["Tracer", "tracer", "span", "instant", "counter_event",
-           "enabled", "configure", "enable", "disable"]
+           "enabled", "configure", "enable", "disable", "end_step",
+           "xplane_name"]
 
 DEFAULT_RING = 65536
+
+#: is a JAX profiler session collecting host annotations right now?
+_in_session = _Annotation.is_enabled
+
+
+def xplane_name(name: str, category: str) -> str:
+    """The name a span has in a device trace's host plane: always ``mx.*``,
+    so a reader of the ``.xplane.pb`` tells the program's spans from JAX's
+    own and the caller's."""
+    if name.startswith("mx."):
+        return name
+    if category == "operator":
+        return "mx.op." + name
+    if category == "compile":
+        return "mx.compile"
+    return f"mx.{category}.{name}"
 
 
 class _NoopSpan:
@@ -51,28 +83,62 @@ class _NoopSpan:
     def __exit__(self, *a):
         return False
 
+    def set(self, **args):
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
+class _Thread(threading.local):
+    """Per-thread span state: the ids of the open spans, innermost last,
+    and the step counter."""
+
+    def __init__(self):
+        self.open: List[int] = []
+        self.step = 0
+
+
 class _Span:
     """One live span; records on exit."""
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_id", "_parent",
+                 "_step", "_annotation")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
-                 args: Optional[dict]):
+                 args: Optional[dict], in_session: bool):
         self._tr = tr
         self._name = name
         self._cat = cat
-        self._args = args
-        self._t0 = time.perf_counter()
+        self._args = dict(args) if args else {}
+        self._annotation = _Annotation(xplane_name(name, cat)) \
+            if in_session else None
+
+    def set(self, **args):
+        """Add ``args`` known only once the work is done (a count of what
+        the span launched)."""
+        self._args.update(args)
 
     def __enter__(self):
+        thread = self._tr._thread
+        self._id = next(self._tr._span_ids)
+        self._parent = thread.open[-1] if thread.open else None
+        self._step = thread.step
+        thread.open.append(self._id)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *a):
-        self._tr.record(self._name, self._cat, self._t0,
-                        time.perf_counter(), self._args)
+        t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*a)
+        self._tr._thread.open.pop()
+        args = self._args
+        args["id"], args["step"] = self._id, self._step
+        if self._parent is not None:
+            args["parent"] = self._parent
+        self._tr._append(self._name, self._cat, self._t0, t1, args)
         return False
 
 
@@ -101,6 +167,8 @@ class Tracer:
         self._rank = rank
         self._tids: Dict[int, int] = {}
         self._tid_counter = itertools.count()
+        self._span_ids = itertools.count(1)
+        self._thread = _Thread()
         self._dropped = 0
         # aggregate stats (cat::name -> [count, total_ms, min_ms, max_ms]);
         # unbounded by design: the table is O(distinct names), not O(spans)
@@ -112,7 +180,9 @@ class Tracer:
     # -- state ----------------------------------------------------------
     @property
     def enabled(self) -> bool:
-        return self._on and not self._paused
+        """On by request (``enable``/``MXTPU_PROFILE``) or because a JAX
+        profiler session is running, and not paused."""
+        return (self._on or _in_session()) and not self._paused
 
     @property
     def rank(self) -> int:
@@ -181,19 +251,37 @@ class Tracer:
                                  category in self._categories)
 
     def span(self, name: str, category: str, args: Optional[dict] = None):
-        """Context manager timing one span. The off path allocates
-        nothing and never reads the clock."""
-        if not self._on or self._paused or (
+        """Context manager timing one span; ``args`` is copied. The off
+        path allocates nothing and never reads the clock."""
+        in_session = _in_session()
+        if not (self._on or in_session) or self._paused or (
                 self._categories is not None and
                 category not in self._categories):
             return _NOOP
-        return _Span(self, name, category, args)
+        return _Span(self, name, category, args, in_session)
+
+    def end_step(self) -> None:
+        """Advance this thread's step number: the spans that follow belong
+        to the next step."""
+        self._thread.step += 1
 
     def record(self, name: str, category: str, t_start: float,
                t_end: float, args: Optional[dict] = None) -> None:
-        """Record one completed span from perf_counter timestamps."""
+        """Record one completed span from perf_counter timestamps: for
+        work that was timed before it was known to be a span (a compile
+        that ``jax.monitoring`` reports, a served request). It has no
+        place in the nesting, and in a device trace it is a marker at
+        the moment it is recorded."""
         if not self.wants(category):
             return
+        if _in_session():
+            with _Annotation(xplane_name(name, category),
+                             dur_us=max(t_end - t_start, 0.0) * 1e6):
+                pass
+        self._append(name, category, t_start, t_end, args)
+
+    def _append(self, name: str, category: str, t_start: float,
+                t_end: float, args: Optional[dict]) -> None:
         ev = {"name": name, "cat": category,
               "ts": (t_start - self._t0) * 1e6,
               "dur": max(t_end - t_start, 0.0) * 1e6,
@@ -357,6 +445,10 @@ def _register_atexit_dump(tr: Tracer) -> None:
 
 def span(name: str, category: str, args: Optional[dict] = None):
     return tracer.span(name, category, args)
+
+
+def end_step() -> None:
+    tracer.end_step()
 
 
 def instant(name: str, category: str = "marker") -> None:

@@ -1,6 +1,6 @@
 """Localize the ResNet-50 conv gap: isolated convs sustain ~190 TFLOP/s
 (probe_lowbit_conv) but the conv-only model skeleton still takes the full
-~104 ms/step (probe_step_breakdown: BN/ReLU ablations change nothing).
+~104 ms/step (BN/ReLU ablations change nothing).
 
 This probe times each ResNet-50 STAGE as a pure-conv chain — forward and
 forward+backward — by the methodology of probe_lowbit_conv:
