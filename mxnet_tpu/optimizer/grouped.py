@@ -591,7 +591,10 @@ def apply_chunk(updater, rule, chunk, lrs, wds, rescale,
     than host floats so the megastep trace can feed slices of its
     dynamic per-step inputs (Adam's bias-corrected lr changes every
     step; baking it would retrace) — and so can pass tracers, inlining
-    the SAME cached program the composed path dispatches.
+    the SAME cached program the composed path dispatches. A host
+    caller hands them over as host float32 numpy arrays, which travel
+    with this launch (``jnp.asarray`` of a Python list is a put plus a
+    cast program of its own).
     ``note_dispatches=False`` suppresses the efficiency-plane note: a
     trace-time call is not a launch, and the megastep driver notes its
     ONE program itself. Returns the handled indices."""
@@ -679,6 +682,11 @@ def grouped_update(updater, items, agg_size: int, sentinel: bool = False,
     existing flag+loss transfer. None (default) = the stats-free
     programs, bit-for-bit the historical behavior.
 
+    Each bucket's learning rates and weight decays and the step's
+    ``rescale_grad`` travel as host float32 arrays with the bucket's own
+    launch (``jnp.asarray`` of a Python list is a put plus a cast program
+    of its own, a launch that ``n_dispatches`` does not count).
+
     Returns ``(handled_indices, n_dispatches, finite_flag, created)``
     where ``finite_flag`` is a device scalar when ``sentinel`` and None
     otherwise, and ``created`` lists the indices whose optimizer state was
@@ -710,12 +718,12 @@ def grouped_update(updater, items, agg_size: int, sentinel: bool = False,
                 sentinel_grads = tuple(p._grad._data for _, p in items)
             flag = global_finite_flag(tuple(sentinel_grads))
 
-    rescale = jnp.asarray(float(opt.rescale_grad), dtype=jnp.float32)
+    rescale = _np.asarray(float(opt.rescale_grad), dtype=_np.float32)
     n_dispatch = 0
     handled = []
     for chunk in chunks:
-        lrs = jnp.asarray([e[4] for e in chunk], dtype=jnp.float32)
-        wds = jnp.asarray([e[5] for e in chunk], dtype=jnp.float32)
+        lrs = _np.asarray([e[4] for e in chunk], dtype=_np.float32)
+        wds = _np.asarray([e[5] for e in chunk], dtype=_np.float32)
         handled += apply_chunk(updater, rule, chunk, lrs, wds, rescale,
                                sentinel=sentinel, flag=flag,
                                stats_out=stats_out)
@@ -787,7 +795,9 @@ def sparse_rows_update(opt, weight, states, grad_rows, idx, valid, lr, wd,
     sentinel and chaos hooks), ``idx``/``valid`` the shard-local row ids
     and their in-shard+non-padding mask, ``lr``/``wd`` dynamic f32
     scalars (Adam's bias-corrected lr changes every step; baking it
-    would retrace) and ``flag`` an optional device all-finite verdict
+    would retrace; Python floats here, handed to the program as host
+    float32 arrays with its launch, since ``jnp.asarray`` of one is a put
+    plus a cast program) and ``flag`` an optional device all-finite verdict
     (the global sentinel). The update math is the SAME
     :data:`_RULES` kernel the dense buckets trace, applied to gathered
     rows — so a plane step is bitwise the dense-gather reference update
@@ -813,9 +823,9 @@ def sparse_rows_update(opt, weight, states, grad_rows, idx, valid, lr, wd,
         return _build_rows_fn(_with_cast(k, False), g)
 
     fn = _cache().get_or_build(sig, _build)
-    lr = jnp.asarray(float(lr), jnp.float32)
-    wd = jnp.asarray(float(wd), jnp.float32)
-    rescale = jnp.asarray(float(opt.rescale_grad), jnp.float32)
+    lr = _np.asarray(float(lr), dtype=_np.float32)
+    wd = _np.asarray(float(wd), dtype=_np.float32)
+    rescale = _np.asarray(float(opt.rescale_grad), dtype=_np.float32)
     if guarded:
         outs = fn(lr, wd, rescale, jnp.asarray(flag), donated, grad_rows,
                   idx, valid)

@@ -197,6 +197,52 @@ def test_parity_vs_dense_gather_reference(plane_on):
         plane.close()
 
 
+def test_warm_rows_update_is_one_launch_and_bitwise_the_kernel(tmp_path):
+    """A warm ``sparse_rows_update`` launches its rows program and nothing
+    else: lr, wd and ``rescale_grad`` travel with that launch as host
+    float32 arrays, not as three puts and cast programs before it. The
+    values are what the device scalars gave: bitwise the rule kernel on the
+    gathered rows."""
+    from test_step_spans import _host_spans, _launches_inside, \
+        _profiler_session
+    rows, dim, bucket = 16, 4, 8
+    opt = opt_mod.Adam(learning_rate=0.05, wd=0.01, rescale_grad=0.5)
+    rs = np.random.RandomState(3)
+    w0 = rs.randn(rows, dim).astype(np.float32)
+    g = jnp.asarray(rs.randn(bucket, dim).astype(np.float32))
+    idx = jnp.asarray(np.array([1, 4, 5, 9, 15, 0, 0, 0], np.int32))
+    valid = jnp.asarray(np.arange(bucket) < 5)
+    lr, wd = 0.0123, 0.01
+
+    def fresh():
+        return jnp.asarray(w0), (jnp.zeros((rows, dim), jnp.float32),
+                                 jnp.zeros((rows, dim), jnp.float32))
+
+    grouped_mod.sparse_rows_update(opt, *fresh(), g, idx, valid, lr, wd)
+    weight, states = fresh()
+    with _profiler_session(tmp_path), \
+            jax.profiler.TraceAnnotation("test.rows_update"):
+        nw, ns = grouped_mod.sparse_rows_update(
+            opt, weight, states, g, idx, valid, lr, wd)
+    assert _launches_inside(_host_spans(tmp_path),
+                            "test.rows_update") == ["fn"]
+
+    kernel = jax.jit(grouped_mod._with_cast(
+        grouped_mod._rule_for(opt).make_kernel(opt, True), False))
+    ref_w, ref_s = fresh()
+    u = idx[:5]
+    kw, ks = kernel(jnp.take(ref_w, u, axis=0), g[:5],
+                    tuple(jnp.take(a, u, axis=0) for a in ref_s),
+                    jnp.asarray(lr, jnp.float32),
+                    jnp.asarray(wd, jnp.float32),
+                    jnp.asarray(opt.rescale_grad, jnp.float32))
+    np.testing.assert_array_equal(np.asarray(nw),
+                                  np.asarray(ref_w.at[u].set(kw)))
+    for got, a, b in zip(ns, ref_s, ks):
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(a.at[u].set(b)))
+
+
 def test_step_touches_only_touched_rows(plane_on):
     plane, _ = _plane("t_touch", rows=32, dim=4, world=4)
     try:

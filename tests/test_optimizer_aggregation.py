@@ -172,6 +172,31 @@ def test_dispatch_count_regression(monkeypatch):
     assert one(1) == 50                   # degenerate cap still works
 
 
+@pytest.mark.parametrize("opt,kw,dtype", [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9,
+             "multi_precision": True}, "bfloat16"),
+    ("adam", {"learning_rate": 0.01, "wd": 0.001}, "float32"),
+], ids=["sgd-mom-mp-bf16", "adam"])
+def test_update_launches_only_its_bucket_programs(opt, kw, dtype,
+                                                  monkeypatch, tmp_path):
+    """What a warm ``Trainer.step`` launches, read from the profiler's host
+    plane and not from the framework's own counter: one program a bucket,
+    and no ``convert_element_type`` for a learning rate, a weight decay or
+    ``rescale_grad`` (as ``jnp.asarray(list, float32)`` each of those was a
+    put and a cast program: two a bucket and one a step)."""
+    from test_step_spans import _host_spans, _launches_inside, \
+        _profiler_session
+    params, tr = _run_steps(opt, kw, 4, monkeypatch, steps=2, dtype=dtype,
+                            n=10)
+    _set_grads(params, np.random.RandomState(1))
+    with _profiler_session(tmp_path):
+        tr.step(4)
+    launched = _launches_inside(_host_spans(tmp_path), "mx.trainer.update")
+    assert tr.last_update_dispatches == 3     # ceil(10 / 4) buckets
+    assert len(launched) == tr.last_update_dispatches, launched
+    assert not [n for n in launched if "convert_element_type" in n]
+
+
 def test_signature_cache_no_per_step_recompile(monkeypatch):
     """Steady-state steps must HIT the signature cache (the CachedOp
     discipline): changing lr / rescale between steps may not mint new
@@ -194,6 +219,31 @@ def test_signature_cache_no_per_step_recompile(monkeypatch):
     assert info.misses == misses0, \
         "per-step lr/batch churn recompiled the bucket program"
     assert info.hits >= 4
+
+
+def test_adam_bias_corrected_lr_mints_no_program(monkeypatch):
+    """Adam's bias-corrected learning rate is another float every step: it
+    travels as a value of the same ``float32[n]`` argument, so steps two and
+    three find the signature cache and the compiler where step one left
+    them."""
+    from mxnet_tpu import telemetry
+    monkeypatch.setenv("MXTPU_OPTIMIZER_AGGREGATION", "4")
+    grouped_mod.clear_cache()
+    rs = np.random.RandomState(0)
+    params = _make_params(rs, n=6)
+    tr = gluon.Trainer(params, "adam", {"learning_rate": 0.01, "wd": 0.001},
+                       kvstore=None)
+    _set_grads(params, rs)
+    tr.step(4)
+    reg = telemetry.default_registry()
+    misses0 = grouped_mod.cache_info().misses
+    compiles0 = reg.render_json().get("mxtpu_xla_compile_total", 0)
+    assert misses0 >= 1
+    for _ in range(2):
+        _set_grads(params, rs)
+        tr.step(4)
+    assert grouped_mod.cache_info().misses == misses0
+    assert reg.render_json().get("mxtpu_xla_compile_total", 0) == compiles0
 
 
 def test_sparse_params_bypass_aggregation(monkeypatch):

@@ -4,6 +4,7 @@ and that it follows a JAX profiler session into the device trace.
 
 Marker ``telemetry`` — tier-1-safe: CPU, in-process, tiny nets.
 """
+import contextlib
 import time
 
 import jax
@@ -268,6 +269,19 @@ def test_xplane_names():
 # ---------------------------------------------------------------------------
 # following a profiler session
 
+@contextlib.contextmanager
+def _profiler_session(logdir):
+    """A JAX profiler session that writes its trace under ``logdir``, with
+    the Python tracer off (the host plane then holds TraceMe events only)."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
 def _host_spans(logdir):
     from jax.profiler import ProfileData
     files = sorted(logdir.rglob("*.xplane.pb"))
@@ -281,20 +295,32 @@ def _host_spans(logdir):
     return spans
 
 
+def _launches_inside(spans, name):
+    """Names of the jitted calls the host made inside the one span called
+    ``name``: what is launched, whatever the framework's counters say. The
+    runtime writes each call as two nested ``PjitFunction(...)`` events, so
+    only the outermost count."""
+    (_, lo, hi), = [s for s in spans if s[0] == name]
+    calls = sorted((s for s in spans if s[0].startswith("PjitFunction(")
+                    and lo <= s[1] and s[2] <= hi),
+                   key=lambda s: (s[1], -s[2]))
+    outer, end = [], lo
+    for call, start, stop in calls:
+        if start >= end:
+            outer.append(call[len("PjitFunction("):-1])
+            end = stop
+    return outer
+
+
 def test_tracer_follows_a_profiler_session_into_the_xplane(
         loop, tmp_path, monkeypatch):
     monkeypatch.delenv("MXTPU_PROFILE", raising=False)
     loop.step()
     assert tracer.events() == [] and not tracer.enabled
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
-    try:
+    with _profiler_session(tmp_path):
         assert tracer.enabled
         with jax.profiler.TraceAnnotation("test.dispatch"):
             loop.step()
-    finally:
-        jax.profiler.stop_trace()
     assert not tracer.enabled, "the tracer is what it was before the session"
     recorded = tracer.events()
     assert len(_named(recorded, "mx.trainer.step")) == 1
