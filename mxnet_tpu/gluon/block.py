@@ -281,6 +281,42 @@ class HybridBlock(Block):
             return self._cached_op(*args)
         return self._imperative_call(*args)
 
+    def remat_call(self, *args):
+        """Call this block so that the backward pass recomputes it
+        (``jax.checkpoint``): of what the block computes only its inputs,
+        and arrays named "mx.attention" (an attention kernel's output and
+        log-sum-exp), are kept. For a child called inside a traced whole
+        (``SPMDTrainer``'s step, a hybridized parent). The block's
+        parameters enter as arguments, and what the forward rebinds (a
+        BatchNorm's running statistics, a router's bias) leaves as results
+        and is rebound outside, so no tracer escapes the checkpoint."""
+        import jax
+        from ..ndarray.ndarray import NDArray, from_jax
+        params = [p for p in self.collect_params().values()
+                  if p._data is not None]
+        aux = [p for p in params if p.grad_req == "null"]
+
+        def pure(arrays, xs):
+            saved = [p._data._data for p in params]
+            for p, a in zip(params, arrays):
+                p._data._data = a
+            try:
+                out = self(*[from_jax(x) for x in xs])
+                return (jax.tree_util.tree_map(
+                    lambda o: o._data, out,
+                    is_leaf=lambda o: isinstance(o, NDArray)),
+                    [p._data._data for p in aux])
+            finally:
+                for p, a in zip(params, saved):
+                    p._data._data = a
+
+        policy = jax.checkpoint_policies.save_only_these_names("mx.attention")
+        out, rebound = jax.checkpoint(pure, policy=policy)(
+            [p._data._data for p in params], [a._data for a in args])
+        for p, a in zip(aux, rebound):
+            p._data._data = a
+        return jax.tree_util.tree_map(from_jax, out)
+
     def _collect_deferred_check(self) -> None:
         for _, p in self.collect_params().items():
             if p._data is None:

@@ -16,6 +16,7 @@ from . import optimizer_ops  # noqa: F401
 from . import rnn_op  # noqa: F401
 from . import linalg  # noqa: F401
 from . import pallas_kernels  # noqa: F401
+from . import lm_ops  # noqa: F401
 from . import quantization  # noqa: F401
 from . import ctc  # noqa: F401
 from . import contrib_ops  # noqa: F401

@@ -119,6 +119,251 @@ def _flash_attention_op(q, k, v, causal=False, scale=None):
     return flash_attention(q, k, v, causal=causal, scale=scale)
 
 
+# ---------------------------------------------------------------------------
+# Blocked attention: both passes tiled over queries AND keys, so no
+# (T, T) score matrix exists for a head in either direction and VMEM holds
+# one (block, block) tile of logits whatever T is. Causal key blocks above
+# the diagonal are skipped (no compute, and the index map re-names the block
+# already held, so no copy either). The backward is two kernels: dK/dV walk
+# the query blocks for one key block (logits transposed, so the saved
+# log-sum-exp broadcasts as a row), dQ walks the key blocks for one query
+# block. Query/key head size and value head size may differ (MLA: 256/256,
+# with the 64 rope dimensions shared by all heads already concatenated).
+# ---------------------------------------------------------------------------
+
+_MASKED = -0.7 * 3.0e38  # not -inf: exp(-inf - -inf) is NaN
+
+
+def _attention_block(t: int) -> int:
+    """Rows of a query or key block: the largest of 512/256/128 that
+    divides T (512x512 float32 logits are 1 MB of VMEM); a T they do not
+    divide is one block (the small shapes of the tests)."""
+    for b in (512, 256, 128):
+        if t % b == 0:
+            return b
+    return t
+
+
+@functools.lru_cache(maxsize=None)
+def _build_blocked_attention(t: int, dk: int, dv: int, causal: bool,
+                             scale: float, dtype: str, interpret: bool):
+    """(fwd, bwd) over (BH, T, dk) queries and keys and (BH, T, dv)
+    values. ``fwd(q, k, v) -> (o, lse)`` with ``lse`` (BH, T) float32;
+    ``bwd(q, k, v, o, lse, do) -> (dq, dk, dv)``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    blk = _attention_block(t)
+    n = t // blk
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))  # a @ b.T
+
+    def dot(a, b, dims=(((1,), (0,)), ((), ()))):
+        return jax.lax.dot_general(a, b, dims, preferred_element_type=f32)
+
+    def logits(a, b, transposed, on_diagonal):
+        """a @ b.T * scale, (blk, blk); on the diagonal block the entries
+        whose key comes after their query are masked. ``transposed``: rows
+        are keys."""
+        s = dot(a, b, nt) * scale
+        if on_diagonal:
+            r = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+            c = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+            s = jnp.where(r <= c if transposed else r >= c, s, _MASKED)
+        return s
+
+    def on_blocks(q_blk, k_blk, body):
+        """Run ``body(on_diagonal)`` where the (query, key) block pair has
+        anything unmasked."""
+        if not causal:
+            body(False)
+            return
+        pl.when(k_blk < q_blk)(lambda: body(False))
+        pl.when(k_blk == q_blk)(lambda: body(True))
+
+    # blocks named by (head, own block, walked block); the walked index is
+    # clamped to the causal range so a skipped step copies nothing
+    def own(width):
+        return pl.BlockSpec((1, blk, width), lambda b, i, j: (b, i, 0))
+
+    def walked_keys(width):
+        return pl.BlockSpec(
+            (1, blk, width),
+            (lambda b, i, j: (b, jnp.minimum(i, j), 0)) if causal
+            else (lambda b, i, j: (b, j, 0)))
+
+    def walked_queries(shape, index):
+        clamp = (lambda i, j: jnp.maximum(i, j)) if causal \
+            else (lambda i, j: j)
+        return pl.BlockSpec(shape, lambda b, i, j: index(b, clamp(i, j)))
+
+    column = pl.BlockSpec((1, blk, 1), lambda b, i, j: (b, i, 0))
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    kw = dict(interpret=interpret, compiler_params=params) \
+        if params is not None else dict(interpret=interpret)
+
+    # ---- forward ---------------------------------------------------------
+    def fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s):
+        qi, ki = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(ki == 0)
+        def _():
+            m_s[...] = jnp.full_like(m_s, -jnp.inf)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        def update(on_diagonal):
+            s = logits(q_ref[0], k_ref[0], False, on_diagonal)
+            m_prev = m_s[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
+            acc_s[...] = alpha * acc_s[...] + dot(p.astype(v_ref.dtype),
+                                                  v_ref[0])
+            m_s[...] = m_new
+
+        on_blocks(qi, ki, update)
+
+        @pl.when(ki == (qi if causal else n - 1))
+        def _():
+            o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+            lse_ref[0] = m_s[...] + jnp.log(l_s[...])
+
+    def fwd(q, k, v):
+        bh = q.shape[0]
+        o, lse = pl.pallas_call(
+            fwd_kernel, grid=(bh, n, n),
+            in_specs=[own(dk), walked_keys(dk), walked_keys(dv)],
+            out_specs=[own(dv), column],
+            out_shape=[jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
+                       jax.ShapeDtypeStruct((bh, t, 1), f32)],
+            scratch_shapes=[pltpu.VMEM((blk, 1), f32),
+                            pltpu.VMEM((blk, 1), f32),
+                            pltpu.VMEM((blk, dv), f32)],
+            name="mx_attention_fwd", **kw)(q, k, v)
+        return o, lse[..., 0]
+
+    # ---- backward: dQ ----------------------------------------------------
+    def dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                  acc_s):
+        qi, ki = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(ki == 0)
+        def _():
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        def update(on_diagonal):
+            s = logits(q_ref[0], k_ref[0], False, on_diagonal)
+            p = jnp.exp(s - lse_ref[0])
+            dp = dot(do_ref[0], v_ref[0], nt)
+            ds = p * (dp - delta_ref[0]) * scale
+            acc_s[...] += dot(ds.astype(k_ref.dtype), k_ref[0])
+
+        on_blocks(qi, ki, update)
+
+        @pl.when(ki == (qi if causal else n - 1))
+        def _():
+            dq_ref[0] = acc_s[...].astype(dq_ref.dtype)
+
+    # ---- backward: dK, dV (rows are keys) ---------------------------------
+    def dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                   dv_ref, dk_s, dv_s):
+        ki, qi = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(qi == (ki if causal else 0))
+        def _():
+            dk_s[...] = jnp.zeros_like(dk_s)
+            dv_s[...] = jnp.zeros_like(dv_s)
+
+        def update(on_diagonal):
+            s = logits(k_ref[0], q_ref[0], True, on_diagonal)
+            p = jnp.exp(s - lse_ref[0])                  # lse: a (1, blk) row
+            dv_s[...] += dot(p.astype(do_ref.dtype), do_ref[0])
+            dp = dot(v_ref[0], do_ref[0], nt)
+            ds = p * (dp - delta_ref[0]) * scale
+            dk_s[...] += dot(ds.astype(q_ref.dtype), q_ref[0])
+
+        on_blocks(qi, ki, update)
+
+        @pl.when(qi == n - 1)
+        def _():
+            dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+    def bwd(q, k, v, o, lse, do):
+        bh = q.shape[0]
+        delta = jnp.sum(o.astype(f32) * do.astype(f32), axis=-1)   # (BH, T)
+        dq = pl.pallas_call(
+            dq_kernel, grid=(bh, n, n),
+            in_specs=[own(dk), walked_keys(dk), walked_keys(dv), own(dv),
+                      column, column],
+            out_specs=own(dk),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((blk, dk), f32)],
+            name="mx_attention_dq", **kw)(
+                q, k, v, do, lse[..., None], delta[..., None])
+        row = walked_queries((1, 1, blk), lambda b, j: (b, 0, j))
+        dk_, dv_ = pl.pallas_call(
+            dkv_kernel, grid=(bh, n, n),
+            in_specs=[walked_queries((1, blk, dk), lambda b, j: (b, j, 0)),
+                      own(dk), own(dv),
+                      walked_queries((1, blk, dv), lambda b, j: (b, j, 0)),
+                      row, row],
+            out_specs=[own(dk), own(dv)],
+            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            scratch_shapes=[pltpu.VMEM((blk, dk), f32),
+                            pltpu.VMEM((blk, dv), f32)],
+            name="mx_attention_dkv", **kw)(
+                q, k, v, do, lse[:, None, :], delta[:, None, :])
+        return dq, dk_, dv_
+
+    return fwd, bwd
+
+
+def blocked_attention(q, k, v, causal: bool = True, scale=None):
+    """Softmax attention blocked over queries and keys in both passes.
+
+    q, k: (BH, T, dk); v: (BH, T, dv) -> (BH, T, dv). Differentiable; the
+    backward keeps the output and the (BH, T) log-sum-exp and recomputes the
+    probabilities block by block. Under ``jax.checkpoint`` with
+    ``save_only_these_names("mx.attention")`` those two are what a layer
+    keeps, so a recomputed layer does not run the forward kernel again.
+    """
+    import jax
+    from jax.ad_checkpoint import checkpoint_name
+
+    _, t, dk = q.shape
+    sc = float(scale) if scale is not None else 1.0 / math.sqrt(dk)
+    fwd, bwd = _build_blocked_attention(
+        t, dk, v.shape[-1], bool(causal), sc, str(q.dtype), _interpret_for(q))
+
+    @jax.custom_vjp
+    def op(q, k, v):
+        return fwd(q, k, v)[0]
+
+    def op_fwd(q, k, v):
+        o, lse = fwd(q, k, v)
+        o = checkpoint_name(o, "mx.attention")
+        lse = checkpoint_name(lse, "mx.attention")
+        return o, (q, k, v, o, lse)
+
+    def op_bwd(res, do):
+        return bwd(*res, do)
+
+    op.defvjp(op_fwd, op_bwd)
+    return op(q, k, v)
+
+
+@register("_contrib_blocked_attention")
+def _blocked_attention_op(q, k, v, causal=True, scale=None):
+    return blocked_attention(q, k, v, causal=causal, scale=scale)
+
+
 @register("_contrib_interleaved_matmul_selfatt_qk")
 def _interleaved_qk(qkv, heads=1):
     """(ref: src/operator/contrib/transformer.cc interleaved matmul helpers)

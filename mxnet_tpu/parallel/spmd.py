@@ -76,7 +76,7 @@ class SPMDTrainer:
             # with CPU-committed params) and cast low precision up so
             # conv dtype checks don't trip
             probe = jnp.asarray(_np.asarray(sample_data))
-            if probe.dtype != jnp.float32:
+            if jnp.issubdtype(probe.dtype, jnp.floating):
                 probe = probe.astype(jnp.float32)
             with autograd.pause():
                 self.block._imperative_call(from_jax(probe))
@@ -187,8 +187,12 @@ class SPMDTrainer:
                 prev_r = autograd.set_recording(False)
                 prev_t = autograd.set_training(True)
                 try:
-                    x = from_jax(data if compute_dtype is None
-                                 else data.astype(compute_dtype))
+                    # only floating inputs take the compute dtype: token
+                    # ids do not survive bfloat16
+                    x = from_jax(data.astype(compute_dtype)
+                                 if compute_dtype is not None and
+                                 jnp.issubdtype(data.dtype, jnp.floating)
+                                 else data)
                     out = block._imperative_call(x)
                     loss = loss_fn(out, from_jax(label))
                     loss_val = jnp.mean(loss._data.astype(jnp.float32))

@@ -1046,6 +1046,10 @@ COVERED_ELSEWHERE = {
     **{op: "tests/test_pallas_ops.py" for op in [
         "_contrib_flash_attention", "_contrib_interleaved_matmul_selfatt_qk",
         "_contrib_interleaved_matmul_selfatt_valatt"]},
+    # the sparse latent-attention language model's ops (ops/lm_ops.py)
+    **{op: "tests/test_glm_moe_lite.py" for op in [
+        "_contrib_rms_norm", "_contrib_swiglu_ffn", "_contrib_mla_attention",
+        "_contrib_dropless_moe", "_contrib_blocked_attention"]},
     # pallas fused conv epilogues (fwd+grad parity, fallback, fold)
     **{op: "tests/test_fused_epilogue.py" for op in [
         "_contrib_fused_bn_relu", "_contrib_fused_bn_add_relu"]},

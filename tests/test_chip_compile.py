@@ -125,6 +125,26 @@ def test_flash_attention_compiles_for_v5e(one_chip, bh, t, d, dtype, causal):
     assert _n_kernels(compiled) == 1
 
 
+@pytest.mark.parametrize("bh,t,dk,dv,dtype,causal", [
+    (20, 8192, 256, 256, "bfloat16", True),    # GLM-4.7-Flash's MLA at 8k
+    (20, 2048, 256, 256, "bfloat16", True),
+    (8, 1024, 128, 128, "bfloat16", False),
+    (4, 4096, 192, 128, "float32", True),
+])
+def test_blocked_attention_compiles_for_v5e(one_chip, bh, t, dk, dv, dtype,
+                                            causal):
+    """The three kernels of ``blocked_attention`` (forward, dQ, dK/dV) at
+    the benchmark's widths: 512x512 tiles at head size 256 fit VMEM."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    fwd, bwd = pk._build_blocked_attention(t, dk, dv, causal, dk ** -0.5,
+                                           dtype, False)
+    q = jax.ShapeDtypeStruct((bh, t, dk), jnp.dtype(dtype), sharding=one_chip)
+    v = jax.ShapeDtypeStruct((bh, t, dv), jnp.dtype(dtype), sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, t), jnp.float32, sharding=one_chip)
+    assert _n_kernels(jax.jit(fwd).lower(q, q, v).compile()) == 1
+    assert _n_kernels(jax.jit(bwd).lower(q, q, v, v, lse, v).compile()) == 2
+
+
 @pytest.mark.parametrize("n_tiles,n_f32", [(1, 2), (2, 2), (3, 4), (5, 3)])
 @pytest.mark.parametrize("c,itemsize", [(64, 2), (1024, 2), (2048, 2),
                                         (256, 4)])
