@@ -18,6 +18,7 @@ from __future__ import annotations
 import ast
 import logging
 import os
+import sys
 import threading
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -247,13 +248,19 @@ env.declare("MXTPU_FLEET_HEARTBEAT_MS", float, 200.0,
 env.declare("MXNET_HOME", str, "",
             "Root directory for datasets and model artifacts "
             "(default ~/.mxnet; ref: docs/faq/env_var.md MXNET_HOME).")
-env.declare("MXTPU_OPTIMIZER_AGGREGATION", int, 4,
+env.declare("MXTPU_OPTIMIZER_AGGREGATION", int, sys.maxsize,
             "Multi-tensor optimizer aggregation: dense parameters are "
-            "grouped into dtype/device buckets of up to this many params "
-            "and each bucket is stepped by ONE jitted program with "
-            "donated weight/state buffers (ref: the reference's "
-            "MXNET_OPTIMIZER_AGGREGATION_SIZE, default 4). 0 disables "
-            "(per-parameter updates).")
+            "grouped by bucket key (weight dtype, device placement, "
+            "multi-precision, state arity) and each key is stepped by "
+            "ONE jitted program with donated weight/state buffers, so "
+            "the count of update programs does not grow with the count "
+            "of parameters. Unset = no cap (the declared default is "
+            "sys.maxsize, a cap no model reaches). A positive value caps "
+            "a program at that many params (ref: the reference's "
+            "MXNET_OPTIMIZER_AGGREGATION_SIZE, whose default of 4 is a "
+            "CUDA kernel's argument-list limit and does not carry over "
+            "to a jitted XLA program). 0 disables (per-parameter "
+            "updates).")
 env.declare("MXTPU_GRAD_BUCKET_MB", float, 25.0,
             "Gradient-allreduce bucketing: Trainer.allreduce_grads "
             "concatenates same-dtype dense gradients into flat buffers "

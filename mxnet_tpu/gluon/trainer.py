@@ -7,7 +7,8 @@ allreduce_grads); without one, updates are local fused ops.
 
 Aggregated hot path (ref: optimizer_op.cc:654 multi_sgd_update +
 MXNET_OPTIMIZER_AGGREGATION_SIZE): dense parameters are grouped into
-dtype/device buckets of up to ``MXTPU_OPTIMIZER_AGGREGATION`` params and
+dtype/device buckets (whole by default; of up to
+``MXTPU_OPTIMIZER_AGGREGATION`` params where that is set) and
 each bucket is stepped by ONE jitted program with donated weight/state
 buffers (optimizer/grouped.py), so a step costs O(buckets) compiled-call
 launches instead of O(params). ``allreduce_grads`` likewise concatenates

@@ -14,6 +14,23 @@ program (``donate_argnums``) so the update stops double-buffering optimizer
 memory; gradients are NOT donated (they stay readable for the sentinel,
 chaos hooks and user inspection, exactly like the per-parameter path).
 
+The reference's cap of 4 parameters a launch does NOT carry over: it is the
+argument-list limit of a CUDA kernel, and a jitted XLA program has none
+(``SPMDTrainer.step`` updates every tensor inside one program). With
+``MXTPU_OPTIMIZER_AGGREGATION`` unset a bucket key is ONE program however
+many parameters it holds, so the count of update launches does not grow
+with the model: ResNet-50's 161 tensors were 41 launches at a cap of 4,
+more than the 32 programs the TPU client keeps in flight, and the host
+stalled behind the backward program every step. Nothing overlaps with a
+split update either: every gradient comes out of one backward program, and
+the ZeRO/overlap plane cuts its items by communication bucket before it
+calls :func:`grouped_update`. An explicit positive value still caps a
+program; ``0`` disables the grouped path. What the cap bought and the
+default gives up: under ``ignore_stale_grad=True`` a fresh set that changes
+from step to step is a new signature for the whole key and not for one
+bucket of four; the signature LRU (``MXTPU_CACHEDOP_CACHE_SIZE``) bounds
+what that keeps.
+
 The per-parameter update math is the SAME pure function the per-parameter
 ops use (``ops/optimizer_ops.py``), so the aggregated step is numerically
 the per-parameter step minus the dispatch overhead. The FitLoop
@@ -48,7 +65,9 @@ def _jnp():
 
 
 def aggregation_size() -> int:
-    """Bucket-size cap from ``MXTPU_OPTIMIZER_AGGREGATION`` (0 = off)."""
+    """Parameters-a-program cap from ``MXTPU_OPTIMIZER_AGGREGATION``: 0 =
+    grouped path off; unset = the declared default ``sys.maxsize``, which
+    no bucket reaches, so a bucket key is one program."""
     try:
         return int(env.get("MXTPU_OPTIMIZER_AGGREGATION"))
     except (TypeError, ValueError):
@@ -565,8 +584,10 @@ def prepare_update(updater, items):
 
 def chunk_prepared(prepared, agg_size: int):
     """Bucket ``prepared`` entries by (weight dtype, device placement,
-    mp-ness, state arity), capped at ``agg_size``, preserving parameter
-    order within a bucket. Pure function of the prepared structure —
+    mp-ness, state arity), preserving parameter order within a bucket;
+    a bucket longer than ``agg_size`` (only an explicit
+    ``MXTPU_OPTIMIZER_AGGREGATION`` is ever that small) is cut into
+    chunks of that many. Pure function of the prepared structure —
     the chunk layout is part of the megastep cache signature."""
     buckets: "OrderedDict[Tuple, List]" = OrderedDict()
     for ent in prepared:

@@ -75,7 +75,9 @@ _ADVICE = {
     "comm_overlapped": "comm-bound despite overlap: collectives outlast "
                        "backward — raise MXTPU_GRAD_BUCKET_MB or enable "
                        "gradient compression",
-    "optimizer": "update-bound: raise MXTPU_OPTIMIZER_AGGREGATION",
+    "optimizer": "update-bound: leave MXTPU_OPTIMIZER_AGGREGATION unset "
+                 "(one program a bucket key) and use a grouped optimizer "
+                 "(SGD, NAG, Adam, RMSProp)",
     "checkpoint": "ckpt-bound: raise ckpt_every or use async_ckpt=True",
 }
 

@@ -22,7 +22,7 @@ import jax
 import mxnet_tpu as mx
 from mxnet_tpu import fault, fit, gluon, io, nd
 from mxnet_tpu import kvstore as kvs
-from mxnet_tpu.base import MXNetError
+from mxnet_tpu.base import MXNetError, env
 from mxnet_tpu.contrib import chaos
 from mxnet_tpu.optimizer import grouped as grouped_mod
 from mxnet_tpu.telemetry import efficiency as eff
@@ -301,7 +301,8 @@ def test_env_default_valued_var_is_not_an_override(monkeypatch,
     monkeypatch.setenv("MXTPU_EFFICIENCY", "on")
     monkeypatch.setenv("MXTPU_RUN_REPORT_DIR", str(tmp_path))
     # SET to the declared default: not a configuration difference
-    monkeypatch.setenv("MXTPU_OPTIMIZER_AGGREGATION", "4")
+    monkeypatch.setenv("MXTPU_OPTIMIZER_AGGREGATION",
+                       str(env.default_for("MXTPU_OPTIMIZER_AGGREGATION")))
     res, _ = _fit(_mlp(), steps=2)
     fp = rrmod.load_run_report(res.run_report)["fingerprint"]
     assert "MXTPU_OPTIMIZER_AGGREGATION" not in fp["env_overrides"]

@@ -2,6 +2,8 @@
 path, dispatch-count regression, sparse bypass, bucketed allreduce
 (ref: optimizer_op.cc multi_sgd_update + MXNET_OPTIMIZER_AGGREGATION_SIZE;
 DDP-style gradient bucketing for the allreduce side)."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -14,10 +16,14 @@ from mxnet_tpu.optimizer import grouped as grouped_mod
 
 
 def _make_params(rs, n=6, dtype="float32", shapes=None):
+    """``dtype`` is one name, or a tuple of names the parameters cycle
+    through (mixed-precision nets: two bucket keys)."""
+    dtypes = (dtype,) if isinstance(dtype, str) else dtype
     params = []
     for j in range(n):
         shape = shapes[j] if shapes else (3, j + 2)
-        p = gluon.Parameter(f"p{j}", shape=shape, dtype=dtype)
+        p = gluon.Parameter(f"p{j}", shape=shape,
+                            dtype=dtypes[j % len(dtypes)])
         p.initialize(mx.init.Constant(0.0))
         p.set_data(nd.array(rs.randn(*shape).astype(np.float32)))
         params.append(p)
@@ -46,9 +52,18 @@ OPTS = [
 ]
 
 
+def _set_agg(monkeypatch, agg):
+    """``None`` leaves the variable unset: the default, one program a
+    bucket key."""
+    if agg is None:
+        monkeypatch.delenv("MXTPU_OPTIMIZER_AGGREGATION", raising=False)
+    else:
+        monkeypatch.setenv("MXTPU_OPTIMIZER_AGGREGATION", str(agg))
+
+
 def _run_steps(opt, kw, agg, monkeypatch, steps=3, dtype="float32", n=6,
                seed=0):
-    monkeypatch.setenv("MXTPU_OPTIMIZER_AGGREGATION", str(agg))
+    _set_agg(monkeypatch, agg)
     rs = np.random.RandomState(seed)
     params = _make_params(rs, n=n, dtype=dtype)
     tr = gluon.Trainer(params, opt, dict(kw), kvstore=None)
@@ -152,65 +167,171 @@ def test_skipped_fused_step_creates_no_state(monkeypatch):
     assert tr._optimizer.num_update == 0
 
 
-def test_dispatch_count_regression(monkeypatch):
-    """Acceptance: a 50-param model steps in O(buckets) compiled-call
-    launches with aggregation on, O(params) with
+@pytest.mark.parametrize("agg,launches", [
+    (0, 50),                              # O(params)
+    (4, 13),                              # ceil(50/4) buckets
+    (64, 1),                              # one bucket covers everything
+    (1, 50),                              # degenerate cap still works
+    (None, 1),                            # unset: one program a bucket key
+], ids=["off", "cap4", "cap64", "cap1", "unset"])
+def test_dispatch_count_regression(agg, launches, monkeypatch):
+    """Acceptance: a 50-param model steps in ONE compiled-call launch by
+    default, O(buckets) under an explicit cap, O(params) with
     MXTPU_OPTIMIZER_AGGREGATION=0."""
-    def one(agg):
-        monkeypatch.setenv("MXTPU_OPTIMIZER_AGGREGATION", str(agg))
-        rs = np.random.RandomState(0)
-        params = _make_params(rs, n=50, shapes=[(4, 4)] * 50)
-        tr = gluon.Trainer(params, "sgd", {"learning_rate": 0.1,
-                                           "momentum": 0.9}, kvstore=None)
-        _set_grads(params, rs)
-        tr.step(8)
-        return tr.last_update_dispatches
-
-    assert one(0) == 50                   # O(params)
-    assert one(4) == 13                   # ceil(50/4) buckets
-    assert one(64) == 1                   # one bucket covers everything
-    assert one(1) == 50                   # degenerate cap still works
+    _set_agg(monkeypatch, agg)
+    rs = np.random.RandomState(0)
+    params = _make_params(rs, n=50, shapes=[(4, 4)] * 50)
+    tr = gluon.Trainer(params, "sgd", {"learning_rate": 0.1,
+                                       "momentum": 0.9}, kvstore=None)
+    _set_grads(params, rs)
+    tr.step(8)
+    assert tr.last_update_dispatches == launches
 
 
-@pytest.mark.parametrize("opt,kw,dtype", [
-    ("sgd", {"learning_rate": 0.1, "momentum": 0.9,
-             "multi_precision": True}, "bfloat16"),
-    ("adam", {"learning_rate": 0.01, "wd": 0.001}, "float32"),
-], ids=["sgd-mom-mp-bf16", "adam"])
-def test_update_launches_only_its_bucket_programs(opt, kw, dtype,
-                                                  monkeypatch, tmp_path):
+def _state_arrays(tr):
+    """Every optimizer-state array of ``tr`` as numpy, by parameter index
+    (the f32 master weight of a multi-precision parameter included)."""
+    out = {}
+    for i, st in sorted(tr._updaters[0].states.items()):
+        flat = []
+        for part in (st if isinstance(st, (tuple, list)) else [st]):
+            flat += grouped_mod._flatten_inner(part)
+        out[i] = [a.asnumpy() for a in flat]
+    return out
+
+
+@pytest.mark.parametrize("opt,kw,dtype,per_param_exact", [
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9,
+             "multi_precision": True}, "bfloat16", True),
+    ("nag", {"learning_rate": 0.1, "momentum": 0.9}, "float32", True),
+    ("adam", {"learning_rate": 0.01, "wd": 0.001}, "float32", True),
+    ("rmsprop", {"learning_rate": 0.01, "centered": True}, "float32", True),
+    # the per-parameter SGD ops bake ``wd`` into their program as a
+    # constant and the grouped ones take it as an argument, so XLA rounds
+    # ``rescale * g + wd * w`` differently there (the last bit, at any
+    # cap): the groupings still agree with each other to the byte
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 0.01,
+             "multi_precision": True}, "bfloat16", False),
+], ids=["sgd-mom-mp-bf16", "nag", "adam-wd", "rmsprop-centered",
+        "sgd-mom-wd-mp-bf16"])
+def test_uncapped_default_is_bitwise_a_cap_of_four_and_per_param(
+        opt, kw, dtype, per_param_exact, monkeypatch):
+    """The grouping changes how many programs carry the update, never its
+    arithmetic: over three steps the unset default (one program), a cap of
+    4 (three programs) and ``0`` (the per-parameter loop) leave the same
+    bytes in every weight and every state array."""
+    runs = {agg: _run_steps(opt, kw, agg, monkeypatch, dtype=dtype, n=10)
+            for agg in (None, 4, 0)}
+    assert [runs[a][1].last_update_dispatches for a in (None, 4, 0)] \
+        == [1, 3, 10]
+    ref_params, ref_tr = runs[None]
+    ref_states = _state_arrays(ref_tr)
+    assert ref_states and all(ref_states.values())
+    for agg in (4, 0):
+        if agg or per_param_exact:
+            same = np.testing.assert_array_equal
+        else:
+            same = functools.partial(np.testing.assert_allclose, rtol=2e-6)
+        params, tr = runs[agg]
+        for pr, pg in zip(ref_params, params):
+            same(pr.data().astype("float32").asnumpy(),
+                 pg.data().astype("float32").asnumpy())
+        states = _state_arrays(tr)
+        assert states.keys() == ref_states.keys()
+        for i in states:
+            assert len(states[i]) == len(ref_states[i])
+            for a, b in zip(ref_states[i], states[i]):
+                same(a, b)
+
+
+_SGD_MP = {"learning_rate": 0.1, "momentum": 0.9, "multi_precision": True}
+
+
+@pytest.mark.parametrize("opt,kw,dtype,agg,programs", [
+    ("sgd", _SGD_MP, "bfloat16", 4, 3),   # ceil(10 / 4) buckets
+    ("adam", {"learning_rate": 0.01, "wd": 0.001}, "float32", 4, 3),
+    ("sgd", _SGD_MP, "bfloat16", None, 1),  # unset: one a bucket key
+    # bfloat16 parameters keep a float32 master and float32 ones do not:
+    # two keys, two programs
+    ("sgd", _SGD_MP, ("bfloat16", "float32"), None, 2),
+], ids=["sgd-mom-mp-bf16", "adam", "unset-one-dtype", "unset-two-dtypes"])
+def test_update_launches_only_its_bucket_programs(opt, kw, dtype, agg,
+                                                  programs, monkeypatch,
+                                                  tmp_path):
     """What a warm ``Trainer.step`` launches, read from the profiler's host
     plane and not from the framework's own counter: one program a bucket,
     and no ``convert_element_type`` for a learning rate, a weight decay or
     ``rescale_grad`` (as ``jnp.asarray(list, float32)`` each of those was a
-    put and a cast program: two a bucket and one a step)."""
+    put and a cast program: two a bucket and one a step). With the variable
+    unset a bucket is a whole bucket key, however many parameters."""
     from test_step_spans import _host_spans, _launches_inside, \
         _profiler_session
-    params, tr = _run_steps(opt, kw, 4, monkeypatch, steps=2, dtype=dtype,
+    params, tr = _run_steps(opt, kw, agg, monkeypatch, steps=2, dtype=dtype,
                             n=10)
     _set_grads(params, np.random.RandomState(1))
     with _profiler_session(tmp_path):
         tr.step(4)
     launched = _launches_inside(_host_spans(tmp_path), "mx.trainer.update")
-    assert tr.last_update_dispatches == 3     # ceil(10 / 4) buckets
+    assert tr.last_update_dispatches == programs
     assert len(launched) == tr.last_update_dispatches, launched
     assert not [n for n in launched if "convert_element_type" in n]
 
 
-def test_signature_cache_no_per_step_recompile(monkeypatch):
+@pytest.mark.parametrize("good_steps", [0, 2], ids=["first-step", "warm"])
+def test_sentinel_skip_with_one_bucket_leaves_every_byte(good_steps,
+                                                         monkeypatch):
+    """The sentinel's skip is all or nothing, and with the variable unset
+    "all" is one program: a poisoned step leaves every weight and every
+    state array byte for byte, and a poisoned FIRST step creates no
+    state."""
+    _set_agg(monkeypatch, None)
+    rs = np.random.RandomState(0)
+    params = _make_params(rs, n=7)
+    tr = gluon.Trainer(params, "adam", {"learning_rate": 0.01, "wd": 0.001},
+                       kvstore=None)
+    for _ in range(good_steps):
+        _set_grads(params, rs)
+        flag = tr.update_with_sentinel(4)
+        assert bool(jax.device_get(flag))
+        assert tr.last_update_dispatches == 2  # the flag and ONE bucket
+    weights = [p.data().asnumpy().copy() for p in params]
+    states = _state_arrays(tr)
+    counts = dict(tr._optimizer._index_update_count)
+    _set_grads(params, rs, poison_at=5)
+    flag = tr.update_with_sentinel(4)
+    assert flag is not None and not bool(jax.device_get(flag))
+    assert tr.last_update_dispatches == 2
+    tr.rollback_step()
+    for p, w in zip(params, weights):
+        np.testing.assert_array_equal(p.data().asnumpy(), w)
+    after = _state_arrays(tr)
+    assert after.keys() == states.keys()
+    assert bool(after) == bool(good_steps)  # no state out of a skipped step
+    for i in states:
+        for a, b in zip(states[i], after[i]):
+            np.testing.assert_array_equal(a, b)
+    assert {i: c for i, c in tr._optimizer._index_update_count.items()
+            if c} == counts
+    assert tr._optimizer.num_update == good_steps
+
+
+@pytest.mark.parametrize("agg,n", [(8, 6), (None, 50)],
+                         ids=["cap8", "unset"])
+def test_signature_cache_no_per_step_recompile(agg, n, monkeypatch):
     """Steady-state steps must HIT the signature cache (the CachedOp
     discipline): changing lr / rescale between steps may not mint new
-    compiled programs."""
-    monkeypatch.setenv("MXTPU_OPTIMIZER_AGGREGATION", "8")
+    compiled programs. With the variable unset one program of 50 bundles
+    is one signature."""
+    _set_agg(monkeypatch, agg)
     grouped_mod.clear_cache()
     rs = np.random.RandomState(0)
-    params = _make_params(rs, n=6)
+    params = _make_params(rs, n=n)
     tr = gluon.Trainer(params, "sgd", {"learning_rate": 0.1,
                                        "momentum": 0.9}, kvstore=None)
     _set_grads(params, rs)
     tr.step(4)
     misses0 = grouped_mod.cache_info().misses
-    assert misses0 >= 1
+    assert misses0 == 1
     for step in range(4):
         tr.set_learning_rate(0.1 / (step + 2))  # scheduled-lr churn
         _set_grads(params, rs)

@@ -184,6 +184,17 @@ def test_update_programs_equal_last_update_dispatches(loop):
     assert allreduce["collectives"] == loop.trainer.last_allreduce_collectives
 
 
+def test_update_span_reports_one_bucket_by_default(loop, monkeypatch):
+    """With ``MXTPU_OPTIMIZER_AGGREGATION`` unset the loop's four parameters
+    (one dtype, one device, one state arity) are one bucket key and so one
+    program, and the span says what the trainer counted."""
+    monkeypatch.delenv("MXTPU_OPTIMIZER_AGGREGATION", raising=False)
+    events = _traced(loop.step)
+    update = _named(events, "mx.trainer.update")[0]["args"]
+    assert update["buckets"] == 1
+    assert update["programs"] == loop.trainer.last_update_dispatches == 1
+
+
 def test_backward_counts_nodes_grads_and_its_own_launches(loop):
     events = _traced(loop.step)
     backward = _named(events, "mx.autograd.backward")[0]["args"]
