@@ -956,90 +956,6 @@ def _zero_overlap_probe(steps=8, batch=16, width=32, world=2):
     }
 
 
-def _megastep_probe(steps=8, batch=16, width=32):
-    """The `megastep` row: ``MXTPU_MEGASTEP=on`` vs the composed path on
-    the same non-hybridized FitLoop workload. The fused leg traces
-    forward + backward + sentinel + grouped update into ONE jitted
-    donated-buffer program, so a warm step is a single dispatch; the row
-    carries warm steps/s and MFU for both legs plus the two structural
-    pins: ``parity`` (the loss trajectories are bitwise EQUAL — the
-    fused program is the composed step's kernels minus the dispatches,
-    see tests/test_megastep.py for the full 6-optimizer matrix) and
-    ``unattributed_dispatches == 0`` (the one noted program resolves its
-    own cost). steps/s uses the warm median: the fused leg pays one cold
-    trace per fresh net, and the median is the number the knob is sold
-    on."""
-    import numpy as np
-    import mxnet_tpu as mx
-    from mxnet_tpu import gluon, io as mxio
-    from mxnet_tpu.fit import FitLoop
-    from mxnet_tpu.telemetry import efficiency as eff
-
-    saved = {k: os.environ.get(k) for k in
-             ("MXTPU_MEGASTEP", "MXTPU_OPTIMIZER_AGGREGATION",
-              "MXTPU_EFFICIENCY", "MXTPU_ZERO", "MXTPU_ZERO_WORLD",
-              "MXTPU_COMM_OVERLAP")}
-
-    def one(mega):
-        os.environ["MXTPU_MEGASTEP"] = "on" if mega else "off"
-        os.environ["MXTPU_OPTIMIZER_AGGREGATION"] = "8"
-        os.environ["MXTPU_EFFICIENCY"] = "on"
-        for k in ("MXTPU_ZERO", "MXTPU_ZERO_WORLD", "MXTPU_COMM_OVERLAP"):
-            os.environ.pop(k, None)
-        mx.random.seed(0)
-        rs = np.random.RandomState(0)
-        net = gluon.nn.Sequential()
-        net.add(gluon.nn.Dense(width, activation="relu"),
-                gluon.nn.Dense(8))
-        net.initialize(mx.init.Xavier())
-        data = rs.randn(steps * batch, width).astype(np.float32)
-        label = rs.randn(steps * batch, 8).astype(np.float32)
-        it = mxio.NDArrayIter(data, label, batch_size=batch)
-        tr = gluon.Trainer(net.collect_params(), "adam",
-                           {"learning_rate": 1e-3})
-        loop = FitLoop(net, tr, lambda out, y: ((out - y) ** 2).mean(),
-                       it, ckpt_dir=None)
-        res = loop.fit(epochs=1)
-        bd = res.step_breakdown or {}
-        e = res.efficiency or {}
-        warm = sorted(rec.get("wall", 0.0)
-                      for rec in (bd.get("per_step") or [])[1:])
-        p50_s = warm[len(warm) // 2] if warm else 0.0
-        recs = [r for r in eff.rollup().recent if r.get("step", 0) >= 1]
-        return {
-            "losses": list(res.losses),
-            "p50_s": p50_s,
-            "mfu": float(e.get("mfu", 0.0)),
-            "flops_per_step": float(e.get("flops_per_step", 0.0)),
-            "unattributed": int(e.get("unattributed_dispatches", -1)),
-            "warm_dispatches": (max(r.get("dispatches", 0) for r in recs)
-                                if recs else -1),
-        }
-
-    try:
-        one(False), one(True)              # warm both legs' programs
-        composed = one(False)
-        fused = one(True)
-    finally:
-        for k, v in saved.items():
-            os.environ.pop(k, None)
-            if v is not None:
-                os.environ[k] = v
-    return {
-        "parity": composed["losses"] == fused["losses"],
-        "steps_per_s_composed": round(
-            1.0 / composed["p50_s"], 2) if composed["p50_s"] else 0.0,
-        "steps_per_s_megastep": round(
-            1.0 / fused["p50_s"], 2) if fused["p50_s"] else 0.0,
-        "mfu_composed": composed["mfu"],
-        "mfu_megastep": fused["mfu"],
-        "flops_per_step_composed": composed["flops_per_step"],
-        "flops_per_step_megastep": fused["flops_per_step"],
-        "warm_dispatches_per_step": fused["warm_dispatches"],
-        "unattributed_dispatches": fused["unattributed"],
-    }
-
-
 def _comm_health_probe(steps=3, width=32, n_params=8, world=4):
     """The `comm_health` row: the collective-observability plane over a
     simulated N-rank ZeRO run — ledger depth, max cross-rank collective
@@ -1806,13 +1722,6 @@ def _run_child(mode, args_rest):
                       flush=True)
             except Exception as e:
                 log(f"zero overlap probe failed: {e}")
-        if os.environ.get("MXTPU_BENCH_MEGASTEP", "1") != "0":
-            try:
-                msrow = _megastep_probe()
-                print("EXTRA_ROW " + json.dumps({"megastep": msrow}),
-                      flush=True)
-            except Exception as e:
-                log(f"megastep probe failed: {e}")
         if os.environ.get("MXTPU_BENCH_COMM_HEALTH", "1") != "0":
             try:
                 crow = _comm_health_probe()
@@ -2075,11 +1984,6 @@ def main():
                 # step time strictly below the barrier plane's with the
                 # moved launches visible under comm_overlapped, MFU held
                 payload["zero_overlap"] = _EXTRAS["zero_overlap"]
-            if "megastep" in _EXTRAS:
-                # the one-program-step evidence: warm steps/s + MFU for
-                # the fused vs composed legs, bitwise loss parity, and a
-                # fully attributed single dispatch per warm step
-                payload["megastep"] = _EXTRAS["megastep"]
             if "comm_health" in _EXTRAS:
                 # the comm-observability evidence: collective-ledger
                 # depth, cross-rank skew and a zero watchdog count on a
@@ -2167,7 +2071,6 @@ def main():
                                    "MXTPU_BENCH_MEMORY": "0",
                                    "MXTPU_BENCH_ZERO": "0",
                                    "MXTPU_BENCH_ZERO_OVERLAP": "0",
-                                   "MXTPU_BENCH_MEGASTEP": "0",
                                    "MXTPU_BENCH_COMM_HEALTH": "0",
                                    "MXTPU_BENCH_NUMERICS": "0",
                                    "MXTPU_BENCH_EFFICIENCY": "0",

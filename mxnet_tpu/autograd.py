@@ -325,14 +325,7 @@ def _vjp_call(node: _TapeNode, cotangents: Tuple):
             _VJP_CACHE[key] = fn
         except Exception:
             fn = run
-    out = fn(node.input_vals, cotangents)
-    from .ops import registry as _reg
-    if _reg.op_islands_active():
-        # whole-step trace (megastep): each vjp is its own compiled
-        # program eagerly; the island barrier keeps it the same isolated
-        # fusion region inline, so the reverse pass stays bitwise
-        out = _reg._island(out)
-    return out
+    return fn(node.input_vals, cotangents)
 
 
 def _toposort(root_nodes: List[_TapeNode]) -> List[_TapeNode]:

@@ -309,24 +309,6 @@ env.declare("MXTPU_MEM_DUMP_DIR", str, "",
             "Directory memory-forensics dumps are written to "
             "(mem_forensics_<pid>_<n>.json). Empty (default) = the "
             "current working directory.")
-env.declare("MXTPU_MEGASTEP", str, "off",
-            "One-program training step (megastep.py): 'on' makes "
-            "fit.FitLoop trace forward + backward + the finiteness "
-            "sentinel + the grouped optimizer update (and, under a "
-            "simulated ZeRO group, the in-graph loopback collectives) "
-            "into ONE jitted program per (signature, world) with donated "
-            "weight/grad/state buffers — a warm step is exactly one "
-            "dispatched program (ref: the reference GraphExecutor running "
-            "the whole symbolic step as one graph, PAPER.md §6b). "
-            "Bitwise-identical trajectories to the composed path, "
-            "including the where-guarded non-finite skip and loss-scale "
-            "backoff. Supersedes MXTPU_COMM_OVERLAP (XLA schedules the "
-            "overlap inside the program). Non-composable configurations "
-            "— gradient compression, sparse params, a non-grouped "
-            "optimizer, MXTPU_OPTIMIZER_AGGREGATION=0, a real "
-            "multi-worker group, ignore_stale_grad, skip_nonfinite=False "
-            "— raise loudly instead of silently falling back. Unknown "
-            "values raise.")
 env.declare("MXTPU_ZERO", str, "off",
             "ZeRO-1 sharded optimizer state (parallel/zero.py): 'on' "
             "replaces the bucketed gradient allreduce with a per-bucket "

@@ -290,13 +290,9 @@ class _RWLock:
 def trace_rw_for(block) -> "_RWLock":
     """The block's shared trace lock, creating and stashing it on first
     use — the SAME instance every CachedOp wrapping ``block`` guards its
-    storage-swapping traces with, so an outside tracer (the one-program
-    megastep swaps every Parameter/grad/state storage to input tracers)
-    excludes concurrent forward traces over the same Parameters by
-    taking this lock's write side. Falls back to a fresh private lock
-    for slotted/exotic blocks that refuse the attribute stash (no shared
-    Parameters can be traced concurrently through CachedOp then either —
-    it falls back identically)."""
+    storage-swapping traces with, so two CachedOps of one block never
+    trace over the same Parameters at once. Falls back to a fresh private
+    lock for slotted/exotic blocks that refuse the attribute stash."""
     rw = getattr(block, "_mxtpu_trace_rw", None)
     if rw is None:
         rw = _RWLock()
@@ -595,7 +591,7 @@ class CachedOp:
     # signature's compiled XLA executable (jax.experimental.
     # serialize_executable) next to its cache key; aot_load deserializes
     # them into pre-warmed cache entries on a fingerprint-matched runtime.
-    AOT_FORMAT = 1
+    AOT_FORMAT = 2  # 2: entries carry their executable's device ids
 
     def aot_export(self, path: str) -> int:
         """Serialize the warm, inference-facing signature entries to
@@ -640,10 +636,16 @@ class CachedOp:
                                              *sds(in_sig))
             finally:
                 self._trace_rw.release_write()
-            payload, in_tree, out_tree = serialize(lowered.compile())
+            compiled = lowered.compile()
+            payload, in_tree, out_tree = serialize(compiled)
             records.append({
                 "key": pickle.dumps(key_sig),
                 "payload": payload,
+                # the devices the executable was compiled for: loading it
+                # over every local device of the replica (the loader's
+                # default) makes each call expect one shard a device
+                "device_ids": [d.id for d in compiled.runtime_executable()
+                               .local_devices()],
                 "in_tree": pickle.dumps(in_tree),
                 "out_tree": pickle.dumps(out_tree),
                 "mutated_idx": entry.mutated_idx,
@@ -667,6 +669,7 @@ class CachedOp:
         never crash the replica."""
         import pickle
 
+        import jax
         from .log import get_logger
         from .serving.aot import runtime_fingerprint
         log = get_logger("mxnet_tpu.cached_op")
@@ -694,12 +697,14 @@ class CachedOp:
                         bundle.get("fingerprint"), fp)
             return 0
         loaded = 0
+        by_id = {d.id: d for d in jax.devices()}
         for rec in bundle.get("entries", ()):
             try:
                 key_sig = pickle.loads(rec["key"])
-                exe = deserialize_and_load(rec["payload"],
-                                           pickle.loads(rec["in_tree"]),
-                                           pickle.loads(rec["out_tree"]))
+                exe = deserialize_and_load(
+                    rec["payload"], pickle.loads(rec["in_tree"]),
+                    pickle.loads(rec["out_tree"]),
+                    execution_devices=[by_id[i] for i in rec["device_ids"]])
                 entry = _CacheEntry()
                 entry.jitted = exe
                 entry.mutated_idx = tuple(rec["mutated_idx"])

@@ -37,7 +37,6 @@ from .base import MXNetError, check, env
 from .log import get_logger
 from . import fault
 from .contrib import chaos as _chaos
-from . import megastep as _megastep
 from .parallel import elastic as _elastic
 from .telemetry import autotune as _autotune
 from .telemetry import collective as _collective
@@ -364,17 +363,6 @@ class FitLoop:
         # MXTPU_DEVICE_PEAK table (a typo'd peak raises here, before
         # step 0, never silently grades MFU against garbage)
         _efficiency.reset_run()
-        # megastep (MXTPU_MEGASTEP): ONE jitted donated-buffer program per
-        # step — forward+backward+sentinel+update (and, under a simulated
-        # group, the in-graph collectives) fuse; a warm step is a single
-        # dispatch. Constructed HERE so every non-composable knob combo
-        # raises before any step runs and before the handlers install.
-        megastep = None
-        if _megastep.megastep_requested():
-            megastep = _megastep.Megastep(
-                self._net, self._trainer, self._loss_fn,
-                skip_nonfinite=self._skip_nonfinite,
-                ignore_stale_grad=self._ignore_stale_grad)
         good_streak = 0
         hb = None
         if self._heartbeat and self._ckpt_dir is not None:
@@ -483,60 +471,49 @@ class FitLoop:
                     bs = batch_size if batch_size is not None \
                         else x.shape[0]
                     import jax
-                    if megastep is not None:
-                        # ONE segment, ONE program: compute + comm +
-                        # optimizer fuse, so the breakdown attributes the
-                        # whole step to 'megastep' (accounted_frac holds
-                        # structurally — there is nothing unattributed to
-                        # leak)
-                        with _segment("megastep"):
-                            fused_flag, loss_dev = megastep.run(
-                                x, y, bs, self._loss_scale, plan,
-                                result.step)
-                    else:
-                        # comm/backward overlap: the scope itself goes
-                        # inactive for a step whose grads the chaos plan
-                        # will poison AFTER backward (clean grads must not
-                        # ship early) — pass OUR chaos clock, the
-                        # trainer's own step() counter never advances
-                        # under FitLoop
-                        ov = overlap_scope(chaos_step=result.step) \
-                            if overlap_scope is not None \
-                            else contextlib.nullcontext()
-                        with _segment("compute"):
-                            with autograd.record():
-                                out = self._net(x)
-                                loss = self._loss_fn(out, y) \
-                                    if y is not None else self._loss_fn(out)
-                                scaled = loss * self._loss_scale \
-                                    if self._loss_scale != 1.0 else loss
-                            with ov:
-                                scaled.backward()
-                        if plan is not None:
-                            plan.poison_grads(self._trainer._params)
-                        with _segment("comm"):
-                            self._trainer.allreduce_grads()
-                        # fetch the finiteness verdict and the loss in ONE
-                        # device-to-host transfer: the sentinel must not
-                        # add a second blocking sync to every step
-                        with _segment("compute"):
-                            loss_dev = loss.mean()._data
-                        fused_flag = None
-                        if self._skip_nonfinite and \
-                                hasattr(self._trainer,
-                                        "update_with_sentinel"):
-                            # aggregated fast path: the finiteness check is
-                            # ONE fused reduction inside the compiled step
-                            # and the update is where-guarded on device — a
-                            # non-finite step already left params/state
-                            # untouched, only the host counters need
-                            # rolling back
-                            with _segment("optimizer"):
-                                fused_flag = \
-                                    self._trainer.update_with_sentinel(
-                                        bs * self._loss_scale,
-                                        ignore_stale_grad=self
-                                        ._ignore_stale_grad)
+                    # comm/backward overlap: the scope itself goes
+                    # inactive for a step whose grads the chaos plan
+                    # will poison AFTER backward (clean grads must not
+                    # ship early) — pass OUR chaos clock, the
+                    # trainer's own step() counter never advances
+                    # under FitLoop
+                    ov = overlap_scope(chaos_step=result.step) \
+                        if overlap_scope is not None \
+                        else contextlib.nullcontext()
+                    with _segment("compute"):
+                        with autograd.record():
+                            out = self._net(x)
+                            loss = self._loss_fn(out, y) \
+                                if y is not None else self._loss_fn(out)
+                            scaled = loss * self._loss_scale \
+                                if self._loss_scale != 1.0 else loss
+                        with ov:
+                            scaled.backward()
+                    if plan is not None:
+                        plan.poison_grads(self._trainer._params)
+                    with _segment("comm"):
+                        self._trainer.allreduce_grads()
+                    # fetch the finiteness verdict and the loss in ONE
+                    # device-to-host transfer: the sentinel must not
+                    # add a second blocking sync to every step
+                    with _segment("compute"):
+                        loss_dev = loss.mean()._data
+                    fused_flag = None
+                    if self._skip_nonfinite and \
+                            hasattr(self._trainer,
+                                    "update_with_sentinel"):
+                        # aggregated fast path: the finiteness check is
+                        # ONE fused reduction inside the compiled step
+                        # and the update is where-guarded on device — a
+                        # non-finite step already left params/state
+                        # untouched, only the host counters need
+                        # rolling back
+                        with _segment("optimizer"):
+                            fused_flag = \
+                                self._trainer.update_with_sentinel(
+                                    bs * self._loss_scale,
+                                    ignore_stale_grad=self
+                                    ._ignore_stale_grad)
                     # the blocking fetch realizes the whole async step
                     # (forward/backward dominate): charged to compute.
                     # Sampled numerics stats (MXTPU_NUMERICS) ride the
@@ -546,10 +523,7 @@ class FitLoop:
                                      "last_numerics_stats", None)
                     nvals = None
                     if fused_flag is not None:
-                        # under megastep the realizing fetch belongs to the
-                        # one fused segment, not a phantom 'compute'
-                        with _segment("megastep" if megastep is not None
-                                      else "compute"):
+                        with _segment("compute"):
                             if nstats:
                                 ok, lval, nvals = jax.device_get(
                                     (fused_flag, loss_dev,

@@ -556,57 +556,6 @@ class Trainer:
         self._last_fused_indices = []
         self._last_fused_created = []
 
-    def megastep_plan(self, batch_size):
-        """HOST half of one fused megastep (``MXTPU_MEGASTEP=on``): the
-        bookkeeping ``update_with_sentinel`` performs between dispatches
-        — rescale resolution, per-rank :func:`grouped.prepare_update`
-        (update-count bumps, state creation, lr/wd resolution) and
-        chunking — extracted so the megastep driver can run it OUTSIDE
-        the trace every step while the ONE traced program replays the
-        device half. Covers every live parameter (megastep's trace-time
-        freshness check replaces the composed path's post-backward
-        ``todo`` filter), per rank of the ZeRO plane when active. Arms
-        ``_last_fused_indices``/``_last_fused_created`` so the existing
-        :meth:`rollback_step` undoes a sentinel-skipped (or
-        failed-to-trace) step exactly like the composed fused path.
-
-        Returns ``(live, rank_chunks, lr_list, wd_list)`` where
-        ``rank_chunks`` is one chunk list per non-empty rank and
-        ``lr_list``/``wd_list`` flatten the per-item scalars in chunk
-        order (the megastep program takes them as ONE dynamic f32 vector
-        — Adam's bias-corrected lr changes every step and must not
-        retrace)."""
-        self._optimizer.rescale_grad = self._scale / batch_size
-        self.last_numerics_stats = None
-        updater = self._updaters[0]
-        live = [(i, p) for i, p in enumerate(self._params)
-                if p.grad_req != "null"]
-        plane = self._zero_plane()
-        agg = _grouped.aggregation_size()
-        if plane is not None:
-            agg = max(1, agg)
-            rank_sets = [[(i, p) for i, p in live if plane.owner(i) == r]
-                         for r in plane.my_ranks]
-        else:
-            rank_sets = [live]
-        rank_chunks, created, handled = [], [], []
-        lr_list, wd_list = [], []
-        for items in rank_sets:
-            if not items:
-                continue
-            prepared, cr = _grouped.prepare_update(updater, items)
-            chunks = _grouped.chunk_prepared(prepared, agg)
-            rank_chunks.append(chunks)
-            created += cr
-            for chunk in chunks:
-                for e in chunk:
-                    handled.append(e[0])
-                    lr_list.append(e[4])
-                    wd_list.append(e[5])
-        self._last_fused_indices = handled
-        self._last_fused_created = created
-        return live, rank_chunks, lr_list, wd_list
-
     def _update(self, ignore_stale_grad=False, sentinel=False):
         with _span("mx.trainer.update", "step") as sp:
             flag = self._apply_update(ignore_stale_grad, sentinel)

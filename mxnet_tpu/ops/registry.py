@@ -36,55 +36,10 @@ from ..telemetry.tracer import span as _span
 
 __all__ = ["OpDef", "register", "get_op", "list_ops", "invoke_jax",
            "eval_shape", "alias", "register_sparse", "stype_dispatch",
-           "storage_fallback_warn", "push_op_islands", "pop_op_islands",
-           "op_islands_active"]
+           "storage_fallback_warn"]
 
 _OPS: Dict[str, "OpDef"] = {}
 
-
-# ---------------------------------------------------------------------------
-# op-island mode: bitwise-faithful whole-step traces (MXTPU_MEGASTEP)
-# ---------------------------------------------------------------------------
-# The eager executor MATERIALIZES every op's outputs (each op is its own
-# compiled program), which forbids XLA from fusing across op boundaries —
-# in particular from contracting a producer's multiply into a consumer's
-# add (FMA, one rounding instead of two). A whole-step trace
-# (megastep.py) inlines those same ops into ONE program, where such
-# cross-op contraction WOULD flip last bits vs the eager trajectory.
-# Island mode restores the eager boundaries structurally: while active
-# (megastep's traced body brackets itself with push/pop), every op's
-# outputs pass through ``lax.optimization_barrier``, so each op compiles
-# as the same isolated fusion region it is eagerly — the fused program
-# is the composed step's exact kernels MINUS the per-op dispatches,
-# which is precisely the megastep win (launch overhead, not kernel
-# algebra) and makes bitwise parity hold by construction.
-import threading as _threading
-
-_ISLANDS = _threading.local()
-
-
-def push_op_islands() -> None:
-    _ISLANDS.depth = getattr(_ISLANDS, "depth", 0) + 1
-
-
-def pop_op_islands() -> None:
-    _ISLANDS.depth = getattr(_ISLANDS, "depth", 1) - 1
-
-
-def op_islands_active() -> bool:
-    return getattr(_ISLANDS, "depth", 0) > 0
-
-
-def _island(out):
-    """Barrier one op's outputs (pytree-safe; None leaves pass through)."""
-    import jax
-    if out is None:
-        return out
-    if isinstance(out, (tuple, list)):
-        typ = type(out)
-        return typ(o if o is None else jax.lax.optimization_barrier(o)
-                   for o in out)
-    return jax.lax.optimization_barrier(out)
 
 # storage-type dispatch table (the FComputeEx + FInferStorageType analog,
 # ref: include/mxnet/op_attr_types.h:122,282): (op name, input stypes) →
@@ -314,8 +269,6 @@ def invoke_jax(opdef: OpDef, arrays: Sequence, params: Dict[str, Any]):
             # Non-jittable param combination (e.g. python callable param):
             # fall back to direct tracing-free eval.
             out = opdef.fn(*arrays, **params, **dyn)
-        if op_islands_active():
-            out = _island(out)
         if _naive_engine():
             import jax
             jax.block_until_ready(out)

@@ -537,10 +537,8 @@ def prepare_update(updater, items):
     """HOST half of one aggregated step over ``items``: state creation
     (ledger-tracked), update-count bumps, and lr/wd resolution — every
     count bumps before any lr is resolved within the step, identical to
-    the per-param loop's order. Pure host bookkeeping, no device work,
-    so the megastep driver can run it OUTSIDE its trace and replay it
-    verbatim on warm steps while the traced program replays the device
-    half. Returns ``(prepared, created)`` where ``prepared`` entries are
+    the per-param loop's order. Pure host bookkeeping, no device work.
+    Returns ``(prepared, created)`` where ``prepared`` entries are
     ``(index, Parameter, state_handles, mp, lr, wd)`` and ``created``
     lists indices whose optimizer state this call first materialized
     (rollback must delete them again)."""
@@ -587,8 +585,7 @@ def chunk_prepared(prepared, agg_size: int):
     mp-ness, state arity), preserving parameter order within a bucket;
     a bucket longer than ``agg_size`` (only an explicit
     ``MXTPU_OPTIMIZER_AGGREGATION`` is ever that small) is cut into
-    chunks of that many. Pure function of the prepared structure —
-    the chunk layout is part of the megastep cache signature."""
+    chunks of that many. Pure function of the prepared structure."""
     buckets: "OrderedDict[Tuple, List]" = OrderedDict()
     for ent in prepared:
         i, p, handles, mp = ent[0], ent[1], ent[2], ent[3]
@@ -604,21 +601,15 @@ def chunk_prepared(prepared, agg_size: int):
 
 
 def apply_chunk(updater, rule, chunk, lrs, wds, rescale,
-                sentinel: bool = False, flag=None, stats_out=None,
-                note_dispatches: bool = True):
+                sentinel: bool = False, flag=None, stats_out=None):
     """DEVICE half for ONE chunk: signature → cached jitted bucket
     program → call → rebind weights/states. ``lrs``/``wds``/``rescale``
-    arrive as arrays (f32 vectors over the chunk / an f32 scalar) rather
-    than host floats so the megastep trace can feed slices of its
-    dynamic per-step inputs (Adam's bias-corrected lr changes every
-    step; baking it would retrace) — and so can pass tracers, inlining
-    the SAME cached program the composed path dispatches. A host
-    caller hands them over as host float32 numpy arrays, which travel
-    with this launch (``jnp.asarray`` of a Python list is a put plus a
-    cast program of its own).
-    ``note_dispatches=False`` suppresses the efficiency-plane note: a
-    trace-time call is not a launch, and the megastep driver notes its
-    ONE program itself. Returns the handled indices."""
+    are dynamic inputs of the program (f32 vectors over the chunk / an
+    f32 scalar): Adam's bias-corrected lr changes every step, and a
+    baked value would retrace. :func:`grouped_update` hands them over as
+    host float32 numpy arrays, which travel with this launch
+    (``jnp.asarray`` of a Python list is a put plus a cast program of
+    its own). Returns the handled indices."""
     opt = updater.optimizer
     collect = stats_out is not None
     statics_key = rule.statics(opt)
@@ -656,7 +647,7 @@ def apply_chunk(updater, rule, chunk, lrs, wds, rescale,
     # program into the current step window — the cost resolves
     # lazily at step end through the SAME registry record
     # program_memory fills. One cached env check when off.
-    if note_dispatches and _efficiency.enabled():
+    if _efficiency.enabled():
         _efficiency.note_dispatch(
             ("opt", sig), "optimizer",
             f"{rule.name}:bucket{len(chunk)}",

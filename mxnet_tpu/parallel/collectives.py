@@ -292,8 +292,7 @@ def _rs_tile_fn(mesh, axis):
     contributes its rank-major padded wire buffer and keeps ONLY its own
     reduced tile. The input is DONATED — the padded wire buffer is
     transient by construction and dies inside the collective instead of
-    living on until the caller's slicing (the buffer-lifetime discipline
-    the one-program megastep will inherit)."""
+    living on until the caller's slicing."""
     import jax
     from jax.sharding import PartitionSpec as P
     from .compat import shard_map
@@ -305,27 +304,6 @@ def _rs_tile_fn(mesh, axis):
     return jax.jit(shard_map(f, mesh=mesh, in_specs=(P(axis),),
                              out_specs=P(axis), check_vma=False),
                    donate_argnums=(0,))
-
-
-def loopback_psum(x, contributions=None):
-    """In-graph ``psum`` for a loopback (single-process simulated) group.
-
-    The one-program megastep traces the simulated world's grad reduction
-    THROUGH this site so the collective lives structurally inside the
-    step program — where a real mesh axis would run ``jax.lax.psum`` /
-    ``psum_scatter`` (:func:`_rs_tile_fn`) and XLA would schedule it
-    against compute — instead of as a host-driven kvstore transport
-    between dispatches. A simulated world plays every rank over shared
-    buffers, so there is exactly ONE local contribution and the sum over
-    it is the identity: no arithmetic node is emitted (``-0.0 + 0.0``
-    would flip sign bits and break the bitwise-parity contract).
-    ``contributions`` lets a future multi-contribution loopback (e.g. a
-    per-device split) reduce through the same site."""
-    parts = [x] if contributions is None else list(contributions)
-    out = parts[0]
-    for p in parts[1:]:
-        out = out + p
-    return out
 
 
 def _coord_segment_reduce(local, all_parts, tag: str):

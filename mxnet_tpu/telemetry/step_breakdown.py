@@ -16,12 +16,6 @@ Segments (the canonical set; producers may add their own names):
 data_wait        blocked on the input pipeline (iterator next())
 h2d              host->device staging of batch arrays
 compute          forward + backward + device sync of the loss
-megastep         the ONE fused step program under ``MXTPU_MEGASTEP`` —
-                 forward + backward + sentinel + update (+ in-graph
-                 collectives) in a single dispatch; replaces
-                 compute/optimizer/comm for the step and is exempt from
-                 the bound detector exactly like ``compute`` (it IS the
-                 compute)
 optimizer        parameter update (incl. the fused sentinel reduction)
 comm             gradient allreduce / kvstore push-pull after backward
 comm_overlapped  collectives launched DURING backward by the overlap
@@ -62,8 +56,8 @@ __all__ = ["SEGMENTS", "StepBreakdown", "segment", "current_breakdown"]
 
 _LOG = get_logger("mxnet_tpu.telemetry")
 
-SEGMENTS = ("data_wait", "h2d", "compute", "megastep", "optimizer",
-            "comm", "comm_overlapped", "checkpoint")
+SEGMENTS = ("data_wait", "h2d", "compute", "optimizer", "comm",
+            "comm_overlapped", "checkpoint")
 
 #: remedy hint per over-threshold segment (the one-line diagnosis tail)
 _ADVICE = {
@@ -273,7 +267,7 @@ class StepBreakdown:
         if wall <= 0 or self.bound_frac <= 0:
             return
         for name, s in sorted(rec.items(), key=lambda kv: -kv[1]):
-            if name in ("wall", "compute", "megastep"):
+            if name in ("wall", "compute"):
                 continue
             frac = s / wall
             if frac >= self.bound_frac:

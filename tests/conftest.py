@@ -90,12 +90,6 @@ def pytest_configure(config):
         "the chaos soak is a subprocess drill on the "
         "coordination-service fallback, same harness as test_elastic.")
     config.addinivalue_line(
-        "markers", "megastep: one-program training-step tests "
-        "(mxnet_tpu/megastep.py fused forward+backward+sentinel+update "
-        "trace, donated buffers, in-graph loopback collectives). "
-        "Tier-1-safe: CPU, in-process, bitwise parity vs the composed "
-        "path pinned for all grouped optimizer configs.")
-    config.addinivalue_line(
         "markers", "efficiency: efficiency/goodput plane tests "
         "(telemetry/efficiency.py per-program FLOP/byte cost registry "
         "+ live MFU/roofline rollup, telemetry/run_report.py run "

@@ -58,7 +58,7 @@ def main():
 
     # -- phase A: rank 1 straggles BETWEEN collectives ------------------
     import time
-    straggle_s = 0.05
+    straggle_s = 0.25  # large beside a loaded host's scheduling noise
     kv.init("w", nd.array(np.zeros((4, 4), np.float32)))
     for step in range(3):
         if rank == 1:

@@ -99,20 +99,6 @@ def test_bench_tiny_deadline_emits_full_headline_json():
         zorow["comm_overlapped_share"]
     assert zorow["mfu_barrier"] == zorow["mfu_overlap"] == 0
     assert zorow["collectives_per_step"] >= 2  # rs + ag per bucket
-    # the megastep row: one jitted donated-buffer program per step —
-    # bitwise loss parity with the composed path, a single fully
-    # attributed dispatch per warm step, and the program carries the
-    # WHOLE step's FLOPs (the composed path attributes only optimizer
-    # dispatches). steps/s is informational on a noisy CPU child; the
-    # parity + attribution pins are the row's contract
-    msrow = payload["megastep"]
-    assert msrow["parity"] is True
-    assert msrow["steps_per_s_megastep"] > 0
-    assert msrow["steps_per_s_composed"] > 0
-    assert msrow["warm_dispatches_per_step"] == 1
-    assert msrow["unattributed_dispatches"] == 0
-    assert msrow["flops_per_step_megastep"] > \
-        msrow["flops_per_step_composed"]
     # the comm_health row: the collective-observability plane over a
     # clean simulated ZeRO run — ledger populated, no skew (one process,
     # one clock), and ZERO watchdog firings with the watchdog armed

@@ -657,10 +657,12 @@ def test_two_process_kv_hang_flight_record_and_fleet_skew(tmp_path):
     ranks = json.loads(r3.stdout)["ranks"]
     assert set(ranks) == {"0", "1"}
     assert all(rank_rep["steps"] for rank_rep in ranks.values())
-    # the two attributions measure the same entries: agree to within
-    # half the injected 50ms straggle (clock + transport noise)
+    # the two attributions measure the same entries: agree to within a
+    # tenth of the injected 250ms straggle (clock + transport noise).
+    # Rank 1 is late to 6 of the 14 compared entries, so its mean reads
+    # about 105ms on an idle host and three quarters of that under load
     live = health["skew_ms_by_rank"]["1"]["mean_ms"]
     offline = rep["collective_skew_ms"]["1"]["mean_ms"]
-    assert live > 20 and offline > 20, (live, offline)
+    assert live > 40 and offline > 40, (live, offline)
     assert abs(live - offline) < 25 + 0.5 * max(live, offline), \
         (live, offline)

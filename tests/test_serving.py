@@ -3,8 +3,9 @@ control, deadlines, drain, and the metrics plane.
 
 Everything here is tier-1-safe: CPU, in-process transport (no sockets),
 deterministic chaos injection for the failure paths. The e2e acceptance
-tests are at the bottom: concurrent heterogeneous clients get bit-exact
-results vs. direct model calls with a closed compile budget, saturation
+tests are at the bottom: concurrent heterogeneous clients get the direct
+model call's results (to 4 ulp of the largest output: the served batch
+sizes are not the direct call's) with a closed compile budget, saturation
 sheds load with QueueFull, and the metrics endpoint emits valid
 Prometheus text exposition.
 """
@@ -444,8 +445,8 @@ def test_batch_dispatch_emits_profiler_span():
 
 
 # ---------------------------------------------------------------------------
-# e2e acceptance: heterogeneous concurrent clients, bit-exact, closed
-# compile budget
+# e2e acceptance: heterogeneous concurrent clients, the direct call's
+# results, closed compile budget
 # ---------------------------------------------------------------------------
 
 def _pool_net(seed=0):
@@ -463,7 +464,7 @@ def _pool_net(seed=0):
     return net
 
 
-def test_e2e_concurrent_heterogeneous_clients_bit_exact():
+def test_e2e_concurrent_heterogeneous_clients_match_direct_call():
     shapes = [(3, 8, 8), (3, 12, 12)]
     net = _pool_net()
     srv = ModelServer(net, bucket_shapes=shapes, max_batch_size=4,
@@ -496,15 +497,19 @@ def test_e2e_concurrent_heterogeneous_clients_bit_exact():
         # acceptance: total XLA compiles <= configured bucket combinations
         assert info.misses == compiles, \
             f"traffic caused {info.misses - compiles} extra compiles"
-        # hybridized reference: the same whole-graph compile path the
-        # server replays (eager per-op execution can differ in the last
-        # ulp — XLA fusion, not padding)
+        # hybridized reference at a batch of its own (10). How the timing
+        # cut the requests into padded batches of 1, 2 or 4 is not known
+        # here, and another batch size is another XLA program whose rows
+        # can differ in the last ulp of the terms it sums (1.5 ulp of the
+        # largest output here); the bitwise claim, at the SAME padded
+        # batch, is test_padding_never_contaminates_rows_matched_batch's
         net.hybridize()
         for s in shapes:
             direct = net(nd.array(np.stack(inputs[s]))).asnumpy()
             served = np.stack(results[s])
-            # bit-exact: padding rows were masked out, row content exact
-            np.testing.assert_array_equal(served, direct)
+            np.testing.assert_allclose(
+                served, direct, rtol=0,
+                atol=4 * np.spacing(np.abs(direct).max()))
     finally:
         srv.stop()
 

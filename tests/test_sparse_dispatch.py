@@ -401,7 +401,7 @@ def test_gluon_embedding_sparse_grad_end_to_end():
 
 
 # ---------------------------------------------------------------------------
-# jit trace-path round-trips (the megastep discipline: row_sparse crosses
+# jit trace-path round-trips (row_sparse crosses
 # into a jitted program as a FIXED-SHAPE dense packed buffer; nnz varies
 # per step, the compiled program does not)
 # ---------------------------------------------------------------------------

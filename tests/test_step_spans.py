@@ -357,6 +357,69 @@ def test_tracer_follows_a_profiler_session_into_the_xplane(
 
 
 # ---------------------------------------------------------------------------
+# what the programs hold
+
+def test_step_programs_hold_no_opt_barrier():
+    """The convolutional cells live on XLA fusing BatchNorm and ReLU into the
+    convolutions round them, so no program the operator layer traces may fence
+    an op's outputs: not ``SPMDTrainer``'s one-program step, not a hybridized
+    block's forward, not its VJP. Read from the lowered text, where a barrier
+    still stands (the CPU compiler drops it from the compiled one)."""
+    # in two halves: the word itself appears nowhere under mxnet_tpu/ or
+    # tests/, so that a grep for it finds any use that comes back
+    barrier = "optimization" "_barrier"
+    fenced = jax.jit(lambda a: getattr(jax.lax, barrier)(a * 2) + 1)
+    assert barrier in fenced.lower(
+        jax.ShapeDtypeStruct((4,), np.float32)).as_text()
+
+    def conv_net():
+        net = gluon.nn.HybridSequential()
+        net.add(gluon.nn.Conv2D(4, 3, padding=1), gluon.nn.BatchNorm(),
+                gluon.nn.Activation("relu"), gluon.nn.GlobalAvgPool2D(),
+                gluon.nn.Flatten(), gluon.nn.Dense(3))
+        net.initialize(mx.init.Xavier())
+        net(nd.zeros((1, 2, 6, 6)))
+        net.hybridize()
+        return net
+
+    rng = np.random.RandomState(2)
+    data = rng.rand(BATCH, 2, 6, 6).astype("float32")
+    label = rng.randint(0, 3, BATCH).astype("float32")
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    trainer = SPMDTrainer(conv_net(), loss_fn, optimizer="sgd",
+                          optimizer_params={"learning_rate": 0.1})
+    trainer.step(data, label)
+    step_fn, step_args = trainer._last_program
+    texts = {"spmd step": step_fn.lower(*step_args).as_text()}
+
+    net = conv_net()
+    x = nd.array(data)
+    with autograd.record():
+        out = net(x)
+        loss = loss_fn(out, nd.array(label))
+    loss.backward()
+    op = net._cached_op
+    (key_sig, entry), = op._cache.snapshot_items()
+    in_sig, param_sig, in_treedef, _training, _flags = key_sig
+
+    def sds(sig):
+        return tuple(jax.ShapeDtypeStruct(tuple(shape), np.dtype(dt))
+                     for shape, dt in sig)
+
+    key = jax.random.PRNGKey(0)
+    cots = (jax.ShapeDtypeStruct(out.shape, np.float32),)
+    op._in_treedef = in_treedef
+    texts["forward"] = entry.jitted.lower(
+        sds(param_sig), key, *sds(in_sig)).as_text()
+    texts["vjp"] = entry.vjp_jitted.lower(
+        sds(param_sig), key, sds(in_sig), cots).as_text()
+    for name, text in texts.items():
+        assert "convolution" in text, name
+        assert barrier not in text, name
+
+
+# ---------------------------------------------------------------------------
 # cost when off
 
 @pytest.mark.heavy
