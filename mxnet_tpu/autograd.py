@@ -10,8 +10,9 @@ input ``jax.Array`` values (immutable, so "snapshot" is just a reference —
 versioned-mutation on NDArray cannot corrupt the tape) and (b) its pure op
 function. The reverse pass walks the tape topologically and calls ``jax.vjp``
 on each op — XLA jit-compiles each (op, params, shapes) vjp once and replays
-it. Whole-graph backward for hybridized blocks bypasses this tape entirely
-(CachedOp lowers fwd+bwd to a single HLO module — see cached_op.py).
+it. A hybridized block is ONE node of this tape (CachedOp: its recorded
+forward linearises, the node's backward applies the transpose — see
+cached_op.py).
 """
 from __future__ import annotations
 
@@ -578,6 +579,12 @@ def _backward_walk(heads, head_grads, retain_graph, variables, sp):
     if not retain_graph:
         for node in order:
             node.input_vals = None
+            # a custom node may hold more than its inputs (a CachedOp's
+            # residuals): it must not outlive the graph because a loss
+            # kept for logging keeps the node
+            release = getattr(node.custom, "_release_graph", None)
+            if release is not None:
+                release()
 
     return results
 

@@ -109,10 +109,13 @@ env.declare("MXNET_EXEC_BULK_EXEC_INFERENCE", bool, True,
 env.declare("MXNET_EXEC_BULK_EXEC_TRAIN", bool, True,
             "Fuse training graphs into single XLA programs.")
 env.declare("MXNET_BACKWARD_DO_MIRROR", bool, False,
-            "Trade compute for memory in backward (jax.checkpoint remat).")
+            "Trade compute for memory in backward (jax.checkpoint remat). "
+            "Off, a recorded hybridized forward keeps convolution and "
+            "matmul outputs and recomputes the elementwise chain.")
 env.declare("MXNET_BACKWARD_MIRROR_POLICY", str, "full",
             "Remat policy when mirroring: full (save nothing) | dots "
-            "(save matmul results, recompute elementwise ops).")
+            "(save matmul results, recompute elementwise ops) | convs "
+            "(save convolution and matmul results).")
 env.declare("MXNET_UPDATE_ON_KVSTORE", bool, True,
             "Run optimizer update inside the kvstore when supported.")
 env.declare("MXNET_KVSTORE_BIGARRAY_BOUND", int, 1000000,

@@ -210,10 +210,40 @@ def _eff():
     return _EFFICIENCY_MOD
 
 
+# A compiled program's cache key. ``flags`` are the env flags read inside op
+# impls (they change the traced program: toggling them must re-trace, not
+# replay); ``record`` is None for the plain forward that inference and
+# serving run, else (residual policy name, which params, which inputs are
+# differentiated): a recorded call runs another program.
+_Signature = namedtuple("_Signature", [
+    "inputs", "params", "in_treedef", "training", "flags", "record"])
+
+
+# What a recorded entry's forward program hands to its backward, worked
+# out once from the traced program (CachedOp._linearize):
+#   n_outs, n_state   how the program's flat outputs split: the block's
+#                     outputs, the mutated state, then the residuals the
+#                     program itself wrote (the set CachedOp._arena recycles)
+#   res_src           one int a leaf of the vjp closure: >= 0 a position in
+#                     the program's outputs, < 0 the ~position of a flat
+#                     argument (params, key, inputs) that is passed through
+#   closure_treedef   treedef of the closure jax.vjp returned
+#   n_inputs, diff_pos  how many inputs the node has (params + inputs), and
+#                     the positions among them of what the closure
+#                     differentiates with respect to
+#   arena_avals       abstract values of the recycled set
+#   residual_bytes    bytes of that set
+#   policy            residual_policy_name the program was built under
+_Linearized = namedtuple("_Linearized", [
+    "n_outs", "n_state", "res_src", "closure_treedef", "n_inputs",
+    "diff_pos", "arena_avals", "residual_bytes", "policy"])
+
+
 class _CacheEntry:
     __slots__ = ("jitted", "mutated_idx", "out_treedef", "vjp_jitted",
                  "n_outputs", "warm", "mem_stats", "cost_stats",
-                 "vjp_abstract", "vjp_cost_stats", "__weakref__")
+                 "vjp_abstract", "vjp_cost_stats", "linear", "alloc",
+                 "__weakref__")
 
     def __init__(self):
         self.jitted = None
@@ -228,11 +258,15 @@ class _CacheEntry:
         # filled lazily by entry_cost_stats ({} = resolution failed, so
         # the efficiency plane does not retry every step)
         self.cost_stats: Optional[dict] = None
-        # abstract (treedef, params, key, ins, cots) signature of the
-        # backward program, captured at its first dispatch under the
-        # efficiency plane so entry_vjp_cost_stats can re-lower it
+        # abstract (closure, cots) signature of the backward program,
+        # captured at its first dispatch so entry_vjp_cost_stats (and a
+        # test) can lower it
         self.vjp_abstract: Optional[tuple] = None
         self.vjp_cost_stats: Optional[dict] = None
+        # recorded entries only: the _Linearized plan, and the program
+        # that allocates a residual set when the op's arena has none
+        self.linear: Optional[_Linearized] = None
+        self.alloc = None
         # False until the first execution (which runs the python trace)
         # has completed — concurrent callers must treat a cold entry like
         # a miss and take the exclusive trace path
@@ -303,42 +337,49 @@ def trace_rw_for(block) -> "_RWLock":
     return rw
 
 
+def _apply_closure(closure, cots):
+    return closure(cots)
+
+
+def _on_tape(x) -> bool:
+    """Whether NDArray ``x`` can receive a gradient: it carries a tape
+    entry (a marked variable or a recorded op's output) and is real or
+    complex."""
+    import jax.numpy as jnp
+    return x._tape_entry is not None and \
+        jnp.issubdtype(x._data.dtype, jnp.inexact)
+
+
 class _CachedOpGrad:
     """Per-call backward closure recorded as a single tape node
-    (ref: CachedOp::Backward, src/imperative/cached_op.cc:1112)."""
+    (ref: CachedOp::Backward, src/imperative/cached_op.cc:1112). It holds
+    what the recorded forward handed over: the ``jax.vjp`` closure, whose
+    leaves are the residuals, and of those the set the forward program
+    wrote itself (``owned``), which goes back to the op's arena when the
+    graph is freed."""
 
-    def __init__(self, op: "CachedOp", entry: _CacheEntry, key,
-                 param_arrays, in_arrays, training: bool,
-                 in_treedef=None):
+    def __init__(self, op: "CachedOp", entry: _CacheEntry, closure, owned):
         self.op = op
         self.entry = entry
-        self.key = key
-        self.param_arrays = param_arrays
-        self.in_arrays = in_arrays
-        self.training = training
-        # the input treedef the forward was keyed under: the backward's
-        # pure fn reads op._in_treedef at trace time, so a later
-        # re-lower (efficiency-plane cost resolution) must restore it
-        self.in_treedef = in_treedef
+        self.closure = closure
+        self.owned = owned
 
-    def _note_efficiency(self, cotangents) -> None:
-        """Efficiency-plane hook: capture the backward program's abstract
-        signature once per entry and note this launch (callers gate on
+    def _release_graph(self) -> None:
+        """The walk freed the graph (``retain_graph`` false): drop the
+        residuals even if an output NDArray keeps this node alive, and
+        hand the recycled set to the next recorded forward, which
+        donates it. The backward that read it is already enqueued; the
+        device's queue orders the two."""
+        owned, self.owned, self.closure = self.owned, None, None
+        if owned:
+            with self.op._arena_lock:
+                self.op._arena.append((self.entry.linear.arena_avals, owned))
+
+    def _note_efficiency(self) -> None:
+        """Efficiency-plane hook: note this launch (callers gate on
         ``enabled()`` — plane-off steps never reach here)."""
-        entry = self.entry
         try:
-            if entry.vjp_abstract is None and self.in_treedef is not None:
-                import jax
-
-                def sds(arrs):
-                    return tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                                 for a in arrs)
-                k = self.key
-                entry.vjp_abstract = (
-                    self.in_treedef, sds(self.param_arrays),
-                    jax.ShapeDtypeStruct(k.shape, k.dtype),
-                    sds(self.in_arrays), sds(cotangents))
-            op = self.op
+            op, entry = self.op, self.entry
             _eff().note_dispatch(
                 ("co_bwd", id(entry)), "cached_op",
                 f"{type(op.block).__name__}:bwd",
@@ -348,48 +389,31 @@ class _CachedOpGrad:
 
     def _run_backward(self, cotangents):
         with _span("mx.cached_op.vjp", "step",
-                   {"block": type(self.op.block).__name__, "programs": 1}):
+                   {"block": type(self.op.block).__name__, "programs": 1,
+                    "recompute": self.entry.linear.policy}):
             return self._vjp(cotangents)
 
     def _vjp(self, cotangents):
         import jax
-        entry = self.entry
-        if _eff().enabled():
-            self._note_efficiency(cotangents)
+        entry, closure = self.entry, self.closure
+        if closure is None:
+            raise MXNetError("graph has already been freed; pass "
+                             "retain_graph=True to backward() to reuse it")
+        cotangents = tuple(cotangents)
         if entry.vjp_jitted is None:
-            from .util import mirror_wrapper
-            fn = self.op._make_pure_fn(self.training, entry)
-            # remat decision resolved HERE (host side, once per compiled
-            # backward), not inside the traced run() (graftcheck GC-T03)
-            mirror = mirror_wrapper(self.op.mirror)
-
-            def run(params, key, ins, cots):
-                def outputs_only(params_, *ins_):
-                    outs, _state = fn(params_, key, *ins_)
-                    return outs
-
-                # mirror/remat: store only the inputs across fwd->bwd and
-                # recompute activations inside the backward program
-                outputs_only = mirror(outputs_only)
-                _, vjp = jax.vjp(outputs_only, params, *ins)
-                return vjp(tuple(cots))
-
-            entry.vjp_jitted = jax.jit(run)
-            # first call traces: fn swaps Parameter storage to Tracers,
-            # so it needs the same exclusivity as a cold forward trace
-            self.op._trace_rw.acquire_write()
-            try:
-                grads = entry.vjp_jitted(self.param_arrays, self.key,
-                                         tuple(self.in_arrays),
-                                         tuple(cotangents))
-            finally:
-                self.op._trace_rw.release_write()
-            return list(grads[0]) + list(grads[1:])
-        grads = entry.vjp_jitted(self.param_arrays, self.key,
-                                 tuple(self.in_arrays), tuple(cotangents))
-        param_grads = grads[0]
-        in_grads = grads[1:]
-        return list(param_grads) + list(in_grads)
+            # the program only applies the transpose: tracing it runs none
+            # of the block's Python, so it needs no trace lock
+            entry.vjp_jitted = jax.jit(_apply_closure)
+            entry.vjp_abstract = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                (closure, cotangents))
+        if _eff().enabled():
+            self._note_efficiency()
+        param_grads, in_grads = entry.vjp_jitted(closure, cotangents)
+        grads = [None] * entry.linear.n_inputs
+        for pos, g in zip(entry.linear.diff_pos, param_grads + in_grads):
+            grads[pos] = g
+        return grads
 
 
 class CachedOp:
@@ -424,6 +448,14 @@ class CachedOp:
         self._cache = SignatureLRU(maxsize=self._cache_size)
         self._trace_rw = trace_rw_for(block)
         self._param_objs: Optional[List] = None
+        # residual sets of recorded calls whose graph was freed, each
+        # with its abstract values, for the next recorded forward to
+        # write over (as many as calls were live at once). The op's, not
+        # an entry's: two signatures whose residuals have the same shapes
+        # (the first step's, before the moving statistics turn float32,
+        # and every later one's) share one set.
+        self._arena: List[tuple] = []
+        self._arena_lock = threading.Lock()
 
     def cache_info(self) -> CacheInfo:
         """Hit/miss/eviction counters + occupancy of the signature cache
@@ -448,13 +480,24 @@ class CachedOp:
             return None
         if not hasattr(entry.jitted, "lower"):
             return entry.jitted  # AOT-loaded: already a Compiled stage
+        from .ops.registry import _trace_time_flags
+        if key_sig.flags != _trace_time_flags():
+            return None  # stale entry from a different flag regime
+        self._trace_rw.acquire_write()
+        try:
+            self._in_treedef = key_sig.in_treedef
+            return entry.jitted.lower(
+                *self._abstract_args(key_sig, entry)).compile()
+        finally:
+            self._trace_rw.release_write()
+
+    @staticmethod
+    def _abstract_args(key_sig, entry: _CacheEntry) -> tuple:
+        """The abstract arguments ``entry.jitted`` was traced with, from
+        the entry's cache key: ``(params, key, *inputs)`` for a plain
+        forward, ``(params, key, inputs, arena)`` for a recorded one."""
         import jax
         import numpy as np
-
-        from .ops.registry import _trace_time_flags
-        in_sig, param_sig, in_treedef, _training, flags = key_sig
-        if flags != _trace_time_flags():
-            return None  # stale entry from a different flag regime
 
         def sds(sig):
             return tuple(jax.ShapeDtypeStruct(tuple(shape), np.dtype(dt))
@@ -462,13 +505,10 @@ class CachedOp:
 
         probe_key = jax.random.PRNGKey(0)
         key_aval = jax.ShapeDtypeStruct(probe_key.shape, probe_key.dtype)
-        self._trace_rw.acquire_write()
-        try:
-            self._in_treedef = in_treedef
-            return entry.jitted.lower(
-                sds(param_sig), key_aval, *sds(in_sig)).compile()
-        finally:
-            self._trace_rw.release_write()
+        if entry.linear is None:
+            return (sds(key_sig.params), key_aval, *sds(key_sig.inputs))
+        return (sds(key_sig.params), key_aval, sds(key_sig.inputs),
+                entry.linear.arena_avals)
 
     def memory_analysis(self, refresh: bool = False) -> Dict[str, dict]:
         """Static per-program memory attribution, keyed by signature
@@ -551,24 +591,13 @@ class CachedOp:
         if cached is not None:
             return cached or None
         ab = entry.vjp_abstract
-        if ab is None or entry.vjp_jitted is None or \
-                not hasattr(entry.vjp_jitted, "lower"):
+        if ab is None:
             return None
         from .telemetry.efficiency import (COST_FIELDS,
                                            compiled_program_stats)
-        in_treedef, params_sds, key_sds, ins_sds, cots_sds = ab
         try:
-            # the vjp trace replays the pure fn (Parameter storage
-            # swapped to tracers) and reads _in_treedef: write lock +
-            # treedef restore, exactly like the forward re-lower
-            self._trace_rw.acquire_write()
-            try:
-                self._in_treedef = in_treedef
-                compiled = entry.vjp_jitted.lower(
-                    params_sds, key_sds, ins_sds, cots_sds).compile()
-            finally:
-                self._trace_rw.release_write()
-            stats = compiled_program_stats(compiled)
+            stats = compiled_program_stats(
+                entry.vjp_jitted.lower(*ab).compile())
         except Exception:
             stats = None
         if not stats or "flops" not in stats:
@@ -577,8 +606,9 @@ class CachedOp:
         cost = {k: stats[k] for k in COST_FIELDS if k in stats}
         entry.vjp_cost_stats = cost
         import hashlib
+        import jax
         digest = hashlib.md5(
-            repr((params_sds, ins_sds, cots_sds)).encode()
+            repr(jax.tree_util.tree_leaves(ab)).encode()
         ).hexdigest()[:12]
         self._record_program(
             f"{type(self.block).__name__}:bwd:{digest}",
@@ -591,7 +621,7 @@ class CachedOp:
     # signature's compiled XLA executable (jax.experimental.
     # serialize_executable) next to its cache key; aot_load deserializes
     # them into pre-warmed cache entries on a fingerprint-matched runtime.
-    AOT_FORMAT = 2  # 2: entries carry their executable's device ids
+    AOT_FORMAT = 3  # 3: the cache key says whether the call was recorded
 
     def aot_export(self, path: str) -> int:
         """Serialize the warm, inference-facing signature entries to
@@ -602,27 +632,19 @@ class CachedOp:
         exported: AOT bundles are a serving artifact."""
         import pickle
 
-        import jax
         from .ops.registry import _trace_time_flags
         from .serving.aot import runtime_fingerprint
         try:
             from jax.experimental.serialize_executable import serialize
         except ImportError as e:
             raise MXNetError(f"AOT export unavailable on this jax: {e}")
-        import numpy as np
         records = []
-
-        def sds(sig):
-            return tuple(jax.ShapeDtypeStruct(tuple(shape), np.dtype(dt))
-                         for shape, dt in sig)
-
-        probe_key = jax.random.PRNGKey(0)
-        key_aval = jax.ShapeDtypeStruct(probe_key.shape, probe_key.dtype)
         for key_sig, entry in self._cache.snapshot_items():
             if not entry.warm or not hasattr(entry.jitted, "lower"):
                 continue  # cold, or itself an AOT-loaded executable
-            in_sig, param_sig, in_treedef, training, flags = key_sig
-            if flags != _trace_time_flags():
+            if entry.linear is not None:
+                continue  # a recorded forward serves a backward, not a replica
+            if key_sig.flags != _trace_time_flags():
                 continue  # stale entry from a different flag regime
             # re-lowering retraces the pure fn, which temporarily swaps
             # Parameter storage to tracers — same exclusivity as a cold
@@ -631,9 +653,9 @@ class CachedOp:
             # caller's)
             self._trace_rw.acquire_write()
             try:
-                self._in_treedef = in_treedef
-                lowered = entry.jitted.lower(sds(param_sig), key_aval,
-                                             *sds(in_sig))
+                self._in_treedef = key_sig.in_treedef
+                lowered = entry.jitted.lower(
+                    *self._abstract_args(key_sig, entry))
             finally:
                 self._trace_rw.release_write()
             compiled = lowered.compile()
@@ -783,6 +805,126 @@ class CachedOp:
 
         return fn
 
+    def _linearize(self, entry: _CacheEntry, training: bool, record,
+                   param_arrays, rng_key, in_arrays) -> None:
+        """Build a recorded entry's forward program: it runs the block
+        once and returns, beside outputs and mutated state, the residuals
+        of ``jax.vjp`` taken with respect to what is on the tape, under
+        the ``jax.checkpoint`` policy ``record`` names. The node's
+        backward only applies the transpose (``_apply_closure``).
+
+        The function is traced to a jaxpr first, because what the
+        program takes as donated arguments (the arena) has the shapes of
+        what it returns: residuals that are the program's own arguments
+        (parameters, the batch) or its outputs are taken from there on
+        the host and never donated; the rest is the set the arena
+        recycles. Runs under the trace write lock (the trace swaps
+        Parameter storage)."""
+        import jax
+        from jax.extend.core import Literal, jaxpr_as_fun
+        from .util import residual_policy
+
+        policy_name, param_mask, in_mask = record
+        policy = residual_policy(policy_name)
+        fn = self._make_pure_fn(training, entry)
+
+        def pick(arrays, mask):
+            return tuple(a for a, m in zip(arrays, mask) if m)
+
+        def merge(arrays, mask, picked):
+            picked = iter(picked)
+            return tuple(next(picked) if m else a
+                         for a, m in zip(arrays, mask))
+
+        def linear(params, key, ins):
+            def f(diff_params, diff_ins):
+                return fn(merge(params, param_mask, diff_params), key,
+                          *merge(ins, in_mask, diff_ins))
+
+            # prevent_cse=False: the backward is another program, there
+            # is nothing to CSE with, and optimization barriers keep XLA
+            # from fusing BatchNorm and ReLU into the convolutions
+            outs, closure, state = jax.vjp(
+                jax.checkpoint(f, policy=policy, prevent_cse=False),
+                pick(params, param_mask), pick(ins, in_mask), has_aux=True)
+            return outs, state, closure
+
+        closed, shapes = jax.make_jaxpr(linear, return_shape=True)(
+            param_arrays, rng_key, tuple(in_arrays))
+        jaxpr = closed.jaxpr
+        n_outs, n_state = len(shapes[0]), len(shapes[1])
+        arg_pos = {v: i for i, v in enumerate(jaxpr.invars)}
+        emit = list(range(n_outs + n_state))  # outvars the program returns
+        out_pos = {}
+        for j in emit:
+            if not isinstance(jaxpr.outvars[j], Literal):
+                out_pos.setdefault(jaxpr.outvars[j], j)
+        res_src = []
+        for j in range(n_outs + n_state, len(jaxpr.outvars)):
+            v = jaxpr.outvars[j]
+            if isinstance(v, Literal):
+                res_src.append(len(emit))
+                emit.append(j)
+            elif v in arg_pos:
+                res_src.append(~arg_pos[v])
+            else:
+                if v not in out_pos:
+                    out_pos[v] = len(emit)
+                    emit.append(j)
+                res_src.append(out_pos[v])
+        arena_avals = tuple(
+            jax.ShapeDtypeStruct(jaxpr.outvars[j].aval.shape,
+                                 jaxpr.outvars[j].aval.dtype)
+            for j in emit[n_outs + n_state:])
+        n_params = len(param_arrays)
+        entry.linear = _Linearized(
+            n_outs, n_state, tuple(res_src),
+            jax.tree_util.tree_structure(shapes[2]),
+            n_params + len(in_arrays),
+            tuple([i for i, m in enumerate(param_mask) if m]
+                  + [n_params + i for i, m in enumerate(in_mask) if m]),
+            arena_avals,
+            sum(a.size * a.dtype.itemsize for a in arena_avals),
+            policy_name)
+
+        run = jaxpr_as_fun(closed)
+
+        def program(params, key, ins, arena):
+            # ``arena`` is donated and otherwise unused: XLA writes this
+            # call's residuals over the set a freed graph gave back
+            del arena
+            out = run(*params, key, *ins)
+            return [out[j] for j in emit]
+
+        entry.jitted = jax.jit(program, donate_argnums=(3,),
+                               keep_unused=True)
+
+    def _take_arena(self, entry: _CacheEntry, like) -> Tuple[tuple, bool]:
+        """A residual set for ``entry``'s forward to donate, and whether
+        it was recycled. With none that fits (first step, a second call
+        in one record scope, a forward whose backward never ran, another
+        input shape) one is allocated on ``like``'s device, so that the
+        forward program has one compiled variant, not a donating and an
+        allocating one; sets of other shapes are dropped then, since the
+        loop has moved on from them."""
+        avals = entry.linear.arena_avals
+        with self._arena_lock:
+            for i in range(len(self._arena) - 1, -1, -1):
+                if self._arena[i][0] == avals:
+                    return self._arena.pop(i)[1], True
+            self._arena.clear()
+        import contextlib
+        import jax
+        if entry.alloc is None:
+            import jax.numpy as jnp
+            entry.alloc = jax.jit(lambda: tuple(
+                jnp.zeros(a.shape, a.dtype) for a in avals))
+        devices = like.devices()
+        where = jax.default_device(next(iter(devices))) \
+            if len(devices) == 1 else contextlib.nullcontext()
+        with where:
+            return entry.alloc(), False
+
     # -----------------------------------------------------------------
     def __call__(self, *args):
         import jax
@@ -825,6 +967,16 @@ class CachedOp:
                 raise MXNetError(f"parameter {p.name} not initialized")
         training = autograd.is_training()
         rng_key = _random.next_key()
+        # a recorded call runs another program than the plain forward
+        # that inference and serving keep: it linearises, with respect to
+        # the arguments that carry a tape entry (only those can receive a
+        # gradient), and keeps what the mirror policy in force says
+        record = None
+        if autograd.is_recording():
+            from .util import residual_policy_name
+            record = (residual_policy_name(self.mirror),
+                      tuple(_on_tape(p._data) for p in params),
+                      tuple(_on_tape(x) for x in flat_in))
 
         from .ops.registry import _trace_time_flags
         mode = "read"
@@ -838,19 +990,17 @@ class CachedOp:
             # against the wrong treedef
             self._in_treedef = in_treedef
             param_arrays = tuple(p._data._data for p in params)
-            key_sig = (tuple((tuple(a.shape), str(a.dtype))
-                             for a in in_arrays),
-                       tuple((tuple(a.shape), str(a.dtype))
-                             for a in param_arrays),
-                       in_treedef, training,
-                       # env flags read inside op impls change the traced
-                       # program: toggling them must re-trace, not replay
-                       _trace_time_flags())
+            key_sig = _Signature(
+                tuple((tuple(a.shape), str(a.dtype)) for a in in_arrays),
+                tuple((tuple(a.shape), str(a.dtype)) for a in param_arrays),
+                in_treedef, training, _trace_time_flags(), record)
             def _new_entry():
                 # cheap: builds the entry + jit WRAPPER only (no trace/
-                # compile happens until the first execution below)
+                # compile happens until the first execution below; a
+                # recorded entry's wrapper is built there too)
                 e = _CacheEntry()
-                e.jitted = jax.jit(self._make_pure_fn(training, e))
+                if record is None:
+                    e.jitted = jax.jit(self._make_pure_fn(training, e))
                 return e
 
             entry = self._cache.get_or_insert(key_sig, _new_entry)
@@ -867,8 +1017,22 @@ class CachedOp:
                 mode = "write"
                 self._in_treedef = in_treedef  # no clobber possible now
                 param_arrays = tuple(p._data._data for p in params)
-            out_arrays, state = entry.jitted(param_arrays, rng_key,
-                                             *in_arrays)
+                if entry.jitted is None:
+                    self._linearize(entry, training, record, param_arrays,
+                                    rng_key, in_arrays)
+            if record is None:
+                out_arrays, state = entry.jitted(param_arrays, rng_key,
+                                                 *in_arrays)
+            else:
+                lin = entry.linear
+                flat_args = param_arrays + (rng_key,) + tuple(in_arrays)
+                arena, recycled = self._take_arena(entry, flat_args[0])
+                flat_out = entry.jitted(param_arrays, rng_key,
+                                        tuple(in_arrays), arena)
+                n = lin.n_outs + lin.n_state
+                out_arrays, state = flat_out[:lin.n_outs], \
+                    flat_out[lin.n_outs:n]
+                sp.set(residual_bytes=lin.residual_bytes, recycled=recycled)
             entry.warm = True
         finally:
             if mode == "read":
@@ -901,12 +1065,14 @@ class CachedOp:
         ctx = flat_in[0]._ctx if flat_in else params[0]._data._ctx
         out_nds = [NDArray(a, ctx=ctx) for a in out_arrays]
 
-        if autograd.is_recording():
-            grad_fn = _CachedOpGrad(self, entry, rng_key, param_arrays,
-                                    in_arrays, training,
-                                    in_treedef=in_treedef)
-            nd_inputs = [p._data for p in params] + list(flat_in)
-            autograd._record_custom(grad_fn, nd_inputs, tuple(out_nds))
+        if record is not None:
+            closure = jax.tree_util.tree_unflatten(
+                lin.closure_treedef,
+                [flat_out[src] if src >= 0 else flat_args[~src]
+                 for src in lin.res_src])
+            autograd._record_custom(
+                _CachedOpGrad(self, entry, closure, tuple(flat_out[n:])),
+                [p._data for p in params] + list(flat_in), tuple(out_nds))
 
         result = jax.tree_util.tree_unflatten(entry.out_treedef, out_nds)
         return result

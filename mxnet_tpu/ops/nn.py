@@ -293,7 +293,7 @@ def _make_bn_core(resid_dtype_name=None):
             n *= data.shape[i]
         return red, bshape, n
 
-    def core(data, g32, beta32, axis, eps):
+    def plain(data, g32, beta32, axis, eps):
         red, bshape, n = _shapes(data, axis)
         mean, var = _bn_batch_stats(data, red, n)
         inv = _lax().rsqrt(var + eps)
@@ -302,7 +302,11 @@ def _make_bn_core(resid_dtype_name=None):
         return out.astype(data.dtype), mean, var
 
     def fwd(data, g32, beta32, axis, eps):
-        out, mean, var = core(data, g32, beta32, axis, eps)
+        # the plain function, not the custom_vjp made of it below: a
+        # jax.checkpoint policy then sees the per-channel reductions and
+        # can keep them (cached_op.py), where one opaque custom_vjp_call
+        # would have the statistics read the whole input again in backward
+        out, mean, var = plain(data, g32, beta32, axis, eps)
         inv = _lax().rsqrt(var + eps)
         if rdt is None:
             return (out, mean, var), (data, mean, inv, g32)
@@ -337,7 +341,7 @@ def _make_bn_core(resid_dtype_name=None):
             - xhat * (sum_dy_xhat / n).reshape(bshape))
         return dx.astype(out_dtype), dgamma, dbeta
 
-    core = jax.custom_vjp(core, nondiff_argnums=(3, 4))
+    core = jax.custom_vjp(plain, nondiff_argnums=(3, 4))
     core.defvjp(fwd, bwd)
     return core
 

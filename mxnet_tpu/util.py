@@ -54,6 +54,24 @@ def mirror_enabled(explicit=None) -> bool:
     return bool(env.get("MXNET_BACKWARD_DO_MIRROR"))
 
 
+def _mirror_policy(name):
+    """The ``jax.checkpoint`` policy a MXNET_BACKWARD_MIRROR_POLICY name
+    stands for (None: save nothing)."""
+    import jax
+    if name in ("full", ""):
+        return None
+    if name == "dots":
+        return jax.checkpoint_policies.checkpoint_dots
+    if name == "convs":
+        def policy(prim, *_args, **_params):
+            return prim.name in ("conv_general_dilated", "dot_general")
+        return policy
+    from .base import MXNetError
+    raise MXNetError(
+        f"unknown MXNET_BACKWARD_MIRROR_POLICY {name!r} "
+        "(expected 'full', 'dots' or 'convs')")
+
+
 def mirror_wrapper(explicit=None):
     """Resolve the mirror decision NOW and return the wrapper to apply.
 
@@ -68,19 +86,44 @@ def mirror_wrapper(explicit=None):
         return lambda fn: fn
     import jax
     from .base import env
-    policy_name = env.get("MXNET_BACKWARD_MIRROR_POLICY") or "full"
-    policy = None
-    if policy_name == "dots":
-        policy = jax.checkpoint_policies.checkpoint_dots
-    elif policy_name == "convs":
-        def policy(prim, *_args, **_params):
-            return prim.name in ("conv_general_dilated", "dot_general")
-    elif policy_name not in ("full", ""):
-        from .base import MXNetError
-        raise MXNetError(
-            f"unknown MXNET_BACKWARD_MIRROR_POLICY {policy_name!r} "
-            "(expected 'full', 'dots' or 'convs')")
+    policy = _mirror_policy(env.get("MXNET_BACKWARD_MIRROR_POLICY") or "full")
     return lambda fn: jax.checkpoint(fn, policy=policy)
+
+
+# What a recorded hybridized forward keeps when mirroring is off: the
+# outputs that are expensive to make again (convolutions, matmuls) and the
+# reductions, whose outputs are small beside their inputs (BatchNorm's
+# per-channel sums). The elementwise chain between them (normalise, scale,
+# shift, activation) is recomputed inside the backward program.
+RESIDUAL_DEFAULT = "elementwise"
+
+
+def _save_convs_dots_reductions(prim, *avals, **params):
+    import jax
+    if prim.name in ("conv_general_dilated", "reduce_sum", "reduce_max",
+                     "reduce_min"):
+        return True
+    # a matmul with batch dimensions is attention's T x T scores a head
+    return jax.checkpoint_policies.dots_with_no_batch_dims_saveable(
+        prim, *avals, **params)
+
+
+def residual_policy_name(explicit=None) -> str:
+    """Name of what a recorded CachedOp forward recomputes in its backward
+    (read on the host, once a call: it is part of the program's cache
+    key): the MXNET_BACKWARD_MIRROR_POLICY in force when mirroring is on,
+    else :data:`RESIDUAL_DEFAULT`."""
+    if not mirror_enabled(explicit):
+        return RESIDUAL_DEFAULT
+    from .base import env
+    return env.get("MXNET_BACKWARD_MIRROR_POLICY") or "full"
+
+
+def residual_policy(name: str):
+    """The ``jax.checkpoint`` policy for a :func:`residual_policy_name`."""
+    if name == RESIDUAL_DEFAULT:
+        return _save_convs_dots_reductions
+    return _mirror_policy(name)
 
 
 def apply_mirror(fn, explicit=None):

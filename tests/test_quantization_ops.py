@@ -16,6 +16,17 @@ from mxnet_tpu.test_utils import assert_almost_equal
 RS = np.random.RandomState(11)
 
 
+@pytest.fixture(autouse=True)
+def _own_draws():
+    """Every test starts its draws (the module's stream, the initializers'
+    global ones) from the same point, whatever ran before it in the same
+    worker: top-1 parity of a random int8 net over four samples swings
+    with the draw."""
+    RS.seed(11)
+    np.random.seed(11)
+    mx.random.seed(11)
+
+
 def _q(name, inputs, params=None):
     out = nd.imperative_invoke(name, tuple(nd.array(a) for a in inputs),
                                dict(params or {}))

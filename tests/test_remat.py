@@ -201,3 +201,120 @@ def test_remat_memory_not_worse():
     plain = temp_bytes(loss)
     remat = temp_bytes(apply_mirror(loss, True))
     assert remat <= plain, f"remat temp {remat} > plain {plain}"
+
+
+# ---------------------------------------------------------------------------
+# what a recorded hybridized forward keeps for its backward (cached_op.py):
+# mirroring off saves convolution and matmul outputs and the reductions;
+# mirror=True / MXNET_BACKWARD_DO_MIRROR keep their meaning, under
+# MXNET_BACKWARD_MIRROR_POLICY
+
+def _plan(net, x, env=None):
+    """The recorded entry's plan after one forward/backward of ``net``."""
+    for k, v in (env or {}).items():
+        os.environ[k] = v
+    try:
+        with autograd.record():
+            loss = (net(x) ** 2).sum()
+        loss.backward()
+    finally:
+        for k in env or {}:
+            del os.environ[k]
+    return _recorded_entry(net).linear
+
+
+def _recorded_entry(net):
+    return [e for _, e in net._cached_op._cache.snapshot_items()
+            if e.linear is not None][-1]
+
+
+def _owned_shapes(plan):
+    return sorted(a.shape for a in plan.arena_avals)
+
+
+@pytest.mark.parametrize("how,policy,owned", [
+    ("default", "elementwise", [(8, 64)] * 6),
+    ("mirror_kwarg", "full", []),
+    ("env_full", "full", []),
+    ("env_dots", "dots", [(8, 64)] * 6),
+])
+def test_recorded_forward_keeps_what_the_policy_says(how, policy, owned):
+    """``full`` leaves only pass-through leaves (parameters, the batch, the
+    key) as residuals, the former behaviour and footprint; the default
+    keeps each hidden layer's product."""
+    net = _deep_mlp()
+    env = {}
+    if how == "mirror_kwarg":
+        net.hybridize(mirror=True)
+    else:
+        net.hybridize()
+        if how.startswith("env_"):
+            env = {"MXNET_BACKWARD_DO_MIRROR": "1",
+                   "MXNET_BACKWARD_MIRROR_POLICY": how[4:]}
+    x = nd.array(np.random.RandomState(3).randn(8, 8).astype(np.float32))
+    plan = _plan(net, x, env)
+    assert plan.policy == policy
+    assert _owned_shapes(plan) == owned
+    assert plan.residual_bytes == sum(4 * a * b for a, b in owned)
+    if not owned:
+        assert all(src < 0 for src in plan.res_src)
+
+
+def test_default_policy_keeps_reductions_not_batched_matmuls():
+    """LayerNorm's per-row sums are kept (they are small beside their
+    input); a matmul with batch dimensions (attention's scores, T x T a
+    head) is recomputed."""
+    class Block(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.norm = gluon.nn.LayerNorm()
+                self.proj = gluon.nn.Dense(16, flatten=False)
+
+        def hybrid_forward(self, F, x):
+            h = self.proj(self.norm(x))          # (2, 6, 16)
+            scores = F.batch_dot(h, h, transpose_b=True)  # (2, 6, 6)
+            return F.batch_dot(F.softmax(scores), h)
+
+    net = Block()
+    net.initialize(mx.init.Xavier())
+    x = nd.array(np.random.RandomState(4).randn(2, 6, 8).astype(np.float32))
+    net(x)
+    net.hybridize()
+    shapes = _owned_shapes(_plan(net, x))
+    assert (2, 6, 16) in shapes          # the projection's product
+    assert (2, 6, 6) not in shapes       # no scores, no softmax output
+    assert any(s in ((2, 6), (2, 6, 1)) for s in shapes)  # the sums
+
+
+def test_default_policy_keeps_batchnorm_statistics():
+    """BatchNorm's training core is a ``jax.custom_vjp``; its forward rule
+    calls the plain function, so the policy sees the per-channel sums
+    and keeps them: the backward program reduces only over cotangents,
+    it does not read the convolution's output again for the batch
+    statistics (two reductions a layer in place of four)."""
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Conv2D(4, 3, padding=1, use_bias=False),
+            gluon.nn.BatchNorm(), gluon.nn.Activation("relu"))
+    net.initialize(mx.init.Xavier())
+    x = nd.array(np.random.RandomState(6).rand(2, 3, 5, 5).astype(np.float32))
+    with autograd.pause():
+        net(x)
+    net.hybridize()
+    assert _owned_shapes(_plan(net, x)) == [(2, 4, 5, 5), (4,), (4,)]
+    entry = _recorded_entry(net)
+    bwd = entry.vjp_jitted.lower(*entry.vjp_abstract).as_text()
+    assert bwd.count("stablehlo.reduce(") == 2   # sum_dy, sum_dy_xhat
+
+
+def test_mirror_flag_is_part_of_the_recorded_key():
+    """The knobs are read on the host at every call and join the cache
+    key: setting the flag after the first step builds the other program,
+    it does not replay the first."""
+    net = _deep_mlp()
+    net.hybridize()
+    x = nd.array(np.random.RandomState(5).randn(8, 8).astype(np.float32))
+    assert _plan(net, x).policy == "elementwise"
+    assert _plan(net, x, {"MXNET_BACKWARD_DO_MIRROR": "1"}).policy == "full"
+    keys = [k.record[0] for k, _ in net._cached_op._cache.snapshot_items()]
+    assert sorted(keys) == ["elementwise", "full"]

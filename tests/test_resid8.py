@@ -54,8 +54,9 @@ def _grads(hybridize=False):
     with autograd.record():
         loss = lossfn(net(mx.nd.array(x)), mx.nd.array(y))
     loss.backward()
-    grads = [p.grad().asnumpy()
-             for _, p in sorted(net.collect_params().items())
+    # by declaration, not by name: names carry a process-wide counter
+    # ("conv10" sorts before "conv9"), so two nets can sort differently
+    grads = [p.grad().asnumpy() for p in net.collect_params().values()
              if p.grad_req != "null"]
     return float(loss.mean().asnumpy()), grads
 
@@ -159,10 +160,14 @@ def test_bn_core_fp8_residual_close():
     import jax.numpy as jnp
     from mxnet_tpu.ops.nn import _make_bn_core
 
-    xb = jnp.asarray(RS.rand(8, 6, 6, 5).astype(np.float32) * 3 + 1)
-    g32 = jnp.asarray(RS.rand(5).astype(np.float32) + 0.5)
-    b32 = jnp.asarray(RS.rand(5).astype(np.float32))
-    dyb = jnp.asarray((RS.rand(8, 6, 6, 5) - 0.5).astype(np.float32))
+    # a stream of its own: the module's RS stands wherever the tests that
+    # ran before this one in the same worker left it, and dgamma is a sum
+    # that nearly cancels, so its relative error swings with the draw
+    rs = np.random.RandomState(0)
+    xb = jnp.asarray(rs.rand(8, 6, 6, 5).astype(np.float32) * 3 + 1)
+    g32 = jnp.asarray(rs.rand(5).astype(np.float32) + 0.5)
+    b32 = jnp.asarray(rs.rand(5).astype(np.float32))
+    dyb = jnp.asarray((rs.rand(8, 6, 6, 5) - 0.5).astype(np.float32))
 
     def run(core):
         def f(d, g, b):

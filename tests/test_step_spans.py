@@ -401,19 +401,9 @@ def test_step_programs_hold_no_opt_barrier():
     loss.backward()
     op = net._cached_op
     (key_sig, entry), = op._cache.snapshot_items()
-    in_sig, param_sig, in_treedef, _training, _flags = key_sig
-
-    def sds(sig):
-        return tuple(jax.ShapeDtypeStruct(tuple(shape), np.dtype(dt))
-                     for shape, dt in sig)
-
-    key = jax.random.PRNGKey(0)
-    cots = (jax.ShapeDtypeStruct(out.shape, np.float32),)
-    op._in_treedef = in_treedef
     texts["forward"] = entry.jitted.lower(
-        sds(param_sig), key, *sds(in_sig)).as_text()
-    texts["vjp"] = entry.vjp_jitted.lower(
-        sds(param_sig), key, sds(in_sig), cots).as_text()
+        *op._abstract_args(key_sig, entry)).as_text()
+    texts["vjp"] = entry.vjp_jitted.lower(*entry.vjp_abstract).as_text()
     for name, text in texts.items():
         assert "convolution" in text, name
         assert barrier not in text, name
