@@ -72,8 +72,10 @@ class SwiGLU(HybridBlock):
 
 class DroplessMoE(HybridBlock):
     """The expert layer for the experts held here
-    (``parallel.moe.dropless_moe_ffn``). ``bias`` is the router's selection
-    bias: no gradient; in training mode each forward moves it by
+    (``parallel.moe.dropless_moe_ffn``): SwiGLU experts, or with
+    ``activation="relu2"`` experts of one product in; the shared expert is
+    ``shared_width`` wide (``width`` by default). ``bias`` is the router's
+    selection bias: no gradient; in training mode each forward moves it by
     ``gamma * sign(mean load - load)``. ``load`` (tokens routed to each of
     the router's experts in the last step) and ``tokens_here`` ((token,
     expert) pairs computed here) are counters kept on the device;
@@ -82,21 +84,24 @@ class DroplessMoE(HybridBlock):
     instances = weakref.WeakSet()
 
     def __init__(self, hidden, width, router_experts, experts_held, top_k,
-                 scaling, gamma, **kwargs):
+                 scaling, gamma, activation="swiglu", shared_width=None,
+                 **kwargs):
         super().__init__(**kwargs)
         self.instances.add(self)
         held = len(experts_held)
         self._attrs = dict(k=top_k, experts_held=tuple(experts_held),
-                           scaling=float(scaling))
+                           scaling=float(scaling), activation=activation)
         self._gamma = gamma
+        shared = shared_width or width
+        halves = 2 if activation == "swiglu" else 1
         get = self.params.get
         self.gate = get("gate", shape=(hidden, router_experts))
         self.bias = get("bias", shape=(router_experts,), init="zeros",
                         grad_req="null", differentiable=False)
-        self.w_in = get("w_in", shape=(held, hidden, 2 * width))
+        self.w_in = get("w_in", shape=(held, hidden, halves * width))
         self.w_out = get("w_out", shape=(held, width, hidden))
-        self.shared_in = get("shared_in", shape=(hidden, 2 * width))
-        self.shared_out = get("shared_out", shape=(width, hidden))
+        self.shared_in = get("shared_in", shape=(hidden, halves * shared))
+        self.shared_out = get("shared_out", shape=(shared, hidden))
         self.load = get("load", shape=(router_experts,), init="zeros",
                         grad_req="null", differentiable=False)
         self.tokens_here = get("tokens_here", shape=(1,), init="zeros",

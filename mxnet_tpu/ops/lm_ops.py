@@ -1,6 +1,9 @@
-"""Operators of a sparse latent-attention language model (the GLM-4.7-Flash
-/ DeepSeek-V2 family): RMSNorm, rotary embedding, multi-head latent
-attention (MLA), the gated FFN and the dropless expert layer.
+"""Operators of today's sparse language models. The GLM-4.7-Flash /
+DeepSeek-V2 family: RMSNorm, rotary embedding, multi-head latent attention
+(MLA), the gated FFN and the dropless expert layer. The ``nemotron_h``
+family: the Mamba-2 mixer (causal depthwise convolution, the selective
+state-space recurrence by chunks, a gated grouped RMSNorm), grouped-KV
+attention and the squared-ReLU FFN.
 
 Pure JAX functions, registered like every other op, so one definition
 serves eager NDArray calls, the autograd tape, hybridized blocks and
@@ -53,6 +56,191 @@ def swiglu_ffn(x, w_in, w_out):
     w_up]`` one (D, 2F) product."""
     import jax.numpy as jnp
     return jnp.dot(swiglu(jnp.dot(x, w_in)), w_out)
+
+
+def relu2(h):
+    """relu(h)^2, the ``nemotron_h`` family's activation."""
+    import jax
+    return jax.numpy.square(jax.nn.relu(h))
+
+
+def relu2_ffn(x, w_in, w_out):
+    """relu(x @ w_in)^2 @ w_out: one product in, not SwiGLU's two halves."""
+    import jax.numpy as jnp
+    return jnp.dot(relu2(jnp.dot(x, w_in)), w_out)
+
+
+# what an expert is: (activation of the first product, FFN)
+FFN_ACTIVATIONS = {"swiglu": (swiglu, swiglu_ffn), "relu2": (relu2, relu2_ffn)}
+
+
+def causal_conv1d(x, weight, bias):
+    """Depthwise causal convolution along T: ``y[t, c] = bias[c] + sum_j
+    weight[c, j] x[t - (W - 1) + j, c]`` with zeros before the sequence.
+    x: (B, T, C); weight: (C, W); bias: (C,). W shifted multiply-adds that
+    XLA fuses into one pass (W is 4), in float32."""
+    import jax.numpy as jnp
+    t, width = x.shape[1], weight.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32),
+                     ((0, 0), (width - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    y = bias.astype(jnp.float32)
+    for j in range(width):
+        y = y + padded[:, j:j + t] * w[:, j]
+    return y.astype(x.dtype)
+
+
+def ssd_chunked(u, dt, a, b, c, chunk=128, decay_dtype=None):
+    """The selective state-space recurrence of Mamba-2 (the "SSD" form) by
+    chunks of ``chunk`` steps.
+
+        S_t = exp(dt_t a) S_{t-1} + dt_t u_t (x) B_t;   y_t = S_t C_t
+
+    per head, ``S`` (P, N), ``S_0 = 0``; ``B`` and ``C`` are shared by the
+    heads of a group. u: (B, T, H, P); dt: (B, T, H) (after softplus); a:
+    (H,), negative; b, c: (B, T, G, N) with H a multiple of G -> y (B, T, H,
+    P) float32 (the skip ``D u`` is the caller's).
+
+    Within a chunk the outputs come from the masked decay matrix, ``y_t =
+    sum_{s <= t} exp(L_t - L_s) dt_s (C_t . B_s) u_s`` with ``L`` the
+    running sum of ``dt a`` inside the chunk: a (chunk, chunk) matrix a head
+    and chunk, never (T, T). Across chunks the (P, N) state is carried: each
+    chunk adds its own ``sum_s exp(L_end - L_s) dt_s u_s (x) B_s`` to the
+    decayed state it was handed, and ``y_t`` gains ``exp(L_t) C_t . S`` of
+    the state handed in. So the state exists once a chunk, not once a step,
+    and that is all the backward pass (JAX's, through these products) keeps.
+    Decay, running sums and the state are float32 whatever ``u`` is
+    (``decay_dtype`` is for a precision check that wants to see a lower one
+    fail); the products take ``u``'s dtype and accumulate in float32. A
+    ``T`` that ``chunk`` does not divide is padded with steps of ``dt = 0``,
+    which neither decay nor feed the state."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    slow = jnp.dtype(decay_dtype or f32)          # decays and running sums
+    bsz, t, h, p = u.shape
+    g, n = b.shape[2], b.shape[3]
+    q = min(chunk, t)
+    pad = -t % q
+    if pad:
+        u, dt, b, c = (
+            jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))
+            for z in (u, dt, b, c))
+    nc = (t + pad) // q
+    low = u.dtype
+    u = u.reshape(bsz, nc, q, g, h // g, p)
+    b = b.reshape(bsz, nc, q, g, n)
+    c = c.reshape(bsz, nc, q, g, n)
+    dt = dt.astype(f32).reshape(bsz, nc, q, g, h // g)
+    run = jnp.cumsum((dt * a.astype(f32).reshape(g, h // g)).astype(slow),
+                     axis=2)                                           # L
+    end = run[:, :, -1]                                        # (B, nc, G, Hg)
+
+    # inside a chunk: (C B^T) * decay * dt, one (q, q) matrix a head
+    cb = jnp.einsum("bcqgn,bcsgn->bcgqs", c, b, preferred_element_type=f32)
+    lq = run.transpose(0, 1, 3, 4, 2)                       # (B, nc, G, Hg, q)
+    seg = lq[..., :, None] - lq[..., None, :]               # L_t - L_s
+    causal = jnp.tril(jnp.ones((q, q), bool))
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    m = cb[:, :, :, None] * decay * dt.transpose(0, 1, 3, 4, 2)[..., None, :]
+    y = jnp.einsum("bcghqs,bcsghp->bcqghp", m.astype(low), u,
+                   preferred_element_type=f32)
+
+    # each chunk's own contribution to the state at its end
+    fed = u.astype(f32) * (jnp.exp(end[:, :, None] - run) * dt)[..., None]
+    own = jnp.einsum("bcsgn,bcsghp->bcghpn", b, fed.astype(low),
+                     preferred_element_type=f32)
+
+    # across chunks: the state handed to each chunk
+    def carry(state, xs):
+        own_c, keep_c = xs
+        return state * keep_c[..., None, None] + own_c, state
+
+    _, handed = jax.lax.scan(
+        carry, jnp.zeros((bsz, g, h // g, p, n), f32),
+        (own.transpose(1, 0, 2, 3, 4, 5), jnp.exp(end).transpose(1, 0, 2, 3)))
+    handed = handed.transpose(1, 0, 2, 3, 4, 5)          # (B, nc, G, Hg, P, N)
+    y = y + jnp.exp(run)[..., None] * jnp.einsum(
+        "bcqgn,bcghpn->bcqghp", c, handed.astype(low),
+        preferred_element_type=f32)
+    return y.reshape(bsz, nc * q, h, p)[:, :t]
+
+
+def gated_group_rms_norm(y, z, weight, groups, eps=1e-5):
+    """RMSNorm(y * silu(z)) normalised within each of ``groups`` equal
+    groups of the last axis (the gate before the norm), one weight over
+    the whole axis; the statistics in float32, the result in ``z``'s
+    dtype."""
+    import jax
+    import jax.numpy as jnp
+    x = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    grouped = x.reshape(x.shape[:-1] + (groups, -1))
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
+    return (grouped.reshape(x.shape) * weight.astype(jnp.float32)) \
+        .astype(z.dtype)
+
+
+def mamba2_mixer(x, w_in, conv_weight, conv_bias, dt_bias, a_log, d, norm,
+                 w_out, heads=1, head_dim=1, groups=1, state=1, chunk=128,
+                 eps=1e-5):
+    """The Mamba-2 mixer over x (B, T, D).
+
+    ``[z | xBC | dt] = x w_in`` (heads x head_dim, heads x head_dim + 2 x
+    groups x state, heads); ``xBC = silu(causal_conv1d(xBC))``, split into
+    ``u``, ``B``, ``C``; ``dt = softplus(dt + dt_bias)``; ``a =
+    -exp(a_log)``; ``y = ssd_chunked(u, dt, a, B, C) + d u``; ``y =
+    gated_group_rms_norm(y, z)``; ``w_out`` back to D."""
+    import jax
+    import jax.numpy as jnp
+    bsz, t, _ = x.shape
+    inner, gn = heads * head_dim, groups * state
+    with jax.named_scope("mx.mamba2"):
+        proj = jnp.dot(x, w_in)
+        z = proj[..., :inner]
+        xbc = jax.nn.silu(causal_conv1d(
+            proj[..., inner:2 * inner + 2 * gn], conv_weight, conv_bias))
+        dt = jax.nn.softplus(proj[..., 2 * inner + 2 * gn:].astype(
+            jnp.float32) + dt_bias.astype(jnp.float32))
+        u = xbc[..., :inner].reshape(bsz, t, heads, head_dim)
+        with jax.named_scope("mx.ssd"):
+            y = ssd_chunked(
+                u, dt, -jnp.exp(a_log.astype(jnp.float32)),
+                xbc[..., inner:inner + gn].reshape(bsz, t, groups, state),
+                xbc[..., inner + gn:].reshape(bsz, t, groups, state), chunk)
+        y = y + d.astype(jnp.float32)[:, None] * u.astype(jnp.float32)
+        y = gated_group_rms_norm(y.reshape(bsz, t, inner), z, norm, groups,
+                                 eps)
+        return jnp.dot(y, w_out)
+
+
+def gqa_attention(x, w_q, w_k, w_v, w_o, heads=1, kv_heads=1, head_dim=1):
+    """Causal grouped-KV attention over x (B, T, D), no position embedding:
+    ``heads`` query heads, query head ``h`` reads KV head ``h // (heads /
+    kv_heads)``; softmax of ``q.k / sqrt(head_dim)``. The attention kernels
+    (``blocked_attention``) see one key and value head a query head: the KV
+    heads are repeated on the way in, and their gradients summed over the
+    group on the way back by the repeat's transpose."""
+    import jax
+    import jax.numpy as jnp
+    from .pallas_kernels import blocked_attention
+    bsz, t, _ = x.shape
+    rep = heads // kv_heads
+
+    def split(y, n):                              # (B, T, n*d) -> (B, n, T, d)
+        return y.reshape(bsz, t, n, head_dim).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("mx.gqa"):
+        q = split(jnp.dot(x, w_q), heads)
+        k = jnp.repeat(split(jnp.dot(x, w_k), kv_heads), rep, axis=1)
+        v = jnp.repeat(split(jnp.dot(x, w_v), kv_heads), rep, axis=1)
+        o = blocked_attention(
+            q.reshape(bsz * heads, t, head_dim),
+            k.reshape(bsz * heads, t, head_dim),
+            v.reshape(bsz * heads, t, head_dim), causal=True,
+            scale=head_dim ** -0.5)
+        o = o.reshape(bsz, heads, t, head_dim).transpose(0, 2, 1, 3)
+        return jnp.dot(o.reshape(bsz, t, heads * head_dim), w_o)
 
 
 def mla_attention(x, w_qa, q_norm, w_qb, w_kva, kv_norm, w_kvb, w_o,
@@ -114,9 +302,26 @@ def _mla_attention_op(x, w_qa, q_norm, w_qb, w_kva, kv_norm, w_kvb, w_o,
                          v_dim=v_dim, theta=theta, eps=eps)
 
 
+@register("_contrib_mamba2_mixer")
+def _mamba2_mixer_op(x, w_in, conv_weight, conv_bias, dt_bias, a_log, d,
+                     norm, w_out, heads=1, head_dim=1, groups=1, state=1,
+                     chunk=128, eps=1e-5):
+    return mamba2_mixer(x, w_in, conv_weight, conv_bias, dt_bias, a_log, d,
+                        norm, w_out, heads=heads, head_dim=head_dim,
+                        groups=groups, state=state, chunk=chunk, eps=eps)
+
+
+@register("_contrib_gqa_attention")
+def _gqa_attention_op(x, w_q, w_k, w_v, w_o, heads=1, kv_heads=1,
+                      head_dim=1):
+    return gqa_attention(x, w_q, w_k, w_v, w_o, heads=heads,
+                         kv_heads=kv_heads, head_dim=head_dim)
+
+
 @register("_contrib_dropless_moe", num_outputs=3, aux_inputs=(2,))
 def _dropless_moe_op(x, gate, bias, w_in, w_out, shared_in, shared_out,
-                     k=1, experts_held=None, scaling=1.0):
+                     k=1, experts_held=None, scaling=1.0,
+                     activation="swiglu"):
     """``parallel.moe.dropless_moe_ffn``: (y, load over all experts, pairs
     computed here), the two counters as float32."""
     import jax.numpy as jnp
@@ -124,6 +329,6 @@ def _dropless_moe_op(x, gate, bias, w_in, w_out, shared_in, shared_out,
     y, stats = dropless_moe_ffn(
         x, {"gate": gate, "bias": bias, "w_in": w_in, "w_out": w_out,
             "shared_in": shared_in, "shared_out": shared_out},
-        k, experts_held, scaling)
+        k, experts_held, scaling, activation=activation)
     return (y, stats["load"].astype(jnp.float32),
             stats["tokens_here"].astype(jnp.float32))

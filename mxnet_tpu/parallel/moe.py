@@ -163,19 +163,25 @@ _TILE = 256  # rows of one product of the grouped matmul
 
 
 def init_dropless_moe_params(key, d_model: int, d_ff: int, n_experts: int,
-                             experts_held=None, dtype=None) -> Dict[str, Any]:
+                             experts_held=None, dtype=None,
+                             activation: str = "swiglu",
+                             shared_ff: Optional[int] = None
+                             ) -> Dict[str, Any]:
     """``dropless_moe_ffn``'s parameters: a router over all ``n_experts``, a
-    selection bias (no gradient), gated expert weights for the experts held
-    (all of them by default) and one shared expert."""
+    selection bias (no gradient), expert weights for the experts held (all
+    of them by default; gated for ``swiglu``, one product in for ``relu2``)
+    and one shared expert of width ``shared_ff`` (``d_ff`` by default)."""
     import jax
     import jax.numpy as jnp
     held = n_experts if experts_held is None else len(experts_held)
+    gated = activation == "swiglu"
     k_gate, k_ffn, k_shared = jax.random.split(key, 3)
-    shared = init_expert_ffn(k_shared, 1, d_model, d_ff, True, dtype)
+    shared = init_expert_ffn(k_shared, 1, d_model, shared_ff or d_ff, gated,
+                             dtype)
     return {
         "gate": init_router(k_gate, d_model, n_experts),
         "bias": jnp.zeros((n_experts,), jnp.float32),
-        **init_expert_ffn(k_ffn, held, d_model, d_ff, True, dtype),
+        **init_expert_ffn(k_ffn, held, d_model, d_ff, gated, dtype),
         "shared_in": shared["w_in"][0], "shared_out": shared["w_out"][0],
     }
 
@@ -368,12 +374,14 @@ def _dispatch_ops():
 
 def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
                      experts_held=None, scaling: float = 1.0,
-                     tile: int = _TILE):
+                     tile: int = _TILE, activation: str = "swiglu"):
     """The expert layer of a sparse language model, for the experts held.
 
-    ``y = sum_e w_e E_e(x) + E_shared(x)`` with ``E`` a SwiGLU, the sum over
-    the token's top-k experts THAT ARE HELD HERE (``experts_held``: their
-    ids among the router's; None: all, the whole layer). Routing is over
+    ``y = sum_e w_e E_e(x) + E_shared(x)``, the sum over the token's top-k
+    experts THAT ARE HELD HERE (``experts_held``: their ids among the
+    router's; None: all, the whole layer). ``E`` is a SwiGLU (``w_in`` holds
+    gate and up, 2F wide) or, with ``activation="relu2"``, ``relu(x w_in)^2
+    w_out``; the shared expert's width is its weights' own. Routing is over
     all of the router's experts and drops nothing: tokens are sorted by
     expert into whole tiles and a grouped product runs over the tiles in
     use. What an absent expert would add is left out, and nothing stands in
@@ -385,7 +393,8 @@ def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
     """
     import jax
     import jax.numpy as jnp
-    from ..ops.lm_ops import swiglu, swiglu_ffn
+    from ..ops.lm_ops import FFN_ACTIVATIONS
+    act, ffn = FFN_ACTIVATIONS[activation]
     b, t, d = x.shape
     n = b * t
     n_experts = params["gate"].shape[1]
@@ -399,10 +408,10 @@ def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
             chosen, held, n_experts, tile)
     with jax.named_scope("mx.moe.experts"):
         xs = _dispatch(xf, row, token)
-        h = swiglu(grouped_matmul(xs, params["w_in"], tile_group, n_active))
+        h = act(grouped_matmul(xs, params["w_in"], tile_group, n_active))
         ys = grouped_matmul(h, params["w_out"], tile_group, n_active)
         y = _combine(ys, w, row, token)
-        shared = swiglu_ffn(xf, params["shared_in"], params["shared_out"])
+        shared = ffn(xf, params["shared_in"], params["shared_out"])
     stats = {"load": load.astype(jnp.int32),
              "tokens_here": jnp.sum(row >= 0).astype(jnp.int32)}
     return (y + shared).reshape(b, t, d), stats

@@ -1050,6 +1050,9 @@ COVERED_ELSEWHERE = {
     **{op: "tests/test_glm_moe_lite.py" for op in [
         "_contrib_rms_norm", "_contrib_swiglu_ffn", "_contrib_mla_attention",
         "_contrib_dropless_moe", "_contrib_blocked_attention"]},
+    # the nemotron_h family's mixers (ops/lm_ops.py)
+    **{op: "tests/test_nemotron_h.py" for op in [
+        "_contrib_mamba2_mixer", "_contrib_gqa_attention"]},
     # pallas fused conv epilogues (fwd+grad parity, fallback, fold)
     **{op: "tests/test_fused_epilogue.py" for op in [
         "_contrib_fused_bn_relu", "_contrib_fused_bn_add_relu"]},

@@ -145,6 +145,40 @@ def test_blocked_attention_compiles_for_v5e(one_chip, bh, t, dk, dv, dtype,
     assert _n_kernels(jax.jit(bwd).lower(q, q, v, v, lse, v).compile()) == 2
 
 
+def test_mamba2_mixer_compiles_for_v5e_without_a_square_or_a_state_a_step(
+        one_chip):
+    """The Mamba-2 mixer of Nemotron-3-Nano at its published widths and
+    8,192 tokens, forward and recomputed backward as the benchmark's cell
+    runs it: XLA's lowering of the chunked scan fits beside 13 GB of model,
+    and neither a (T, T) matrix a head nor the state at every step is among
+    the program's arrays (the largest is the projection's output)."""
+    import re
+    from mxnet_tpu.ops import lm_ops
+    t, d, h, p, g, n = 8192, 2688, 64, 64, 8, 128
+    inner, conv = h * p, h * p + 2 * g * n
+    bf = jnp.bfloat16
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, bf, sharding=one_chip)
+
+    args = (sds(1, t, d), sds(d, inner + conv + h), sds(conv, 4), sds(conv),
+            sds(h), sds(h), sds(h), sds(inner), sds(inner, d))
+
+    def loss(*a):
+        mixer = jax.checkpoint(lambda *a: lm_ops.mamba2_mixer(
+            *a, heads=h, head_dim=p, groups=g, state=n, chunk=128))
+        return jnp.sum(mixer(*a).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(9)))).lower(
+        *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    sizes = [int(np.prod([int(x) for x in dims.split(",")]))
+             for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]",
+                                    compiled.as_text())]
+    assert max(sizes) <= t * (inner + conv + h)          # 84M elements
+    assert max(sizes) < min(h * t * t, t * h * p * n) // 40
+
+
 @pytest.mark.parametrize("n_tiles,n_f32", [(1, 2), (2, 2), (3, 4), (5, 3)])
 @pytest.mark.parametrize("c,itemsize", [(64, 2), (1024, 2), (2048, 2),
                                         (256, 4)])
