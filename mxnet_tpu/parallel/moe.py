@@ -20,8 +20,15 @@ the whole layer stays MXU/XLA friendly — no dynamic gather loops.
 ``dropless_moe_ffn`` is the layer of today's sparse language models: it
 routes every token (sigmoid scores, top-k on score + bias, no auxiliary
 loss), is told WHICH experts it holds, and computes those experts' part of
-the result by a sorted dispatch and a grouped product. Both draw their
-router and their expert weights from ``init_router`` / ``init_expert_ffn``.
+the result in one loop over the tiles of rows that were routed here. What
+is sized by what: the plan's index arrays (the pair of each row, the expert
+of each tile) have a place for all N k (token, choice) pairs, so any routing
+fits; everything D or F wide has N rows (the input, the float32 accumulator
+of the result, their gradients) or the rows of one tile (the gathered
+tokens, the hidden activations, the expert's output), made and used inside
+the loop, so the routed path costs what was routed here. Both layers draw
+their router and their expert weights from ``init_router`` /
+``init_expert_ffn``.
 """
 from __future__ import annotations
 
@@ -31,8 +38,7 @@ from typing import Any, Dict, Optional, Tuple
 
 __all__ = ["init_router", "init_expert_ffn", "init_moe_params",
            "moe_param_specs", "moe_ffn", "init_dropless_moe_params",
-           "route_topk", "dropless_moe_ffn", "grouped_matmul",
-           "balance_bias_update"]
+           "route_topk", "dropless_moe_ffn", "balance_bias_update"]
 
 _INIT_SCALE = 0.02
 
@@ -159,7 +165,12 @@ def moe_ffn(x, params: Dict[str, Any], n_experts: int,
 # The dropless layer
 # ---------------------------------------------------------------------------
 
-_TILE = 256  # rows of one product of the grouped matmul
+# Rows of one pass of the routed experts' loop. Padding costs rows of one tile
+# an expert, and a tile of the backward reads and writes the expert's two
+# float32 weight gradients whole: at both language models' widths 512 beats
+# 256 and 1024 on a v5e, under even routing and with every token on one
+# expert (PERF.md section 6, PR 34).
+_TILE = 512
 
 
 def init_dropless_moe_params(key, d_model: int, d_ff: int, n_experts: int,
@@ -199,7 +210,10 @@ def route_topk(x, gate, bias, k: int, scaling: float = 1.0):
     s = jax.nn.sigmoid(jnp.dot(x, gate.astype(x.dtype),
                                preferred_element_type=jnp.float32))
     _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
-    w = jnp.take_along_axis(s, chosen, axis=-1)
+    # s at the chosen experts, as a masked sum of one term: a gather of N k
+    # scalars and the scatter that transposes it cost the chip 0.4 ms each
+    picked = chosen[..., None] == jnp.arange(s.shape[-1])[None, None, :]
+    w = jnp.sum(jnp.where(picked, s[:, None, :], 0.0), axis=-1)
     w = scaling * w / jnp.sum(w, axis=-1, keepdims=True)
     return chosen, w
 
@@ -212,66 +226,95 @@ def balance_bias_update(bias, load, gamma: float):
     return bias + gamma * jnp.sign(jnp.mean(load) - load)
 
 
-def _grouped_product(x, w, tile_group, n_active, transpose_w: bool):
-    """out[rows of tile i] = x[rows of tile i] @ w[tile_group[i]] for the
-    first ``n_active`` tiles, zero below. A loop with a traced trip count:
-    only the tiles that hold tokens cost anything."""
+def _take_rows(x, index):
+    """x[index], zero where the index is past the end (a padding row)."""
+    import jax.numpy as jnp
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+def _add_rows(acc, index, rows):
+    """acc[index] += rows; an index past the end (a padding row) is dropped.
+    XLA's scatter, which on a v5e takes 0.4 us for a row of 2,688 float32
+    and 1.0 us once it is told that the indices are sorted and unique
+    (PERF.md section 6, PR 34): so it is not told."""
+    return acc.at[index].add(rows, mode="drop")
+
+
+def _dot(a, b, contract):
     import jax
     import jax.numpy as jnp
-    tile = x.shape[0] // tile_group.shape[0]
-    n_out = w.shape[1] if transpose_w else w.shape[2]
-    dims = (((1,), (1,)), ((), ())) if transpose_w \
-        else (((1,), (0,)), ((), ()))
-
-    def body(i, out):
-        rows = jax.lax.dynamic_slice_in_dim(x, i * tile, tile)
-        wi = jax.lax.dynamic_index_in_dim(w, tile_group[i], keepdims=False)
-        y = jax.lax.dot_general(rows, wi, dims,
-                                preferred_element_type=jnp.float32)
-        return jax.lax.dynamic_update_slice_in_dim(
-            out, y.astype(out.dtype), i * tile, 0)
-
-    return jax.lax.fori_loop(
-        0, n_active, body, jnp.zeros((x.shape[0], n_out), x.dtype))
-
-
-def grouped_matmul(x, w, tile_group, n_active):
-    """Grouped product over the experts held. ``x`` (R, K) holds the
-    dispatched tokens sorted by expert, every expert's rows padded to whole
-    tiles of ``R / len(tile_group)`` rows; ``w`` is (G, K, N);
-    ``tile_group[i]`` is the expert of tile i and ``n_active`` the number of
-    tiles in use. Returns (R, N), zero in the unused tiles. Differentiable
-    in ``x`` and ``w``; the weight gradient accumulates in float32."""
-    return _grouped_matmul_op()(x, w, tile_group, n_active)
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 @functools.lru_cache(maxsize=None)
-def _grouped_matmul_op():
+def _routed_experts_op(act, tile: int):
+    """The routed path as ONE differentiable op, ``out[n] = sum over n's
+    pairs held here of w[pair] * E_expert(x[n])``: a loop with a traced
+    trip count over the tiles in use, in each direction. Nothing D or F
+    wide is larger than (N, D) or one tile, and the backward keeps only the
+    op's own inputs: it makes a tile's rows, pre-activation and ``h``
+    again."""
     import jax
     import jax.numpy as jnp
+    f32 = jnp.float32
+
+    def rows_of(i, w, pair, tile_group):
+        """Tile i: (its pairs, past the end in a padding row; their tokens,
+        likewise; their routing weights, 0 in a padding row; its expert)."""
+        n, k = w.shape
+        pairs = jax.lax.dynamic_slice_in_dim(pair, i * tile, tile)
+        tokens = jnp.where(pairs < n * k, pairs // k, n)
+        scale = jnp.take(w.reshape(-1), pairs, mode="fill", fill_value=0)
+        return pairs, tokens, scale[:, None], tile_group[i]
+
+    def hidden(x, w_in, tokens, group):
+        xs = _take_rows(x, tokens)
+        return xs, _dot(xs, w_in[group], (1, 0)).astype(x.dtype)
 
     @jax.custom_vjp
-    def op(x, w, tile_group, n_active):
-        return _grouped_product(x, w, tile_group, n_active, False)
+    def op(x, w_in, w_out, w, pair, tile_group, n_active):
+        def body(i, out):
+            _, tokens, scale, group = rows_of(i, w, pair, tile_group)
+            h = act(hidden(x, w_in, tokens, group)[1])
+            return _add_rows(out, tokens,
+                             scale * _dot(h, w_out[group], (1, 0)))
 
-    def fwd(x, w, tile_group, n_active):
-        return op(x, w, tile_group, n_active), (x, w, tile_group, n_active)
+        return jax.lax.fori_loop(0, n_active, body,
+                                 jnp.zeros(x.shape, f32)).astype(x.dtype)
 
-    def bwd(res, dy):
-        x, w, tile_group, n_active = res
-        tile = x.shape[0] // tile_group.shape[0]
-        dx = _grouped_product(dy, w, tile_group, n_active, True)
+    def fwd(*args):
+        return op(*args), args
 
-        def body(i, dw):
-            rows = jax.lax.dynamic_slice_in_dim(x, i * tile, tile)
-            dyi = jax.lax.dynamic_slice_in_dim(dy, i * tile, tile)
-            g = jax.lax.dot_general(rows, dyi, (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            return dw.at[tile_group[i]].add(g)
+    def bwd(res, dout):
+        x, w_in, w_out, w, pair, tile_group, n_active = res
 
-        dw = jax.lax.fori_loop(0, n_active, body,
-                               jnp.zeros(w.shape, jnp.float32))
-        return dx.astype(x.dtype), dw.astype(w.dtype), None, None
+        def body(i, carry):
+            dx, dw_in, dw_out, dw = carry
+            pairs, tokens, scale, group = rows_of(i, w, pair, tile_group)
+            xs, pre = hidden(x, w_in, tokens, group)
+            h, act_vjp = jax.vjp(act, pre)
+            dys = _take_rows(dout, tokens)
+            # d out / d (h w_out) before the routing weight scales it: with
+            # h it is the weight's own gradient, sum_d y dout, and y is not
+            # made again
+            dh = _dot(dys, w_out[group], (1, 1))
+            dw = dw.at[pairs].set(jnp.sum(h.astype(f32) * dh, axis=1),
+                                  mode="drop")
+            dy = (scale * dys.astype(f32)).astype(x.dtype)
+            dw_out = dw_out.at[group].add(_dot(h, dy, (0, 0)))
+            dpre, = act_vjp((scale * dh).astype(h.dtype))
+            dw_in = dw_in.at[group].add(_dot(xs, dpre, (0, 0)))
+            dx = _add_rows(dx, tokens, _dot(dpre, w_in[group], (1, 1)))
+            return dx, dw_in, dw_out, dw
+
+        dx, dw_in, dw_out, dw = jax.lax.fori_loop(
+            0, n_active, body,
+            (jnp.zeros(x.shape, f32), jnp.zeros(w_in.shape, f32),
+             jnp.zeros(w_out.shape, f32), jnp.zeros((w.size,), f32)))
+        return (dx.astype(x.dtype), dw_in.astype(w_in.dtype),
+                dw_out.astype(w_out.dtype), dw.reshape(w.shape).astype(w.dtype),
+                None, None, None)
 
     op.defvjp(fwd, bwd)
     return op
@@ -279,97 +322,34 @@ def _grouped_matmul_op():
 
 def _dispatch_plan(chosen, experts_held, n_experts: int, tile: int):
     """Where every (token, choice) pair goes. Rows are sorted by held
-    expert and each expert's rows start on a tile boundary; a pair whose
-    expert is not held here has no row. Returns (row of each pair (N, k),
-    -1 where not held; token of each row (R,), N where the row is padding;
-    tile_group; n_active; per-expert load over ALL experts)."""
-    import jax
+    expert, an expert's rows in token order and starting on a tile
+    boundary; a pair whose expert is not held here has no row. Index arrays
+    only, sized for all N k pairs (R = N k + g tiles' worth of rows: whole
+    tiles of every expert fit whatever the routing). Returns (pair of each
+    row (R,), N k where the row is padding; expert of each tile; tiles in
+    use; load over ALL the router's experts; pairs with a row here)."""
     import jax.numpy as jnp
-    import numpy as np
     n, k = chosen.shape
     g = len(experts_held)
-    local = np.full((n_experts,), g, np.int32)        # g: "not held here"
-    local[np.asarray(experts_held)] = np.arange(g, dtype=np.int32)
     flat = chosen.reshape(-1)
-    group = jnp.asarray(local)[flat]                                 # (N*k,)
-    onehot = (group[:, None] == jnp.arange(g)[None, :]).astype(jnp.int32)
+    onehot = (flat[:, None] == jnp.asarray(experts_held, flat.dtype)[None, :]
+              ).astype(jnp.int32)                                 # (N k, g)
     rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
     count = jnp.sum(onehot, axis=0)                                   # (g,)
     tiles = (count + tile - 1) // tile
-    first_tile = jnp.cumsum(tiles) - tiles
-    n_tiles = (n * k) // tile + g        # whole tiles of every expert fit
-    held = group < g
-    row = jnp.where(held, first_tile[jnp.minimum(group, g - 1)] * tile
-                    + rank, -1)
-    token = jnp.full((n_tiles * tile,), n, jnp.int32).at[
-        jnp.where(held, row, n_tiles * tile)].set(
-            jnp.arange(n * k, dtype=jnp.int32) // k, mode="drop")
-    tile_group = jnp.clip(
-        jnp.searchsorted(jnp.cumsum(tiles), jnp.arange(n_tiles),
-                         side="right"), 0, g - 1).astype(jnp.int32)
+    last_tile = jnp.cumsum(tiles)
+    n_tiles = (n * k) // tile + g
+    first_row = jnp.sum(onehot * ((last_tile - tiles) * tile)[None, :], axis=1)
+    row = jnp.where(jnp.sum(onehot, axis=1) > 0, first_row + rank,
+                    n_tiles * tile)
+    pair = jnp.full((n_tiles * tile,), n * k, jnp.int32).at[row].set(
+        jnp.arange(n * k, dtype=jnp.int32), mode="drop")
+    tile_group = jnp.minimum(
+        jnp.sum(jnp.arange(n_tiles)[:, None] >= last_tile[None, :], axis=1),
+        g - 1).astype(jnp.int32)
     load = jnp.sum(flat[:, None] == jnp.arange(n_experts)[None, :], axis=0)
-    return (row.reshape(n, k), token, tile_group,
-            jnp.sum(tiles).astype(jnp.int32), load)
-
-
-def _gather_rows(x, index, valid):
-    import jax.numpy as jnp
-    rows = jnp.take(x, jnp.where(valid, index, 0), axis=0)
-    return jnp.where(valid[..., None], rows, jnp.zeros((), x.dtype))
-
-
-def _dispatch(x, row, token):
-    """xs[r] = x[token[r]] (zero in padding rows). Its transpose is a
-    gather too: dx[n] = sum over n's choices of dxs[row[n, choice]], so
-    neither direction scatters."""
-    return _dispatch_ops()[0](x, row, token)
-
-
-def _combine(ys, w, row, token):
-    """out[n] = sum over n's choices of w[n, choice] * ys[row[n, choice]];
-    a choice with no row here adds nothing."""
-    return _dispatch_ops()[1](ys, w, row, token)
-
-
-@functools.lru_cache(maxsize=None)
-def _dispatch_ops():
-    import jax
-    import jax.numpy as jnp
-    f32 = jnp.float32
-
-    @jax.custom_vjp
-    def dispatch(x, row, token):
-        return _gather_rows(x, token, token < x.shape[0])
-
-    def dispatch_bwd(row, dxs):
-        return jnp.sum(_gather_rows(dxs, row, row >= 0), axis=1), None, None
-
-    dispatch.defvjp(lambda x, row, token: (dispatch(x, row, token), row),
-                    dispatch_bwd)
-
-    @jax.custom_vjp
-    def combine(ys, w, row, token):
-        picked = _gather_rows(ys, row, row >= 0)                 # (N, k, D)
-        return jnp.einsum("nkd,nk->nd", picked, w.astype(ys.dtype),
-                          preferred_element_type=f32).astype(ys.dtype)
-
-    def combine_bwd(res, dout):
-        ys, w, row, token = res
-        picked = _gather_rows(ys, row, row >= 0)
-        dw = jnp.einsum("nkd,nd->nk", picked, dout,
-                        preferred_element_type=f32).astype(w.dtype)
-        # weight of each row: its pair's; rows are unique per pair
-        w_row = jnp.zeros((ys.shape[0],), f32).at[
-            jnp.where(row >= 0, row, ys.shape[0]).reshape(-1)].set(
-                w.reshape(-1).astype(f32), mode="drop")
-        dys = _gather_rows(dout, token, token < row.shape[0]) \
-            * w_row[:, None].astype(dout.dtype)
-        return dys.astype(ys.dtype), dw, None, None
-
-    combine.defvjp(lambda ys, w, row, token:
-                   (combine(ys, w, row, token), (ys, w, row, token)),
-                   combine_bwd)
-    return dispatch, combine
+    return (pair, tile_group, last_tile[-1].astype(jnp.int32), load,
+            jnp.sum(count))
 
 
 def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
@@ -379,17 +359,28 @@ def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
 
     ``y = sum_e w_e E_e(x) + E_shared(x)``, the sum over the token's top-k
     experts THAT ARE HELD HERE (``experts_held``: their ids among the
-    router's; None: all, the whole layer). ``E`` is a SwiGLU (``w_in`` holds
-    gate and up, 2F wide) or, with ``activation="relu2"``, ``relu(x w_in)^2
-    w_out``; the shared expert's width is its weights' own. Routing is over
-    all of the router's experts and drops nothing: tokens are sorted by
-    expert into whole tiles and a grouped product runs over the tiles in
-    use. What an absent expert would add is left out, and nothing stands in
-    for the chips that hold it or for their exchange.
+    router's, each once; None: all, the whole layer). ``E`` is a SwiGLU
+    (``w_in`` holds gate and up, 2F wide) or, with ``activation="relu2"``,
+    ``relu(x w_in)^2 w_out``; the shared expert's width is its weights' own.
+    Routing is over all of the router's experts and drops nothing, whatever
+    it sends here: the pairs held here are sorted by expert into whole tiles
+    of ``tile`` rows, and ONE loop with a traced trip count runs over the
+    tiles in use (one more in the backward pass, which makes a tile's rows
+    and activations again from the layer's inputs). A tile gathers its
+    tokens from ``x``, runs its expert's two products (operands in ``x``'s
+    dtype, float32 accumulation, the pre-activation rounded to ``x``'s
+    dtype) and adds its rows, scaled by their routing weights, into an
+    (N, D) float32 accumulator. So the index arrays of the plan are sized
+    for all N k pairs, and every D- or F-wide array by N or by one tile:
+    the layer costs what was routed here. What an absent expert would add is
+    left out, and nothing stands in for the chips that hold it or for their
+    exchange.
 
     x: (B, T, D) -> (y (B, T, D), stats) with ``stats["load"]`` the tokens
-    routed to each of ALL experts (int32) and ``stats["tokens_here"]`` the
-    (token, expert) pairs computed here.
+    routed to each of ALL experts (int32), ``stats["tokens_here"]`` the
+    (token, expert) pairs computed here and ``stats["tiles_run"]`` the
+    tiles the loop ran (each pass): under ``tokens_here / tile`` + the
+    number of experts held.
     """
     import jax
     import jax.numpy as jnp
@@ -404,14 +395,13 @@ def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
     xf = x.reshape(n, d)
     with jax.named_scope("mx.moe.route"):
         chosen, w = route_topk(xf, params["gate"], params["bias"], k, scaling)
-        row, token, tile_group, n_active, load = _dispatch_plan(
+        pair, tile_group, n_active, load, here = _dispatch_plan(
             chosen, held, n_experts, tile)
     with jax.named_scope("mx.moe.experts"):
-        xs = _dispatch(xf, row, token)
-        h = act(grouped_matmul(xs, params["w_in"], tile_group, n_active))
-        ys = grouped_matmul(h, params["w_out"], tile_group, n_active)
-        y = _combine(ys, w, row, token)
+        y = _routed_experts_op(act, tile)(
+            xf, params["w_in"], params["w_out"], w, pair, tile_group,
+            n_active)
         shared = ffn(xf, params["shared_in"], params["shared_out"])
     stats = {"load": load.astype(jnp.int32),
-             "tokens_here": jnp.sum(row >= 0).astype(jnp.int32)}
+             "tokens_here": here.astype(jnp.int32), "tiles_run": n_active}
     return (y + shared).reshape(b, t, d), stats

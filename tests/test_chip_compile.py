@@ -179,6 +179,47 @@ def test_mamba2_mixer_compiles_for_v5e_without_a_square_or_a_state_a_step(
     assert max(sizes) < min(h * t * t, t * h * p * n) // 40
 
 
+def test_expert_layer_compiles_for_v5e_with_no_array_of_all_pairs(one_chip):
+    """The expert layer of Nemotron-3-Nano at its published widths (8,192
+    tokens, 8 of 128 relu2 experts held, six a token, the wider shared
+    expert), forward and recomputed backward as the benchmark's cell runs
+    it: nothing D or F wide has a row for every (token, choice) pair. The
+    largest array is the float32 weight gradient of the experts held; the
+    smallest array of all pairs would be 49,152 x 1,856, over twice that."""
+    import re
+    from mxnet_tpu.parallel import moe
+    n, d, f, shared, router, k, held = 8192, 2688, 1856, 3712, 128, 6, 8
+    bf = jnp.bfloat16
+
+    def sds(*shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(1, n, d), sds(d, router, dtype=jnp.float32),
+            sds(held, d, f), sds(held, f, d), sds(d, shared), sds(shared, d))
+
+    def layer(x, gate, w_in, w_out, shared_in, shared_out):
+        params = {"gate": gate, "bias": jnp.zeros((router,), jnp.float32),
+                  "w_in": w_in, "w_out": w_out, "shared_in": shared_in,
+                  "shared_out": shared_out}
+        return moe.dropless_moe_ffn(x, params, k, tuple(range(held)), 2.5,
+                                    activation="relu2")[0]
+
+    def loss(*a):
+        return jnp.sum(jax.checkpoint(layer)(*a).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile()
+    # the compiler reads 829 MB (1,320 MB before the loop): two float32
+    # weight gradients of 160 MB, the shared expert's 8,192 x 3,712 and
+    # (N, D) accumulators
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    sizes = [int(np.prod([int(x) for x in dims.split(",")]))
+             for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]",
+                                    compiled.as_text())]
+    assert max(sizes) <= held * d * f                    # 40M elements
+    assert max(sizes) < n * k * f // 2
+
+
 @pytest.mark.parametrize("n_tiles,n_f32", [(1, 2), (2, 2), (3, 4), (5, 3)])
 @pytest.mark.parametrize("c,itemsize", [(64, 2), (1024, 2), (2048, 2),
                                         (256, 4)])

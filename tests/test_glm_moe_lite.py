@@ -276,6 +276,63 @@ def test_expert_layer_gradients(held):
             assert rel(got[1][name], want[1][name]) < 1e-5, name
 
 
+# The routed path is one loop over the tiles in use, with its own backward:
+# held experts, selection bias by expert, tokens a sequence (two sequences),
+# tile. N k = 296 is no multiple of 16; a bias of 5 sends every token to an
+# expert, one of -5 none.
+ROUTED_CASES = {
+    "all_held": (None, {}, 40, 8),
+    "subset": ((0, 1, 2, 3), {}, 40, 8),
+    "ragged_tiles": ((5, 9, 12), {}, 37, 16),
+    "all_to_one_held_expert": ((2, 3), {3: 5.0}, 40, 8),
+    "none_chosen": ((5, 9), {5: -5.0, 9: -5.0}, 40, 8),
+}
+
+
+@pytest.mark.parametrize("case", ROUTED_CASES)
+def test_routed_loop_is_the_plain_masked_sum(case):
+    """Output, counters and every gradient of the layer against the plain
+    masked sum, whatever the routing sends here: everything, a share, rows
+    that fill no whole tile, every token on one expert, nothing."""
+    held, bias, t, tile = ROUTED_CASES[case]
+    ids = held or tuple(range(E))
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, t, D))
+    p = _layer_params(held)
+    for e, b in bias.items():
+        p["bias"] = p["bias"].at[e].set(b)
+
+    def ours(x, p):
+        y, stats = moe.dropless_moe_ffn(x, p, K, held, 1.8, tile=tile)
+        return jnp.sum(y ** 2), (y, stats)
+
+    def plain(x, p):
+        y = _plain_layer(x, p, ids)
+        return jnp.sum(y ** 2), y
+
+    (_, (y, stats)), got = jax.value_and_grad(ours, (0, 1), has_aux=True)(x, p)
+    (_, want_y), want = jax.value_and_grad(plain, (0, 1), has_aux=True)(x, p)
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
+    here, tiles = int(stats["tokens_here"]), int(stats["tiles_run"])
+    assert here == int(stats["load"][np.asarray(ids)].sum())
+    assert 0 <= tiles * tile - here < len(ids) * tile   # padding: under a tile an expert
+    assert rel(got[0], want[0]) < 1e-5
+    for name in p:
+        if name == "bias":
+            assert not np.any(np.asarray(got[1][name]))
+        else:
+            assert rel(got[1][name], want[1][name]) < 1e-5, name
+    if case == "all_to_one_held_expert":
+        assert int(stats["load"][3]) == 2 * t <= here
+    if case == "none_chosen":
+        assert here == tiles == 0
+        np.testing.assert_allclose(
+            y, reference.swiglu(x, p["shared_in"], p["shared_out"]),
+            rtol=1e-5, atol=1e-5)
+        assert not np.any(np.asarray(got[1]["w_in"]))
+        assert not np.any(np.asarray(got[1]["w_out"]))
+        assert not np.any(np.asarray(got[1]["gate"]))
+
+
 def test_bias_moves_toward_balance_and_takes_no_gradient():
     """Training steps move the selection bias of an overloaded expert down
     and of a starved one up, by gamma a step; the optimizer never touches

@@ -510,6 +510,65 @@ def test_eight_of_128_held_leaves_out_exactly_the_absent_terms():
     np.testing.assert_allclose(y.reshape(-1, D), want, rtol=1e-5, atol=1e-4)
 
 
+# As tests/test_glm_moe_lite.py's ROUTED_CASES, for squared-ReLU experts under
+# a 16-wide router choosing four: held experts, selection bias by expert,
+# tokens a sequence, tile.
+ROUTED_CASES = {
+    "all_held": (None, {}, 40, 8),
+    "subset": ((0, 1, 2, 3), {}, 40, 8),
+    "ragged_tiles": ((5, 9, 12), {}, 37, 16),
+    "all_to_one_held_expert": ((2, 3), {3: 5.0}, 40, 8),
+    "none_chosen": ((5, 9), {5: -5.0, 9: -5.0}, 40, 8),
+}
+
+
+@pytest.mark.parametrize("case", ROUTED_CASES)
+def test_relu2_routed_loop_is_the_plain_masked_sum(case):
+    """The loop over the tiles in use and its backward with ``relu2``
+    experts: output, counters and every gradient against the plain masked
+    sum for everything held, a share, rows that fill no whole tile, every
+    token on one expert, and no token here."""
+    held, bias, t, tile = ROUTED_CASES[case]
+    experts, k = 16, 4
+    ids = held or tuple(range(experts))
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, t, D))
+    p = _layer_params(held, experts=experts)
+    for e, b in bias.items():
+        p["bias"] = p["bias"].at[e].set(b)
+
+    def ours(x, p):
+        y, stats = moe.dropless_moe_ffn(x, p, k, held, 2.5, tile=tile,
+                                        activation="relu2")
+        return jnp.sum(y ** 2), (y, stats)
+
+    def plain(x, p):
+        y = _plain_layer(x, p, ids, k)
+        return jnp.sum(y ** 2), y
+
+    (_, (y, stats)), got = jax.value_and_grad(ours, (0, 1), has_aux=True)(x, p)
+    (_, want_y), want = jax.value_and_grad(plain, (0, 1), has_aux=True)(x, p)
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-4)
+    here, tiles = int(stats["tokens_here"]), int(stats["tiles_run"])
+    assert here == int(stats["load"][np.asarray(ids)].sum())
+    assert 0 <= tiles * tile - here < len(ids) * tile
+    assert rel(got[0], want[0]) < 1e-5
+    for name in p:
+        if name == "bias":
+            assert not np.any(np.asarray(got[1][name]))
+        else:
+            assert rel(got[1][name], want[1][name]) < 1e-5, name
+    if case == "all_to_one_held_expert":
+        assert int(stats["load"][3]) == 2 * t <= here
+    if case == "none_chosen":
+        assert here == tiles == 0
+        np.testing.assert_allclose(
+            y, reference.relu2_ffn(x, p["shared_in"], p["shared_out"]),
+            rtol=1e-5, atol=1e-4)
+        assert not np.any(np.asarray(got[1]["w_in"]))
+        assert not np.any(np.asarray(got[1]["w_out"]))
+        assert not np.any(np.asarray(got[1]["gate"]))
+
+
 @pytest.mark.parametrize("held", [None, (0, 1, 2, 3), (5, 9)])
 def test_relu2_expert_layer_gradients(held):
     experts = 16
