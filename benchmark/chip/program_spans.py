@@ -11,15 +11,15 @@ launches the spans own; the readers under ``metrics/`` take medians over the
 steps. A program without such spans (the parent of the PR that added them)
 gives no steps, and the readers return nothing.
 
-``gaps_by_span(xplane_path)`` lays the same spans, which a session also
-writes into the trace's host plane, against the device's idle gaps.
+A session also writes the same spans into the trace's host plane, where
+``trace_reduce`` lays them against the device's idle gaps;
+``gaps_by_span(xplane_path)`` gives that for a kept trace.
 
     python3 benchmark/chip/program_spans.py <file.xplane.pb>
 
 prints that as one JSON object.
 """
 import json
-import pathlib
 import statistics
 import sys
 from collections import defaultdict
@@ -90,14 +90,6 @@ def steps(events=None, skip=trace_reduce.SKIP):
     return out
 
 
-def other_ms(step):
-    """What is left of a Gluon step's period once its forward, backward and
-    update are taken out; None where one of them has no span."""
-    phases = [step["root_ms"].get(name) for name in (FORWARD, BACKWARD, UPDATE)]
-    if None not in phases:
-        return step["period_ms"] - sum(phases)
-
-
 def median(values):
     """Over the steps that have the value; None where none has."""
     values = [v for v in values if v is not None]
@@ -110,92 +102,24 @@ def root_ms(name, events=None):
 
 
 # ---------------------------------------------------------------------------
-# the device's idle gaps under the program's spans
+# a kept trace's idle gaps under the program's spans
 
-def load_host(path, prefixes=("mx.", "bench.")):
-    """The host plane's spans whose names start with one of ``prefixes``,
-    as ``(name, start_s, end_s)``."""
-    import gzip
-    from jax.profiler import ProfileData
-    raw = pathlib.Path(path).read_bytes()
-    if str(path).endswith(".gz"):
-        raw = gzip.decompress(raw)
-    host = []
-    for plane in ProfileData.from_serialized_xspace(raw).planes:
-        if plane.name == "/host:CPU":
-            for line in plane.lines:
-                host += [(e.name, e.start_ns * 1e-9,
-                          (e.start_ns + e.duration_ns) * 1e-9)
-                         for e in line.events if e.name.startswith(prefixes)]
-    return sorted(host, key=lambda s: s[1])
-
-
-def innermost(spans, s, e):
-    """The name of the shortest span that covers more than half of the gap
-    (s, e): spans of one thread nest, so that is the innermost. Where none
-    covers half, the one that covers most; "host.between" where none
-    touches it."""
-    best, key = "host.between", None
-    for name, hs, he in spans:
-        overlap = min(e, he) - max(s, hs)
-        if overlap <= 0:
-            continue
-        k = (1, hs - he) if 2 * overlap > e - s else (0, overlap)
-        if key is None or k > key:
-            best, key = name, k
-    return best
-
-
-def gaps_by_span(xplane_path, skip=trace_reduce.SKIP, top=10):
-    """The idle gaps of the first device in the traced window
-    (``trace_reduce``'s window and interval arithmetic), each put down to
-    the innermost ``mx.`` span that covers most of it (to the ``bench.``
-    span where no ``mx.`` span touches it): the ``top`` longest, and the
-    idle milliseconds per step by span. None where the trace has no window
-    or no device."""
-    trace = trace_reduce.load(xplane_path)
-    win = trace_reduce.window(trace["host"], skip)
-    if win is None or not trace["devices"]:
-        return None
-    dev = next(iter(trace["devices"].values()))
-    lo = trace_reduce.snap(dev["modules"], win[0])
-    hi = trace_reduce.snap(dev["modules"], win[1])
-    busy = trace_reduce.union([(max(s, lo), min(e, hi))
-                               for _, _, s, e in dev["ops"]
-                               if min(e, hi) > max(s, lo)])
-    host = load_host(xplane_path)
-    ours = [h for h in host if h[0].startswith("mx.")]
-    named = []
-    for s, e in trace_reduce.subtract([(lo, hi)], busy):
-        name = innermost(ours, s, e)
-        if name == "host.between":
-            name = innermost(host, s, e)
-        named.append((name, e - s))
-    per_step = defaultdict(float)
-    for name, seconds in named:
-        per_step[name] += 1e3 * seconds / win[2]
-    return {
-        "steps": win[2], "window_ms_per_step": 1e3 * (hi - lo) / win[2],
-        "idle_ms_per_step": sum(per_step.values()),
-        "longest_ms": [[name, 1e3 * seconds] for name, seconds in
-                       sorted(named, key=lambda p: -p[1])[:top]],
-        "idle_ms_per_step_by_span": dict(
-            sorted(per_step.items(), key=lambda p: -p[1])),
-        "host_spans": _host_spans(host, win),
-    }
-
-
-def _host_spans(host, win):
-    """For each span name, over the spans that start inside the window: how
-    many a step, their median milliseconds, and their milliseconds a step."""
-    ms = defaultdict(list)
-    for name, s, e in host:
-        if win[0] <= s < win[1]:
-            ms[name].append(1e3 * (e - s))
-    return {name: {"per_step": len(v) / win[2],
-                   "median_ms": statistics.median(v),
-                   "ms_per_step": sum(v) / win[2]}
-            for name, v in sorted(ms.items(), key=lambda p: -sum(p[1]))}
+def gaps_by_span(xplane_path):
+    """What every traced run's ``breakdown.idle_gaps`` is cut from, in full,
+    for a trace kept with ``--keep-trace``: the idle milliseconds a step of
+    the first device by the host span ``trace_reduce`` puts each gap down
+    to. None where the trace has no window or no device."""
+    out = trace_reduce.reduce(trace_reduce.load(xplane_path))
+    if out:
+        by_span = {name: 1e3 * seconds / out["steps"] for name, seconds in
+                   out["idle_seconds_by_span"].items()}
+        return {"steps": out["steps"],
+                "window_ms_per_step": 1e3 * out["window_s"] / out["steps"],
+                "idle_ms_per_step": sum(by_span.values()),
+                "idle_ms_per_step_by_span": dict(
+                    sorted(by_span.items(), key=lambda p: -p[1])),
+                "longest_ms": [[name, 1e3 * seconds]
+                               for name, seconds in out["idle_gaps"]]}
 
 
 if __name__ == "__main__":

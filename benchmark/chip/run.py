@@ -7,8 +7,10 @@
 Runs one cell of ``BENCHMARK.json`` on the machine it is started on and
 prints, as the last line of its standard output, one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (with
-``--trace 1`` also ``breakdown``). Every name in ``BENCHMARK.json`` resolves
-to a file beside this one, and nothing else is imported by hand:
+``--trace 1`` also ``breakdown``), and last in it, as on the last lines of
+standard error, ``compared``: each number ``correct`` rests on beside its
+limit. Every name in ``BENCHMARK.json`` resolves to a file beside this one,
+and nothing else is imported by hand:
 
     configs/<config>.json   sizes of the model, source, what was assumed
     models/<config>.py      plain reference: loss(), flops_per_sample()
@@ -20,10 +22,16 @@ to a file beside this one, and nothing else is imported by hand:
 A cell is a closed loop that dispatches step i+1 before it waits for step i.
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` measures
 most of the window the same way, then profiles a few steps, and reports the
-cell's per-layer metrics. Without a TPU the run exits non-zero and prints no
-result. ``--rehearse DIR`` (the tests) takes the cells from DIR's
-``workloads.json`` and their ``configs/`` and ``traffic/`` from DIR too, lets
-a CPU pass and stamps it as one.
+cell's per-layer metrics. ``trace_reduce`` reads that trace under the
+program's own names: every device op under the ``jax.named_scope`` path in
+its ``op_name`` (the ``mx_*_ms`` and ``scope_rest_ms`` metrics;
+``breakdown.device_ops`` and, in milliseconds a step, ``scopes``), every
+kernel under its instruction's name (the ``*_roofline`` metrics), every idle
+gap under the innermost ``bench.`` or ``mx.`` host span that covers it
+(``breakdown.idle_gaps``, ``idle_ms_per_step``). Without a TPU the run exits
+non-zero and prints no result. ``--rehearse DIR`` (the tests) takes the cells
+from DIR's ``workloads.json`` and their ``configs/`` and ``traffic/`` from
+DIR too, lets a CPU pass and stamps it as one.
 """
 import time
 T_START = time.perf_counter()
@@ -92,6 +100,8 @@ class Loop:
                     self.i += 1
                     if len(pending) < self.ahead:
                         continue
+                if not pending:  # ahead 1: the last wait left nothing
+                    break
                 with span("bench.wait"):
                     loss = float(self.path.wait(pending.popleft()))
                 done.append(time.perf_counter())
@@ -199,23 +209,36 @@ def main():
         "reference": reference, "peaks": peaks, "setup_s": setup_s,
         "done": done, "dispatch_s": dispatch_s, "trace": trace,
     }
+    listed = [m for m in bench["per_layer" if args.trace else "end_to_end"]
+              if cell["name"] in m.get("workloads", [cell["name"]])]
+    # the cell's readers, for one that asks what the others read
+    run["readers"] = {m["name"]: load_module("metrics", m["name"])
+                      for m in listed}
     metrics = {}
-    for m in bench["per_layer" if args.trace else "end_to_end"]:
-        if cell["name"] in m.get("workloads", [cell["name"]]):
-            value = load_module("metrics", m["name"]).read(run)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    for m in listed:
+        value = run["readers"][m["name"]].read(run)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
     # ---- correct ----------------------------------------------------------
     pool = traffic["pool"]
     placed = set(devices)
+    first_pass = statistics.fmean(loop.losses[:pool] or [0])
+    last_pass = statistics.fmean(loop.losses[-pool:] or [0])
+    compared = {  # name: [number, limit]
+        "first_step_loss_gap": [
+            abs(first_loss - ref_loss) / (abs(ref_loss) + 1),
+            reference.TOLERANCE],
+        "steps_failed": [loop.failed, 0],
+        "last_pass_over_first_pass_loss": [
+            last_pass / first_pass if first_pass else None, 1.0],
+        "compiled_in_window": [compiled_in_window, 0],
+    }
     checks = {
-        "reference": abs(first_loss - ref_loss) / (abs(ref_loss) + 1)
+        "reference": compared["first_step_loss_gap"][0]
         <= reference.TOLERANCE,
         "no_step_failed": loop.failed == 0 and loop.attempted > 0,
-        "loss_fell": len(loop.losses) >= 2 * pool and
-        statistics.fmean(loop.losses[-pool:])
-        < statistics.fmean(loop.losses[:pool]),
+        "loss_fell": len(loop.losses) >= 2 * pool and last_pass < first_pass,
         "on_device": all(a.sharding.device_set == placed
                          for a in path.state()),
         "no_compile_in_window": compiled_in_window == 0,
@@ -236,8 +259,7 @@ def main():
         "failed": loop.failed, "metrics": metrics, "device": device,
         "checks": checks,
         "losses": {"reference": ref_loss, "first_step": first_loss,
-                   "first_pass": statistics.fmean(loop.losses[:pool] or [0]),
-                   "last_pass": statistics.fmean(loop.losses[-pool:] or [0])},
+                   "first_pass": first_pass, "last_pass": last_pass},
         "compiled_in_window": compiled_in_window,
         "setup": {k: b - a for (k, b), a in zip(
             marks.items(), [T_START] + list(marks.values()))},
@@ -247,6 +269,13 @@ def main():
             trace["busy_s"], trace["window_s"]
         result["breakdown"] = {"device_ops": trace["device_ops"],
                                "idle_gaps": trace["idle_gaps"]}
+        for key, by in (("scopes", "seconds_by_scope"),
+                        ("idle_ms_per_step", "idle_seconds_by_span")):
+            result[key] = {name: 1e3 * seconds / trace["steps"]
+                           for name, seconds in trace[by].items()}
+    result["compared"] = compared
+    for name, (number, limit) in compared.items():
+        print(f"compared {name}: {number} limit {limit}", file=sys.stderr)
     print(json.dumps(result), flush=True)
 
 

@@ -1,4 +1,4 @@
-"""``program_spans`` on a hand-made event list, and the five span metrics in
+"""``program_spans`` on a hand-made event list, and the four span metrics in
 a rehearsed traced run of the tiny Gluon and SPMD cells."""
 import json
 import pathlib
@@ -59,12 +59,7 @@ def test_steps_are_grouped_and_the_ends_left_out():
         assert s["self_ms"][ps.BACKWARD] == pytest.approx(5)
         assert s["self_ms"][ps.UPDATE] == pytest.approx(5)
         assert s["self_ms"][ps.FORWARD] == pytest.approx(10)
-        assert ps.other_ms(s) == pytest.approx(20)
     assert ps.root_ms(ps.BACKWARD, events) == pytest.approx(30)
-    # the four metrics sum to the period
-    s = steps[0]
-    assert sum(s["root_ms"].values()) + ps.other_ms(s) \
-        == pytest.approx(s["period_ms"])
 
 
 def test_a_missing_phase_is_none_not_zero():
@@ -72,10 +67,10 @@ def test_a_missing_phase_is_none_not_zero():
         n, missing=(ps.BACKWARD, "mx.cached_op.vjp", "mx.autograd.deliver"))]
     steps = ps.steps(events, skip=1)
     assert len(steps) == 4
-    assert all(ps.other_ms(s) is None for s in steps)
+    assert all(ps.BACKWARD not in s["root_ms"] for s in steps)
     assert ps.root_ms(ps.BACKWARD, events) is None
     assert ps.root_ms(ps.FORWARD, events) == pytest.approx(10)
-    assert ps.median(ps.other_ms(s) for s in steps) is None
+    assert ps.median(s["root_ms"].get(ps.BACKWARD) for s in steps) is None
 
 
 def test_no_step_spans_no_steps():
@@ -100,18 +95,6 @@ def test_spmd_steps():
     assert steps[0]["self_ms"]["mx.spmd.step"] == pytest.approx(2)
 
 
-def test_innermost_span_of_a_gap():
-    spans = [("bench.dispatch", 0.0, 10.0), ("mx.trainer.step", 4.0, 10.0),
-             ("mx.trainer.update", 5.0, 9.0), ("mx.cached_op.forward", 0.5, 2)]
-    # the innermost of those that cover most of the gap
-    assert ps.innermost(spans, 6.0, 7.0) == "mx.trainer.update"
-    assert ps.innermost(spans, 4.2, 5.2) == "mx.trainer.step"
-    assert ps.innermost(spans, 2.5, 3.5) == "bench.dispatch"
-    # none covers half: the one that covers most
-    assert ps.innermost(spans[1:], 1.5, 4.4) == "mx.cached_op.forward"
-    assert ps.innermost(spans, 11.0, 12.0) == "host.between"
-
-
 @pytest.mark.parametrize("cell", ["resnet50_train_gluon",
                                   "resnet50_train_spmd"])
 def test_span_metrics_in_a_rehearsed_traced_run(cell):
@@ -122,13 +105,12 @@ def test_span_metrics_in_a_rehearsed_traced_run(cell):
     assert line["correct"] is True, line
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert m["dispatches_per_step"] >= 2
-    phases = ["host_forward_ms", "host_backward_ms", "host_update_ms",
-              "host_other_ms"]
+    phases = ["host_forward_ms", "host_backward_ms", "host_update_ms"]
     if cell.endswith("gluon"):
-        # each step's four sum to its period by construction (above); the
-        # line holds their medians
         assert all(m[p] > 0 for p in phases)
-        assert m["dispatches_per_step"] > 20
+        # what the cell reads on the chip since PR 30, one update program
+        # a bucket key (ledger, PRs 30 - 34); 55 before it
+        assert m["dispatches_per_step"] == 15
     else:
         assert not set(phases) & set(m)
         assert m["dispatches_per_step"] == 2

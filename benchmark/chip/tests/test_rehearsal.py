@@ -54,6 +54,31 @@ def test_cell_end_to_end(cell, trace):
     assert not (ROOT / ".bench_trace" / cell["name"]).exists()
 
 
+@pytest.mark.parametrize("trace", [0, 1])
+def test_fitloop_with_one_step_in_flight(trace):
+    """``FitLoop`` on its own thread, the harness one batch ahead and no
+    more: the last wait leaves nothing in flight, and the loop drains with
+    no failure. The loss scale stays 1, so nothing compiles in the window."""
+    done = run(["--rehearse", str(HERE / "rehearse_35"), "--workload",
+                "resnet50_train_fitloop", "--seed", str(2**31 + 35),
+                "--seconds", "8", "--trace", str(trace)])
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True, line
+    assert line["failed"] == 0 and line["attempted"] >= 4
+    assert line["compiled_in_window"] == 0
+    assert set(line["metrics"]) == (
+        {"host_dispatch_ms"} if trace else {"samples_per_s", "setup_s"})
+    # the numbers correct rests on, each beside its limit: last in the
+    # line, and the last lines of standard error
+    assert list(line)[-1] == "compared"
+    said = [l for l in done.stderr.splitlines() if l.startswith("compared ")]
+    assert done.stderr.splitlines()[-len(said):] == said
+    assert [l.split()[1].rstrip(":") for l in said] == list(line["compared"])
+    for (number, limit), l in zip(line["compared"].values(), said):
+        assert l.split()[2:] == [str(number), "limit", str(limit)]
+
+
 def test_no_tpu_no_result():
     done = run(["--workload", REAL["workloads"][0]["name"], "--seed", "1",
                 "--seconds", "1", "--trace", "0"])
@@ -80,3 +105,40 @@ def test_benchmark_json_resolves_to_files():
         p.name[:-3] for p in (chip / "metrics").glob("*.py")}
     e2e = {m["name"] for m in REAL["end_to_end"]}
     assert all(m["moves"] in e2e for m in REAL["per_layer"])
+    # a metric lists cells that exist, and every cell reports a layer metric
+    cells = [w["name"] for w in REAL["workloads"]]
+    for m in REAL["per_layer"] + REAL["end_to_end"]:
+        assert set(m.get("workloads", cells)) <= set(cells), m["name"]
+    assert all(any(c in m.get("workloads", cells) for m in REAL["per_layer"])
+               for c in cells)
+
+
+def test_the_cells_and_metrics_of_pr_35():
+    cells = {w["name"]: w for w in REAL["workloads"]}
+    assert len(cells) == 7
+    assert [w["name"] for w in cells.values() if w["chips"] == 4] \
+        == ["resnet50_train_dp4"]
+    fitloop = cells["resnet50_train_fitloop"]
+    assert (fitloop["config"], fitloop["traffic"], fitloop["chips"]) \
+        == ("resnet50_v1", "fitloop_b256", 1)
+    layer = {m["name"]: m for m in REAL["per_layer"]}
+    assert not {"step_ms_p95.gluon", "host_other_ms"} & set(layer)
+    lang = ["glm_4_7_flash_train_spmd_s8k", "nemotron_3_nano_train_spmd_s8k"]
+    scopes = {"mx_mamba2_ms": lang[1:], "mx_ssd_ms": lang[1:],
+              "mx_gqa_ms": lang[1:], "mx_mla_ms": lang[:1],
+              "mx_moe_ms": lang, "scope_rest_ms": lang}
+    for name, where in scopes.items():
+        m = layer[name]
+        assert (m["workloads"], m["unit"], m["better"], m["source"]) \
+            == (where, "ms", "lower", "device_trace"), name
+    for kernel in ("fwd", "dq", "dkv"):
+        assert layer[f"mx_attention_{kernel}_roofline"]["workloads"] == lang
+    tail = next(m for m in REAL["end_to_end"] if m["name"] == "step_ms_p95")
+    # the device-paced cells; not the one the host paces, nor a language cell
+    assert tail["workloads"] == [
+        "resnet50_train_spmd", "inception_v3_train_spmd",
+        "resnet50_train_gluon", "resnet50_train_dp4"]
+    # FitLoop closes its steps without Trainer.step: no span says how many
+    # programs it launched, and a metric with no list must read everywhere
+    assert "resnet50_train_fitloop" not in layer[
+        "dispatches_per_step"]["workloads"]
