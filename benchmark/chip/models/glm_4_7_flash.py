@@ -219,6 +219,21 @@ def loss(params, data, label, config, mtp_weight=None):
     return main + weight * mtp
 
 
+def loss_of_logits(heads, label, config):
+    """The loss of a batch whose (logits, MTP logits) are given, float32."""
+    main, mtp = (h.astype(jnp.float32) for h in heads)
+    return cross_entropy(main, label[..., 0]) + config["mtp_loss_weight"] \
+        * cross_entropy(mtp, label[..., 1])
+
+
+def score(params, data, label, config):
+    """(loss, (logits, MTP logits)) of one forward pass, float32: what a
+    path that does not train is compared with, a sequence at a time."""
+    with jax.default_matmul_precision("highest"):
+        heads = forward(params, data, config)
+        return loss_of_logits(heads, label, config), heads
+
+
 # ---------------------------------------------------------------------------
 # operations and bytes, from the shapes
 
@@ -258,7 +273,9 @@ def flops_per_sample(config):
 
 
 def kernel_costs(config, batch):
-    """{kernel: (FLOPs, bytes)} a training step over all its call sites:
+    """{kernel: (FLOPs, bytes)} a step over all its call sites (the
+    forward kernel runs once a step, trained or not; the other two only
+    where the step trains):
     what the algorithm needs, causal attention at half the square, every
     operand read and every result written once, bf16 (the log-sum-exp and
     the row sums float32)."""

@@ -20,6 +20,26 @@ and nothing else is imported by hand:
     metrics/<metric>.py     read(run) -> number or None, for every metric
 
 A cell is a closed loop that dispatches step i+1 before it waits for step i.
+There are two kinds of path, and the traffic file says which (``"trains":
+false``; absent, the path trains). ``correct`` follows what the path does:
+
+    a path that trains hands the harness ``initial`` (its parameters before
+    the first step) and ``pool``: the first step's loss is held against the
+    reference's, during set-up, and the last pass over the pool has to lose
+    less than the first (``loss_fell``).
+
+    a path that does not train hands it ``pool`` and, once the window has
+    closed and the peak of memory is read, ``produced()``: the float32
+    weights, and what the window's last step on ``pool[0]`` produced at the
+    timed sizes (the per-sequence losses and every head's logits), letting
+    go of its net. The reference (``score()`` of models/<config>.py) then
+    runs a sequence at a time: each head's logits by their relative L2
+    error, and the worst sequence's loss against the reference's loss of
+    the same logits (``outputs_match``, limits in the traffic file's
+    ``limits``); every batch has to lose at its last completion in the
+    window what it lost at its first (``same_every_pass``, limit 0).
+    Nothing of it is counted in ``setup_s``.
+
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` measures
 most of the window the same way, then profiles a few steps, and reports the
 cell's per-layer metrics. ``trace_reduce`` reads that trace under the
@@ -114,6 +134,47 @@ class Loop:
         return done, dispatch_s
 
 
+def outputs_gaps(reference, config, batch, params, losses, heads):
+    """What a path that does not train produced for ``batch`` against the
+    plain reference, a sequence at a time so that it fits: ({"logits_gap.
+    head<i>": relative L2 error of head i's logits over the batch against
+    ``score``'s, "sequence_loss_gap": the worst sequence's |loss - the
+    reference's loss of the same logits| / (|the reference's| + 1)},
+    ``score``'s loss of the batch). The first is the net's number and sees
+    a precision; the second is the loss program's and sees what is left out
+    of a mean. Every gap is None where the path produced other shapes than
+    the reference."""
+    import jax
+    import jax.numpy as jnp
+    tokens, labels = batch
+    score = jax.jit(lambda p, t, l: reference.score(p, t, l, config))
+    loss_of = jax.jit(lambda h, l: reference.loss_of_logits(h, l, config))
+    parts = jax.jit(lambda got, ref: (
+        jnp.sum((got.astype(jnp.float32) - ref) ** 2), jnp.sum(ref ** 2)))
+    n = tokens.shape[0]
+    ref_losses, loss_gaps = [], []
+    for b in range(n):
+        one = slice(b, b + 1)
+        ref_loss, want = score(params, tokens[one], labels[one])
+        ref_losses.append(float(ref_loss))
+        if b == 0:
+            sums = [[0.0, 0.0] for _ in want]
+            fits = losses.shape == (n,) and [h.shape for h in heads] == [
+                (n,) + ref.shape[1:] for ref in want]
+        if not fits:
+            continue
+        for total, got, ref in zip(sums, heads, want):
+            err, norm = parts(got[one], ref)
+            total[0] += float(err)
+            total[1] += float(norm)
+        own = float(loss_of([h[one] for h in heads], labels[one]))
+        loss_gaps.append(abs(float(losses[b]) - own) / (abs(own) + 1))
+    gaps = {f"logits_gap.head{i}": math.sqrt(err / norm) if fits else None
+            for i, (err, norm) in enumerate(sums)}
+    gaps["sequence_loss_gap"] = max(loss_gaps) if fits else None
+    return gaps, statistics.fmean(ref_losses)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -159,10 +220,12 @@ def main():
         config, traffic, args.seed, devices)
     marks["build_s"] = time.perf_counter()
     reference = load_module("models", cell["config"])
-    ref_loss = float(jax.jit(
-        lambda p, d, l: reference.loss(p, d, l, config))(
-            path.initial, *path.pool[0]))
-    del path.initial
+    trains = traffic.get("trains", True)
+    if trains:
+        ref_loss = float(jax.jit(
+            lambda p, d, l: reference.loss(p, d, l, config))(
+                path.initial, *path.pool[0]))
+        del path.initial
     marks["reference_s"] = time.perf_counter()
     loop = Loop(path, traffic["ahead"])
     loop.run(count=1)
@@ -197,6 +260,7 @@ def main():
     else:
         done, dispatch_s = loop.run(until=t0 + args.seconds)
     compiled_in_window = len(compiles) - compiled_before
+    t_closed = time.perf_counter()
 
     # ---- what was measured ------------------------------------------------
     kind = devices[0].device_kind
@@ -220,31 +284,52 @@ def main():
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
+    placed = set(devices)
+    on_device = all(a.sharding.device_set == placed for a in path.state())
+    stats = [d.memory_stats() or {} for d in devices]
+
     # ---- correct ----------------------------------------------------------
     pool = traffic["pool"]
-    placed = set(devices)
     first_pass = statistics.fmean(loop.losses[:pool] or [0])
     last_pass = statistics.fmean(loop.losses[-pool:] or [0])
+    if trains:
+        of_kind = {"last_pass_over_first_pass_loss": [
+            last_pass / first_pass if first_pass else None, 1.0]}
+        by_kind = {"loss_fell": len(loop.losses) >= 2 * pool
+                   and last_pass < first_pass}
+    else:
+        # the steps complete in the order they were issued: step j of the
+        # window ran batch j % pool
+        last = {j % pool: loss for j, loss in enumerate(loop.losses)}
+        change = max(abs(last[b] - loss)
+                     for b, loss in enumerate(loop.losses[:pool])) \
+            if loop.failed == 0 and len(loop.losses) >= 2 * pool else None
+        gaps, ref_loss = outputs_gaps(
+            reference, config, path.pool[0], *path.produced())
+        of_kind = {"loss_change_between_passes": [change, 0],
+                   **{name: [gap, traffic["limits"][name]]
+                      for name, gap in gaps.items()}}
+        by_kind = {"same_every_pass": change == 0,
+                   "outputs_match": all(
+                       gap is not None and gap <= traffic["limits"][name]
+                       for name, gap in gaps.items())}
     compared = {  # name: [number, limit]
         "first_step_loss_gap": [
             abs(first_loss - ref_loss) / (abs(ref_loss) + 1),
             reference.TOLERANCE],
         "steps_failed": [loop.failed, 0],
-        "last_pass_over_first_pass_loss": [
-            last_pass / first_pass if first_pass else None, 1.0],
+        **of_kind,
         "compiled_in_window": [compiled_in_window, 0],
     }
     checks = {
         "reference": compared["first_step_loss_gap"][0]
         <= reference.TOLERANCE,
         "no_step_failed": loop.failed == 0 and loop.attempted > 0,
-        "loss_fell": len(loop.losses) >= 2 * pool and last_pass < first_pass,
-        "on_device": all(a.sharding.device_set == placed
-                         for a in path.state()),
+        **by_kind,
+        "on_device": on_device,
         "no_compile_in_window": compiled_in_window == 0,
         "known_device": peaks is not None or bool(args.rehearse),
     }
-    stats = [d.memory_stats() or {} for d in devices]
     device = {
         "platform": devices[0].platform, "kind": kind,
         "count": len(jax.devices()),
@@ -263,6 +348,9 @@ def main():
         "compiled_in_window": compiled_in_window,
         "setup": {k: b - a for (k, b), a in zip(
             marks.items(), [T_START] + list(marks.values()))},
+        # after the window: reading the trace, and the reference of a path
+        # that does not train
+        "closing_s": time.perf_counter() - t_closed,
     }
     if trace:
         device["busy_s"], device["window_s"] = \

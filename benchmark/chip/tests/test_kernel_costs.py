@@ -1,5 +1,5 @@
-"""The FLOPs and bytes the attention kernels' rooflines rest on, by hand,
-and the roofline reader on a hand-made reading."""
+"""The FLOPs and bytes the attention kernels' rooflines and the step's MFU
+rest on, by hand, and their readers on hand-made readings."""
 import json
 import pathlib
 import sys
@@ -79,3 +79,42 @@ def test_a_reference_without_kernels_has_no_roofline():
            "trace": {"steps": 4, "seconds_by_kind": {"mx_attention_fwd": 1}}}
     assert run.load_module(
         "metrics", "mx_attention_fwd_roofline").read(out) is None
+
+
+def test_glm_kernel_costs_are_of_a_step_trained_or_not():
+    """The forward kernel runs once a step whether the step trains or
+    scores, so its entry serves both kinds of path: the docstrings say "a
+    step", and the reader asks the traffic for its batch alone."""
+    _, _, costs = costs_of("glm_4_7_flash")
+    assert "a step over all its call sites" in costs.__doc__
+    assert "a training step" not in costs.__doc__
+    header = run.load_module("metrics", "kernel_roofline").__doc__
+    assert "in a step" in header and "training step" not in header
+    # four sequences a step: four times one sequence's forward
+    assert costs(json.loads((CHIP / "configs" / "glm_4_7_flash.json")
+                            .read_text()), 4)["mx_attention_fwd"][0] \
+        == 4 * 6 * 20 * 8192 ** 2 * 512
+
+
+def test_mfu_counts_the_forward_once_where_the_path_does_not_train():
+    """``flops_per_sample`` is forward x 3. A made-up run of the scoring
+    traffic at 11 sequences a second reads 55% of the chip's peak; the same
+    run read as training would read three times that, which no chip gives."""
+    config, reference, _ = costs_of("glm_4_7_flash")
+    mfu = run.load_module("metrics", "mfu")
+    done = [i * 4 / 11.0 for i in range(20)]  # a batch of 4 every 364 ms
+    scored = {"reference": reference, "config": config, "peaks": PEAKS,
+              "cell": {"chips": 1}, "done": done,
+              "traffic": json.loads((CHIP / "traffic"
+                                     / "score_lm_s8192_b4.json").read_text())}
+    assert scored["traffic"]["trains"] is False
+    per_sample = reference.flops_per_sample(config)
+    assert mfu.flops(scored) == per_sample / 3
+    assert mfu.read(scored) == pytest.approx(
+        100 * 11 * per_sample / 3 / 197e12)
+    assert 50 < mfu.read(scored) < 100
+    trained = dict(scored, traffic={"batch": 4})  # no "trains": it trains
+    assert mfu.flops(trained) == per_sample
+    assert mfu.read(trained) == pytest.approx(3 * mfu.read(scored))
+    assert mfu.read(trained) > 100
+    assert mfu.read(dict(scored, peaks=None)) is None

@@ -13,8 +13,24 @@ import pytest
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[2]
 RUN = [sys.executable, str(HERE.parent / "run.py")]
-CELLS = json.loads((HERE / "rehearse" / "workloads.json").read_text())
+# (rehearsal directory, cell): every path on a configuration of its own kind
+CELLS = [(d, cell) for d in ("rehearse", "rehearse_36")
+         for cell in json.loads((HERE / d / "workloads.json").read_text())
+         if "mantissa3" not in cell["name"]]  # the control: test_correct.py
 REAL = json.loads((ROOT / "BENCHMARK.json").read_text())
+# what correct rests on, by kind of path: names and order
+CHECKS = {
+    True: ["reference", "no_step_failed", "loss_fell", "on_device",
+           "no_compile_in_window", "known_device"],
+    False: ["reference", "no_step_failed", "same_every_pass",
+            "outputs_match", "on_device", "no_compile_in_window",
+            "known_device"]}
+COMPARED = {
+    True: ["first_step_loss_gap", "steps_failed",
+           "last_pass_over_first_pass_loss", "compiled_in_window"],
+    False: ["first_step_loss_gap", "steps_failed",
+            "loss_change_between_passes", "logits_gap.head0",
+            "logits_gap.head1", "sequence_loss_gap", "compiled_in_window"]}
 
 
 def run(args, devices=1):
@@ -26,17 +42,24 @@ def run(args, devices=1):
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-@pytest.mark.parametrize("cell", CELLS, ids=lambda c: c["name"])
-def test_cell_end_to_end(cell, trace):
-    if trace and cell["config"] != "resnet50_v1":
+@pytest.mark.parametrize("where, cell", CELLS, ids=[c["name"] for _, c in CELLS])
+def test_cell_end_to_end(where, cell, trace):
+    traffic = json.loads((HERE / where / "traffic"
+                          / f"{cell['traffic']}.json").read_text())
+    trains = traffic.get("trains", True)
+    if trace and trains and cell["config"] != "resnet50_v1":
         pytest.skip("the traced flow is the same for every configuration")
-    done = run(["--rehearse", str(HERE / "rehearse"), "--workload",
+    done = run(["--rehearse", str(HERE / where), "--workload",
                 cell["name"], "--seed", str(2**31 + 11), "--seconds", "8",
                 "--trace", str(trace)], devices=cell["chips"])
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
     assert line["correct"] is True, line
+    # a path that trains is held to what it was held to before there was
+    # another kind, under the same names; one that does not, to its own
+    assert list(line["checks"]) == CHECKS[trains]
+    assert list(line["compared"]) == COMPARED[trains]
     assert line["failed"] == 0 and line["attempted"] >= 4
     assert line["device"]["platform"] == "cpu"
     assert line["device"]["count"] == cell["chips"]
@@ -115,6 +138,7 @@ def test_benchmark_json_resolves_to_files():
 
 def test_the_cells_and_metrics_of_pr_35():
     cells = {w["name"]: w for w in REAL["workloads"]}
+    scored = cells.pop("glm_4_7_flash_score_s8k_b4")  # PR 36: the next test
     assert len(cells) == 7
     assert [w["name"] for w in cells.values() if w["chips"] == 4] \
         == ["resnet50_train_dp4"]
@@ -129,10 +153,12 @@ def test_the_cells_and_metrics_of_pr_35():
               "mx_moe_ms": lang, "scope_rest_ms": lang}
     for name, where in scopes.items():
         m = layer[name]
-        assert (m["workloads"], m["unit"], m["better"], m["source"]) \
+        listed = [w for w in m["workloads"] if w != scored["name"]]
+        assert (listed, m["unit"], m["better"], m["source"]) \
             == (where, "ms", "lower", "device_trace"), name
     for kernel in ("fwd", "dq", "dkv"):
-        assert layer[f"mx_attention_{kernel}_roofline"]["workloads"] == lang
+        assert [w for w in layer[f"mx_attention_{kernel}_roofline"][
+            "workloads"] if w != scored["name"]] == lang
     tail = next(m for m in REAL["end_to_end"] if m["name"] == "step_ms_p95")
     # the device-paced cells; not the one the host paces, nor a language cell
     assert tail["workloads"] == [
@@ -142,3 +168,38 @@ def test_the_cells_and_metrics_of_pr_35():
     # programs it launched, and a metric with no list must read everywhere
     assert "resnet50_train_fitloop" not in layer[
         "dispatches_per_step"]["workloads"]
+
+
+def test_the_cell_of_pr_36():
+    """One cell more, forward only, on lists that existed: no configuration,
+    no end-to-end metric and no bound is new."""
+    cells = {w["name"]: w for w in REAL["workloads"]}
+    assert len(cells) == 8
+    assert [w["name"] for w in cells.values() if w["chips"] == 4] \
+        == ["resnet50_train_dp4"]
+    scored = cells["glm_4_7_flash_score_s8k_b4"]
+    assert (scored["config"], scored["traffic"], scored["chips"]) \
+        == ("glm_4_7_flash", "score_lm_s8192_b4", 1)
+    assert len(scored["why"]) <= 200
+    traffic = json.loads((HERE.parent / "traffic"
+                          / "score_lm_s8192_b4.json").read_text())
+    assert traffic["trains"] is False and traffic["path"] == "score_lm"
+    assert set(traffic["limits"]) == {
+        "logits_gap.head0", "logits_gap.head1", "sequence_loss_gap"}
+    # every other traffic file trains, and says so by saying nothing
+    assert all("trains" not in json.loads(f.read_text())
+               for f in (HERE.parent / "traffic").glob("*.json")
+               if f.stem != "score_lm_s8192_b4")
+    listing = {m["name"] for m in REAL["per_layer"]
+               if scored["name"] in m.get("workloads", [scored["name"]])}
+    assert listing == {
+        "host_dispatch_ms", "programs_per_step", "loop_fusion_share",
+        "device_idle", "mfu", "mx_attention_fwd_roofline", "mx_mla_ms",
+        "mx_moe_ms", "scope_rest_ms"}
+    assert {m["name"]: m["bound"] for m in REAL["end_to_end"]} == {
+        "samples_per_s": 0.025, "step_ms_p95": 0.01, "setup_s": 0.1}
+    tail = next(m for m in REAL["end_to_end"] if m["name"] == "step_ms_p95")
+    assert scored["name"] not in tail["workloads"]
+    assert [c["name"] for c in REAL["configs"]] == [
+        "resnet50_v1", "inception_v3", "glm_4_7_flash",
+        "nemotron_3_nano_30b_a3b"]
