@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 from .registry import register
 
@@ -122,26 +123,92 @@ def _flash_attention_op(q, k, v, causal=False, scale=None):
 # ---------------------------------------------------------------------------
 # Blocked attention: both passes tiled over queries AND keys, so no
 # (T, T) score matrix exists for a head in either direction and VMEM holds
-# one (block, block) tile of logits whatever T is. Causal key blocks above
-# the diagonal are skipped (no compute, and the index map re-names the block
-# already held, so no copy either). The backward is two kernels: dK/dV walk
-# the query blocks for one key block (logits transposed, so the saved
-# log-sum-exp broadcasts as a row), dQ walks the key blocks for one query
-# block. Query/key head size and value head size may differ (MLA: 256/256,
-# with the 64 rope dimensions shared by all heads already concatenated).
+# a few tiles of logits whatever T is. A grid step owns one block of queries
+# (of keys in dK/dV) and a STRETCH of the other operand, several tiles
+# fetched by one BlockSpec; the kernel body walks the stretch's tiles
+# itself, as straight-line code with its running state (the online
+# softmax's m, l and accumulator; the gradients' accumulators) in values,
+# read from scratch and written back once a grid step. The products of tile
+# j+1 that need nothing of tile j are written into the program BEFORE tile
+# j's softmax: the matrix unit takes its work in program order, so that is
+# what lets it run them while the vector unit is busy. (The forward walks
+# four tiles a step so; the backward kernels are written the same way and
+# handed one tile a step: ``_attention_walk``.) Causal: the stretch
+# that holds the diagonal runs the tiles at or below it (one variant of the
+# body for each place the diagonal can take in a stretch) and masks the one
+# on it; stretches wholly above the diagonal do nothing, and the index map
+# re-names the stretch already held, so they copy nothing either. The
+# backward is two kernels: dK/dV walk the query tiles for one key block
+# (logits transposed, so the saved log-sum-exp broadcasts as a row), dQ
+# walks the key tiles for one query block. Query/key head size and value
+# head size may differ (MLA: 256/256, with the 64 rope dimensions shared by
+# all heads already concatenated).
 # ---------------------------------------------------------------------------
 
 _MASKED = -0.7 * 3.0e38  # not -inf: exp(-inf - -inf) is NaN
 
+# What one stretch of the walked operands (keys and values, or queries and
+# output gradients) may hold in VMEM, both buffers of the pipeline counted;
+# with the owned blocks, the scratch and a few tiles of float32 logits the
+# kernel stays under the 16 MB the compiler gives it.
+_ATTENTION_STRETCH_VMEM = 4 * 1024 * 1024
+_ATTENTION_STRETCH_TILES = 4  # variants of the body grow with its square
 
-def _attention_block(t: int) -> int:
-    """Rows of a query or key block: the largest of 512/256/128 that
-    divides T (512x512 float32 logits are 1 MB of VMEM); a T they do not
-    divide is one block (the small shapes of the tests)."""
-    for b in (512, 256, 128):
-        if t % b == 0:
-            return b
-    return t
+
+class _Walk(NamedTuple):
+    """Rows of the block a grid step owns, which is also a tile of the
+    walked operand (a tile of logits is ``block`` x ``block``), and of the
+    stretch of tiles a grid step is handed (a multiple of ``block`` that
+    divides T)."""
+    block: int
+    stretch: int
+
+    def diagonal(self, i):
+        """(stretch that holds own block ``i``'s diagonal tile, its place
+        in it); ints or traced ints."""
+        places = self.stretch // self.block
+        s = i // places
+        return s, i - s * places
+
+    def after_diagonal(self, i, s, keys_own: bool):
+        """How far stretch ``s`` lies on the masked side of own block
+        ``i``'s diagonal: 0 holds it, below 0 is wholly live, above 0
+        wholly masked (a dead step). Queries own their block unless
+        ``keys_own`` (dK/dV), where later stretches are the live ones."""
+        s_d, _ = self.diagonal(i)
+        return s_d - s if keys_own else s - s_d
+
+    def visits(self, place, keys_own: bool):
+        """The tiles of its stretch a grid step visits, in order, as (place
+        in the stretch, on the diagonal). ``place``: the diagonal tile's,
+        None for a stretch with no diagonal (all of its tiles are live)."""
+        places = self.stretch // self.block
+        if place is None:
+            return tuple((j, False) for j in range(places))
+        if keys_own:
+            return ((place, True),) + tuple(
+                (j, False) for j in range(place + 1, places))
+        return tuple((j, False) for j in range(place)) + ((place, True),)
+
+
+def _attention_walk(t: int, dk: int, dv: int, itemsize: int,
+                    backward: bool = False) -> _Walk:
+    """Block and stretch from the shapes alone. A block is the largest of
+    512/256/128 rows that divides T (512x512 float32 logits are 1 MB of
+    VMEM); a T they do not divide is one block (the small shapes of the
+    tests). The forward's stretch is the most tiles, up to
+    ``_ATTENTION_STRETCH_TILES`` and ``_ATTENTION_STRETCH_VMEM``, that
+    divide T's tiles. The ``backward`` kernels keep one tile a grid step:
+    a tile of theirs is three and four products long, and a training step
+    that held the stretch's variants of both took 10 s longer to load from
+    the compile cache (2 s with stretches of two tiles) for 6 - 12% less
+    of their time (``PERF.md``, section 6, PR 37)."""
+    block = next((b for b in (512, 256, 128) if t % b == 0), t)
+    most = 1 if backward else min(
+        _ATTENTION_STRETCH_TILES,
+        max(1, _ATTENTION_STRETCH_VMEM // (2 * block * (dk + dv) * itemsize)))
+    tiles = max(n for n in range(1, most + 1) if (t // block) % n == 0)
+    return _Walk(block, tiles * block)
 
 
 @functools.lru_cache(maxsize=None)
@@ -150,23 +217,35 @@ def _build_blocked_attention(t: int, dk: int, dv: int, causal: bool,
     """(fwd, bwd) over (BH, T, dk) queries and keys and (BH, T, dv)
     values. ``fwd(q, k, v) -> (o, lse)`` with ``lse`` (BH, T) float32;
     ``bwd(q, k, v, o, lse, do) -> (dq, dk, dv)``."""
+    import jax.numpy as jnp
+    itemsize = jnp.dtype(dtype).itemsize
+    fwd, _ = _attention_passes(t, dk, dv, causal, scale, interpret,
+                               _attention_walk(t, dk, dv, itemsize))
+    _, bwd = _attention_passes(t, dk, dv, causal, scale, interpret,
+                               _attention_walk(t, dk, dv, itemsize, True))
+    return fwd, bwd
+
+
+def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
+    """``_build_blocked_attention``'s (fwd, bwd) with both passes on one
+    walk."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    blk = _attention_block(t)
-    n = t // blk
+    blk = walk.block
+    n, n_s = t // blk, t // walk.stretch
     f32 = jnp.float32
     nt = (((1,), (1,)), ((), ()))  # a @ b.T
 
     def dot(a, b, dims=(((1,), (0,)), ((), ()))):
         return jax.lax.dot_general(a, b, dims, preferred_element_type=f32)
 
-    def logits(a, b, transposed, on_diagonal):
-        """a @ b.T * scale, (blk, blk); on the diagonal block the entries
-        whose key comes after their query are masked. ``transposed``: rows
-        are keys."""
+    def logits(a, b, on_diagonal, transposed):
+        """a @ b.T * scale, (blk, blk); on the diagonal tile the
+        entries whose key comes after their query are masked.
+        ``transposed``: rows are keys."""
         s = dot(a, b, nt) * scale
         if on_diagonal:
             r = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
@@ -174,75 +253,105 @@ def _build_blocked_attention(t: int, dk: int, dv: int, causal: bool,
             s = jnp.where(r <= c if transposed else r >= c, s, _MASKED)
         return s
 
-    def on_blocks(q_blk, k_blk, body):
-        """Run ``body(on_diagonal)`` where the (query, key) block pair has
-        anything unmasked."""
+    def one_ahead(visits, products):
+        """(rows of the tile, products(rows, on_diagonal)) for each visit,
+        the products of visit j+1 written into the program before what the
+        caller does with those of visit j (see the comment above)."""
+        def tile(j, on_diagonal):
+            rows = slice(j * blk, (j + 1) * blk)
+            return rows, products(rows, on_diagonal)
+        ahead = tile(*visits[0])
+        for visit in visits[1:] + (None,):
+            here, ahead = ahead, visit and tile(*visit)
+            yield here
+
+    def on_stretch(i, s, body, keys_own=False):
+        """Run ``body(visits)`` where own block ``i`` and stretch ``s`` have
+        anything unmasked: one straight-line variant for a stretch wholly
+        live and one for each place of the diagonal."""
         if not causal:
-            body(False)
+            body(walk.visits(None, keys_own))
             return
-        pl.when(k_blk < q_blk)(lambda: body(False))
-        pl.when(k_blk == q_blk)(lambda: body(True))
+        pl.when(walk.after_diagonal(i, s, keys_own) < 0)(
+            lambda: body(walk.visits(None, keys_own)))
+        s_d, place = walk.diagonal(i)
+        for at in range(walk.stretch // blk):
+            pl.when(jnp.logical_and(s == s_d, place == at))(
+                lambda at=at: body(walk.visits(at, keys_own)))
 
-    # blocks named by (head, own block, walked block); the walked index is
-    # clamped to the causal range so a skipped step copies nothing
+    # blocks named by (head, own block, stretch); the stretch is clamped to
+    # the causal range so a dead step copies nothing
     def own(width):
-        return pl.BlockSpec((1, blk, width), lambda b, i, j: (b, i, 0))
+        return pl.BlockSpec((1, blk, width), lambda b, i, s: (b, i, 0))
 
-    def walked_keys(width):
+    def walked(shape, index, keys_own=False):
+        clamp = jnp.maximum if keys_own else jnp.minimum
         return pl.BlockSpec(
-            (1, blk, width),
-            (lambda b, i, j: (b, jnp.minimum(i, j), 0)) if causal
-            else (lambda b, i, j: (b, j, 0)))
+            shape, (lambda b, i, s: index(b, clamp(s, walk.diagonal(i)[0])))
+            if causal else (lambda b, i, s: index(b, s)))
 
-    def walked_queries(shape, index):
-        clamp = (lambda i, j: jnp.maximum(i, j)) if causal \
-            else (lambda i, j: j)
-        return pl.BlockSpec(shape, lambda b, i, j: index(b, clamp(i, j)))
+    def walked_rows(width, keys_own=False):
+        return walked((1, walk.stretch, width), lambda b, s: (b, s, 0),
+                      keys_own)
 
-    column = pl.BlockSpec((1, blk, 1), lambda b, i, j: (b, i, 0))
+    column = pl.BlockSpec((1, blk, 1), lambda b, i, s: (b, i, 0))
     params = None if interpret else pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     kw = dict(interpret=interpret, compiler_params=params) \
         if params is not None else dict(interpret=interpret)
 
     # ---- forward ---------------------------------------------------------
-    def fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s):
-        qi, ki = pl.program_id(1), pl.program_id(2)
+    # m, l and alpha a row are kept across a vreg's 128 lanes where the
+    # widths allow: a row maximum comes out of its reduction that way, and
+    # widening a (blk, 1) column to the logits or to the accumulator is a
+    # lane permute a row group a tile, which at head size 128 cost more
+    # than the tile's products
+    lanes = 128 if blk % 128 == 0 and dv % 128 == 0 else 1
 
-        @pl.when(ki == 0)
+    def across(a, width):
+        """(blk, lanes) against (blk, width) operands."""
+        return jnp.tile(a, (1, width // lanes)) if lanes > 1 else a
+
+    def fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s):
+        qi, si = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(si == 0)
         def _():
             m_s[...] = jnp.full_like(m_s, -jnp.inf)
             l_s[...] = jnp.zeros_like(l_s)
             acc_s[...] = jnp.zeros_like(acc_s)
 
-        def update(on_diagonal):
-            s = logits(q_ref[0], k_ref[0], False, on_diagonal)
-            m_prev = m_s[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
-            acc_s[...] = alpha * acc_s[...] + dot(p.astype(v_ref.dtype),
-                                                  v_ref[0])
-            m_s[...] = m_new
+        def update(visits):
+            q = q_ref[0]
+            m, l, acc = m_s[...], l_s[...], acc_s[...]
+            for rows, s in one_ahead(visits, lambda rows, on_diagonal: logits(
+                    q, k_ref[0, rows], on_diagonal, False)):
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - across(m_new, blk))
+                l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+                acc = across(alpha, dv) * acc + dot(p.astype(v_ref.dtype),
+                                                    v_ref[0, rows])
+                m = m_new
+            m_s[...], l_s[...], acc_s[...] = m, l, acc
 
-        on_blocks(qi, ki, update)
+        on_stretch(qi, si, update)
 
-        @pl.when(ki == (qi if causal else n - 1))
+        @pl.when(si == (walk.diagonal(qi)[0] if causal else n_s - 1))
         def _():
-            o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
-            lse_ref[0] = m_s[...] + jnp.log(l_s[...])
+            o_ref[0] = (acc_s[...] / across(l_s[...], dv)).astype(o_ref.dtype)
+            lse_ref[0] = (m_s[...] + jnp.log(l_s[...]))[:, :1]
 
     def fwd(q, k, v):
         bh = q.shape[0]
         o, lse = pl.pallas_call(
-            fwd_kernel, grid=(bh, n, n),
-            in_specs=[own(dk), walked_keys(dk), walked_keys(dv)],
+            fwd_kernel, grid=(bh, n, n_s),
+            in_specs=[own(dk), walked_rows(dk), walked_rows(dv)],
             out_specs=[own(dv), column],
             out_shape=[jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
                        jax.ShapeDtypeStruct((bh, t, 1), f32)],
-            scratch_shapes=[pltpu.VMEM((blk, 1), f32),
-                            pltpu.VMEM((blk, 1), f32),
+            scratch_shapes=[pltpu.VMEM((blk, lanes), f32),
+                            pltpu.VMEM((blk, lanes), f32),
                             pltpu.VMEM((blk, dv), f32)],
             name="mx_attention_fwd", **kw)(q, k, v)
         return o, lse[..., 0]
@@ -250,46 +359,62 @@ def _build_blocked_attention(t: int, dk: int, dv: int, causal: bool,
     # ---- backward: dQ ----------------------------------------------------
     def dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                   acc_s):
-        qi, ki = pl.program_id(1), pl.program_id(2)
+        qi, si = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(ki == 0)
+        @pl.when(si == 0)
         def _():
             acc_s[...] = jnp.zeros_like(acc_s)
 
-        def update(on_diagonal):
-            s = logits(q_ref[0], k_ref[0], False, on_diagonal)
-            p = jnp.exp(s - lse_ref[0])
-            dp = dot(do_ref[0], v_ref[0], nt)
-            ds = p * (dp - delta_ref[0]) * scale
-            acc_s[...] += dot(ds.astype(k_ref.dtype), k_ref[0])
+        def update(visits):
+            q, do = q_ref[0], do_ref[0]
+            lse, delta = lse_ref[0], delta_ref[0]
+            acc = acc_s[...]
 
-        on_blocks(qi, ki, update)
+            def products(rows, on_diagonal):
+                return (logits(q, k_ref[0, rows], on_diagonal, False),
+                        dot(do, v_ref[0, rows], nt))
 
-        @pl.when(ki == (qi if causal else n - 1))
+            for rows, (s, dp) in one_ahead(visits, products):
+                k = k_ref[0, rows]
+                ds = jnp.exp(s - lse) * (dp - delta) * scale
+                acc = acc + dot(ds.astype(k.dtype), k)
+            acc_s[...] = acc
+
+        on_stretch(qi, si, update)
+
+        @pl.when(si == (walk.diagonal(qi)[0] if causal else n_s - 1))
         def _():
             dq_ref[0] = acc_s[...].astype(dq_ref.dtype)
 
     # ---- backward: dK, dV (rows are keys) ---------------------------------
     def dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                    dv_ref, dk_s, dv_s):
-        ki, qi = pl.program_id(1), pl.program_id(2)
+        ki, si = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(qi == (ki if causal else 0))
+        @pl.when(si == (walk.diagonal(ki)[0] if causal else 0))
         def _():
             dk_s[...] = jnp.zeros_like(dk_s)
             dv_s[...] = jnp.zeros_like(dv_s)
 
-        def update(on_diagonal):
-            s = logits(k_ref[0], q_ref[0], True, on_diagonal)
-            p = jnp.exp(s - lse_ref[0])                  # lse: a (1, blk) row
-            dv_s[...] += dot(p.astype(do_ref.dtype), do_ref[0])
-            dp = dot(v_ref[0], do_ref[0], nt)
-            ds = p * (dp - delta_ref[0]) * scale
-            dk_s[...] += dot(ds.astype(q_ref.dtype), q_ref[0])
+        def update(visits):
+            k, v = k_ref[0], v_ref[0]
+            dk_acc, dv_acc = dk_s[...], dv_s[...]
 
-        on_blocks(qi, ki, update)
+            def products(rows, on_diagonal):
+                return (logits(k, q_ref[0, rows], on_diagonal, True),
+                        dot(v, do_ref[0, rows], nt))
 
-        @pl.when(qi == n - 1)
+            for rows, (s, dp) in one_ahead(visits, products):
+                q, do = q_ref[0, rows], do_ref[0, rows]
+                p = jnp.exp(s - lse_ref[0, :, rows])  # lse, delta: (1, blk)
+                dv_acc = dv_acc + dot(p.astype(do.dtype), do)
+                ds = p * (dp - delta_ref[0, :, rows]) * scale
+                dk_acc = dk_acc + dot(ds.astype(q.dtype), q)
+            dk_s[...], dv_s[...] = dk_acc, dv_acc
+
+        on_stretch(ki, si, update, keys_own=True)
+
+        @pl.when(si == n_s - 1)
         def _():
             dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
@@ -298,21 +423,19 @@ def _build_blocked_attention(t: int, dk: int, dv: int, causal: bool,
         bh = q.shape[0]
         delta = jnp.sum(o.astype(f32) * do.astype(f32), axis=-1)   # (BH, T)
         dq = pl.pallas_call(
-            dq_kernel, grid=(bh, n, n),
-            in_specs=[own(dk), walked_keys(dk), walked_keys(dv), own(dv),
+            dq_kernel, grid=(bh, n, n_s),
+            in_specs=[own(dk), walked_rows(dk), walked_rows(dv), own(dv),
                       column, column],
             out_specs=own(dk),
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             scratch_shapes=[pltpu.VMEM((blk, dk), f32)],
             name="mx_attention_dq", **kw)(
                 q, k, v, do, lse[..., None], delta[..., None])
-        row = walked_queries((1, 1, blk), lambda b, j: (b, 0, j))
+        row = walked((1, 1, walk.stretch), lambda b, s: (b, 0, s), True)
         dk_, dv_ = pl.pallas_call(
-            dkv_kernel, grid=(bh, n, n),
-            in_specs=[walked_queries((1, blk, dk), lambda b, j: (b, j, 0)),
-                      own(dk), own(dv),
-                      walked_queries((1, blk, dv), lambda b, j: (b, j, 0)),
-                      row, row],
+            dkv_kernel, grid=(bh, n, n_s),
+            in_specs=[walked_rows(dk, True), own(dk), own(dv),
+                      walked_rows(dv, True), row, row],
             out_specs=[own(dk), own(dv)],
             out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                        jax.ShapeDtypeStruct(v.shape, v.dtype)],

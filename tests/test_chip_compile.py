@@ -127,6 +127,9 @@ def test_flash_attention_compiles_for_v5e(one_chip, bh, t, d, dtype, causal):
 
 @pytest.mark.parametrize("bh,t,dk,dv,dtype,causal", [
     (20, 8192, 256, 256, "bfloat16", True),    # GLM-4.7-Flash's MLA at 8k
+    (80, 8192, 256, 256, "bfloat16", True),    # the same, scoring 4 sequences
+    (32, 8192, 128, 128, "bfloat16", True),    # Nemotron-3-Nano's attention
+    (4, 32768, 256, 256, "bfloat16", True),    # the stretch does not grow
     (20, 2048, 256, 256, "bfloat16", True),
     (8, 1024, 128, 128, "bfloat16", False),
     (4, 4096, 192, 128, "float32", True),
@@ -134,7 +137,9 @@ def test_flash_attention_compiles_for_v5e(one_chip, bh, t, d, dtype, causal):
 def test_blocked_attention_compiles_for_v5e(one_chip, bh, t, dk, dv, dtype,
                                             causal):
     """The three kernels of ``blocked_attention`` (forward, dQ, dK/dV) at
-    the benchmark's widths: 512x512 tiles at head size 256 fit VMEM."""
+    the benchmark's widths: the forward's stretch of four 512-row tiles of
+    keys and values at head size 256, both buffers, and the 512x512 float32
+    logits of the tiles in flight fit VMEM."""
     from mxnet_tpu.ops import pallas_kernels as pk
     fwd, bwd = pk._build_blocked_attention(t, dk, dv, causal, dk ** -0.5,
                                            dtype, False)

@@ -26,7 +26,7 @@ from mxnet_tpu import autograd, nd
 from mxnet_tpu.gluon.model_zoo import get_model
 from mxnet_tpu.gluon.model_zoo.text import CONFIG_KEYS, LMLoss
 from mxnet_tpu.ndarray.ndarray import from_jax
-from mxnet_tpu.ops.pallas_kernels import blocked_attention
+from mxnet_tpu.ops.pallas_kernels import _attention_walk, blocked_attention
 from mxnet_tpu.parallel import SPMDTrainer, moe
 from mxnet_tpu.parallel.ring_attention import attention
 
@@ -415,9 +415,76 @@ def _plain_attention(q, k, v, causal):
                      scale=q.shape[-1] ** -0.5)[0].transpose(1, 0, 2)
 
 
+@pytest.mark.parametrize("backward,keys_own", [
+    (False, False), (True, False), (True, True)])  # fwd, dq, dkv
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("t", [128, 256, 384, 640, 1024, 1152, 1536, 2048,
+                               3584, 4096, 8192, 16384, 32768])
+def test_attention_walk_visits_every_live_tile_once(t, causal, backward,
+                                                    keys_own):
+    """The walk the three kernels share, with no kernel run: over all grid
+    steps every (query tile, key tile) pair at or below the diagonal is
+    visited exactly once and none above it, exactly the diagonal tiles are
+    masked, a dead step is one whose stretch lies wholly above the
+    diagonal, and at T = 8,192 a head of the forward takes 64 grid steps,
+    24 of them dead (256 and 120 with one grid step a tile, which the
+    backward kernels keep)."""
+    walk = _attention_walk(t, 256, 256, 2, backward)
+    blk, places = walk.block, walk.stretch // walk.block
+    assert t % walk.stretch == 0 and walk.stretch % blk == 0 and places <= 4
+    n, n_s = t // blk, t // walk.stretch
+    seen, dead = {}, 0
+    for i in range(n):
+        for s in range(n_s):
+            place = None
+            if causal:
+                after = walk.after_diagonal(i, s, keys_own)
+                if after > 0:
+                    dead += 1
+                    continue
+                if after == 0:
+                    assert walk.diagonal(i)[0] == s
+                    place = walk.diagonal(i)[1]
+            for j, on_diagonal in walk.visits(place, keys_own):
+                assert 0 <= j < places
+                other = s * places + j
+                pair = (other, i) if keys_own else (i, other)
+                assert pair not in seen
+                seen[pair] = on_diagonal
+    assert seen == {(q, k): causal and q == k for q in range(n)
+                    for k in range(n) if k <= q or not causal}
+    if causal:
+        assert dead == sum(i // places if keys_own else
+                           n_s - 1 - i // places for i in range(n))
+    if t == 8192:
+        steps, dead_steps = (256, 120) if backward else (64, 24)
+        assert (blk, n * n_s) == (512, steps)
+        assert dead == (dead_steps if causal else 0)
+
+
+def test_attention_walk_fits_its_stretch_to_the_operands():
+    """Two buffers of a stretch of both walked operands stay inside
+    ``_ATTENTION_STRETCH_VMEM`` whatever T is; wide float32 operands get a
+    shorter stretch, a T with a prime count of tiles one tile a step."""
+    for t in (8192, 32768):
+        assert _attention_walk(t, 256, 256, 2) == (512, 2048)
+        assert _attention_walk(t, 128, 128, 2) == (512, 2048)
+    assert _attention_walk(4096, 192, 128, 4) == (512, 1024)
+    assert _attention_walk(3584, 128, 128, 2) == (512, 512)
+    assert _attention_walk(48, 16, 16, 4) == (48, 48)
+    assert _attention_walk(8192, 256, 256, 2, backward=True) == (512, 512)
+
+
 @pytest.mark.parametrize("t,dk,dv,causal", [
-    (256, 256, 256, True),      # MLA's head sizes, two blocks of 128
-    (384, 64, 32, True), (256, 32, 64, False), (48, 16, 16, True)])
+    (256, 256, 256, True),      # MLA's head sizes, one block
+    (384, 64, 32, True), (256, 32, 64, False), (48, 16, 16, True),
+    # three stretches of three tiles of 128: the diagonal at every place of
+    # a stretch, wholly live stretches before (after, in dK/dV) it
+    (1152, 32, 16, True), (1152, 16, 32, False),
+    # a count of tiles (five) that no stretch divides: one tile a step
+    (640, 16, 32, True),
+    # tiles of 512; 128 lanes of running maximum and sum a row (dv = 128)
+    (1536, 64, 128, True), (2048, 128, 64, True)])
 def test_blocked_attention_forward_and_backward(t, dk, dv, causal):
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k = (jax.random.normal(ks[i], (3, t, dk)) for i in (0, 1))
@@ -430,6 +497,21 @@ def test_blocked_attention_forward_and_backward(t, dk, dv, causal):
                     (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_blocked_attention_backward_walks_a_stretch_too(monkeypatch):
+    """dQ and dK/dV are written for the forward's walk and handed one tile a
+    step (``_attention_walk``); handed its stretches of three tiles they
+    give the same gradients."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    walk = pk._attention_walk
+    monkeypatch.setattr(pk, "_attention_walk",
+                        lambda *shape, backward=False: walk(*shape[:4]))
+    pk._build_blocked_attention.cache_clear()
+    try:
+        test_blocked_attention_forward_and_backward(1152, 32, 16, True)
+    finally:
+        pk._build_blocked_attention.cache_clear()
 
 
 def test_blocked_attention_op_and_bfloat16():
