@@ -464,8 +464,10 @@ def _backward_walk(heads, head_grads, retain_graph, variables, sp):
         if hg is not None:
             g = hg._data
         else:
+            # the cast of 1 to the dtype, and its broadcast where the head
+            # has a shape to fill
             g = jnp.ones(h.shape, h._data.dtype)
-            launched += 1
+            launched += 1 + bool(h.shape)
         add_grad(e, g)
         if isinstance(e, _OutputEntry):
             root_nodes.append(e.node)

@@ -27,10 +27,17 @@ from collections import OrderedDict, namedtuple
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from .base import MXNetError, env
+from .random import NEXT_KEY_PROGRAMS
 from .telemetry.tracer import span as _span
 
 __all__ = ["CachedOp", "CacheInfo", "SignatureLRU", "make_scan_forward",
            "scan_forward"]
+
+# ``args`` of the replay's child spans, shared: a span copies what it is
+# given, and a closed site builds nothing
+_PREPARE_ARGS = {"programs": NEXT_KEY_PROGRAMS}
+_ONE_PROGRAM = {"programs": 1}
+_NO_PROGRAMS = {"programs": 0}
 
 CacheInfo = namedtuple("CacheInfo",
                        ["hits", "misses", "evictions", "currsize", "maxsize"])
@@ -955,127 +962,149 @@ class CachedOp:
 
     def _replay(self, flat_in, in_treedef, in_arrays, sp):
         """Run the block's compiled program for these inputs (tracing and
-        compiling it first on a new signature). ``sp`` is the call's
-        span."""
+        compiling it first on a new signature). ``sp`` is the call's span;
+        its three children tile it and own what the call launches:
+        ``mx.cached_op.prepare`` (parameters, the random key, signature and
+        cache lookup), ``.launch`` (the residual set, the jitted call that
+        donates it, letting go of its handles) and ``.finish`` (write-back,
+        wraps, the tape record)."""
         import jax
         from . import autograd, random as _random
         from .ndarray.ndarray import NDArray
-
-        params = self._params()
-        for p in params:
-            if p._data is None:
-                raise MXNetError(f"parameter {p.name} not initialized")
-        training = autograd.is_training()
-        rng_key = _random.next_key()
-        # a recorded call runs another program than the plain forward
-        # that inference and serving keep: it linearises, with respect to
-        # the arguments that carry a tape entry (only those can receive a
-        # gradient), and keeps what the mirror policy in force says
-        record = None
-        if autograd.is_recording():
-            from .util import residual_policy_name
-            record = (residual_policy_name(self.mirror),
-                      tuple(_on_tape(p._data) for p in params),
-                      tuple(_on_tape(x) for x in flat_in))
-
         from .ops.registry import _trace_time_flags
-        mode = "read"
-        self._trace_rw.acquire_read()
-        try:
-            # treedef is read by the pure fn at TRACE time only (traces
-            # hold the write lock); assigning inside the lock — and
-            # re-asserting under write exclusivity below — keeps a
-            # concurrent caller's different input structure (or a
-            # memory_analysis/aot_export re-lower) from being traced
-            # against the wrong treedef
-            self._in_treedef = in_treedef
-            param_arrays = tuple(p._data._data for p in params)
-            key_sig = _Signature(
-                tuple((tuple(a.shape), str(a.dtype)) for a in in_arrays),
-                tuple((tuple(a.shape), str(a.dtype)) for a in param_arrays),
-                in_treedef, training, _trace_time_flags(), record)
-            def _new_entry():
-                # cheap: builds the entry + jit WRAPPER only (no trace/
-                # compile happens until the first execution below; a
-                # recorded entry's wrapper is built there too)
-                e = _CacheEntry()
-                if record is None:
-                    e.jitted = jax.jit(self._make_pure_fn(training, e))
-                return e
 
-            entry = self._cache.get_or_insert(key_sig, _new_entry)
-            sp.set(block=type(self.block).__name__, programs=1,
-                   cache="hit" if entry.warm else "miss")
-            if not entry.warm:
-                # cold entry (ours or a concurrent thread's): the first
-                # execution runs the python trace, which swaps Parameter
-                # storage to Tracers — upgrade to the exclusive lock and
-                # re-read the params after no reader/trace is in flight
-                self._trace_rw.release_read()
-                mode = None
-                self._trace_rw.acquire_write()
-                mode = "write"
-                self._in_treedef = in_treedef  # no clobber possible now
+        mode = None
+        try:
+            with _span("mx.cached_op.prepare", "step", _PREPARE_ARGS):
+                params = self._params()
+                for p in params:
+                    if p._data is None:
+                        raise MXNetError(
+                            f"parameter {p.name} not initialized")
+                training = autograd.is_training()
+                rng_key = _random.next_key()
+                # a recorded call runs another program than the plain
+                # forward that inference and serving keep: it linearises,
+                # with respect to the arguments that carry a tape entry
+                # (only those can receive a gradient), and keeps what the
+                # mirror policy in force says
+                record = None
+                if autograd.is_recording():
+                    from .util import residual_policy_name
+                    record = (residual_policy_name(self.mirror),
+                              tuple(_on_tape(p._data) for p in params),
+                              tuple(_on_tape(x) for x in flat_in))
+
+                self._trace_rw.acquire_read()
+                mode = "read"
+                # treedef is read by the pure fn at TRACE time only (traces
+                # hold the write lock); assigning inside the lock — and
+                # re-asserting under write exclusivity below — keeps a
+                # concurrent caller's different input structure (or a
+                # memory_analysis/aot_export re-lower) from being traced
+                # against the wrong treedef
+                self._in_treedef = in_treedef
                 param_arrays = tuple(p._data._data for p in params)
-                if entry.jitted is None:
-                    self._linearize(entry, training, record, param_arrays,
-                                    rng_key, in_arrays)
-            if record is None:
-                out_arrays, state = entry.jitted(param_arrays, rng_key,
-                                                 *in_arrays)
-            else:
-                lin = entry.linear
-                flat_args = param_arrays + (rng_key,) + tuple(in_arrays)
-                arena, recycled = self._take_arena(entry, flat_args[0])
-                flat_out = entry.jitted(param_arrays, rng_key,
-                                        tuple(in_arrays), arena)
-                n = lin.n_outs + lin.n_state
-                out_arrays, state = flat_out[:lin.n_outs], \
-                    flat_out[lin.n_outs:n]
-                sp.set(residual_bytes=lin.residual_bytes, recycled=recycled)
-            entry.warm = True
+                key_sig = _Signature(
+                    tuple((tuple(a.shape), str(a.dtype)) for a in in_arrays),
+                    tuple((tuple(a.shape), str(a.dtype))
+                          for a in param_arrays),
+                    in_treedef, training, _trace_time_flags(), record)
+
+                def _new_entry():
+                    # cheap: builds the entry + jit WRAPPER only (no trace/
+                    # compile happens until the first execution below; a
+                    # recorded entry's wrapper is built there too)
+                    e = _CacheEntry()
+                    if record is None:
+                        e.jitted = jax.jit(self._make_pure_fn(training, e))
+                    return e
+
+                entry = self._cache.get_or_insert(key_sig, _new_entry)
+                # programs 0: the children own what the call launches
+                sp.set(block=type(self.block).__name__, programs=0,
+                       cache="hit" if entry.warm else "miss")
+                if not entry.warm:
+                    # cold entry (ours or a concurrent thread's): the first
+                    # execution runs the python trace, which swaps Parameter
+                    # storage to Tracers — upgrade to the exclusive lock and
+                    # re-read the params after no reader/trace is in flight
+                    self._trace_rw.release_read()
+                    mode = None
+                    self._trace_rw.acquire_write()
+                    mode = "write"
+                    self._in_treedef = in_treedef  # no clobber possible now
+                    param_arrays = tuple(p._data._data for p in params)
+                    if entry.jitted is None:
+                        self._linearize(entry, training, record,
+                                        param_arrays, rng_key, in_arrays)
+            with _span("mx.cached_op.launch", "step",
+                       _ONE_PROGRAM) as launch:
+                if record is None:
+                    out_arrays, state = entry.jitted(param_arrays, rng_key,
+                                                     *in_arrays)
+                else:
+                    lin = entry.linear
+                    flat_args = param_arrays + (rng_key,) + tuple(in_arrays)
+                    arena, recycled = self._take_arena(entry, flat_args[0])
+                    flat_out = entry.jitted(param_arrays, rng_key,
+                                            tuple(in_arrays), arena)
+                    # the donated set's handles die here, inside the span
+                    # of the call that consumed them, not as the frame goes
+                    del arena
+                    n = lin.n_outs + lin.n_state
+                    out_arrays, state = flat_out[:lin.n_outs], \
+                        flat_out[lin.n_outs:n]
+                    sp.set(residual_bytes=lin.residual_bytes,
+                           recycled=recycled)
+                    if not recycled:  # _take_arena ran its allocation
+                        launch.set(programs=2)
+                entry.warm = True
         finally:
             if mode == "read":
                 self._trace_rw.release_read()
             elif mode == "write":
                 self._trace_rw.release_write()
 
-        # write back mutable state (moving stats) — versioned-var rebind,
-        # exclusive: a concurrent replay must not capture a torn set of
-        # params (only training-mode calls mutate, so serving never pays)
-        if entry.mutated_idx:
-            self._trace_rw.acquire_write()
-            try:
-                for i, s in zip(entry.mutated_idx, state):
-                    params[i]._data._rebind(s)
-            finally:
-                self._trace_rw.release_write()
+        with _span("mx.cached_op.finish", "step", _NO_PROGRAMS):
+            # write back mutable state (moving stats) — versioned-var
+            # rebind, exclusive: a concurrent replay must not capture a torn
+            # set of params (only training-mode calls mutate, so serving
+            # never pays)
+            if entry.mutated_idx:
+                self._trace_rw.acquire_write()
+                try:
+                    for i, s in zip(entry.mutated_idx, state):
+                        params[i]._data._rebind(s)
+                finally:
+                    self._trace_rw.release_write()
 
-        # efficiency plane (MXTPU_EFFICIENCY): one launch of this warm
-        # program into the current step window — a list append; the cost
-        # itself resolves lazily (entry_cost_stats) at step end. One
-        # cached env check when the plane is off.
-        if _eff().enabled():
-            _eff().note_dispatch(
-                ("co_fwd", id(entry)), "cached_op",
-                f"{type(self.block).__name__}:fwd",
-                lambda op=self, k=key_sig, e=entry:
-                op.entry_cost_stats(k, e))
+            # efficiency plane (MXTPU_EFFICIENCY): one launch of this warm
+            # program into the current step window — a list append; the cost
+            # itself resolves lazily (entry_cost_stats) at step end. One
+            # cached env check when the plane is off.
+            if _eff().enabled():
+                _eff().note_dispatch(
+                    ("co_fwd", id(entry)), "cached_op",
+                    f"{type(self.block).__name__}:fwd",
+                    lambda op=self, k=key_sig, e=entry:
+                    op.entry_cost_stats(k, e))
 
-        ctx = flat_in[0]._ctx if flat_in else params[0]._data._ctx
-        out_nds = [NDArray(a, ctx=ctx) for a in out_arrays]
+            ctx = flat_in[0]._ctx if flat_in else params[0]._data._ctx
+            out_nds = [NDArray(a, ctx=ctx) for a in out_arrays]
 
-        if record is not None:
-            closure = jax.tree_util.tree_unflatten(
-                lin.closure_treedef,
-                [flat_out[src] if src >= 0 else flat_args[~src]
-                 for src in lin.res_src])
-            autograd._record_custom(
-                _CachedOpGrad(self, entry, closure, tuple(flat_out[n:])),
-                [p._data for p in params] + list(flat_in), tuple(out_nds))
+            if record is not None:
+                closure = jax.tree_util.tree_unflatten(
+                    lin.closure_treedef,
+                    [flat_out[src] if src >= 0 else flat_args[~src]
+                     for src in lin.res_src])
+                autograd._record_custom(
+                    _CachedOpGrad(self, entry, closure,
+                                  tuple(flat_out[n:])),
+                    [p._data for p in params] + list(flat_in),
+                    tuple(out_nds))
 
-        result = jax.tree_util.tree_unflatten(entry.out_treedef, out_nds)
-        return result
+            return jax.tree_util.tree_unflatten(entry.out_treedef, out_nds)
 
 
 def make_scan_forward(block, training: bool = False):

@@ -45,10 +45,17 @@ from .telemetry import memory as _memory
 from .telemetry import numerics as _numerics
 from .telemetry import run_report as _run_report
 from .telemetry.step_breakdown import StepBreakdown, segment as _segment
+from .telemetry.tracer import span as _span, tracer as _tracer
 
 __all__ = ["FitLoop", "FitResult", "resumable_exit_code"]
 
 _LOG = get_logger("mxnet_tpu.fit")
+
+# ``args`` of the step's spans, shared (a span copies them). None launches
+# an executable itself; the fetch says that it blocks on the device, so a
+# reader does not take its time for host work.
+_NO_PROGRAMS = {"programs": 0}
+_FETCH = {"programs": 0, "blocking": True}
 
 
 def resumable_exit_code() -> int:
@@ -399,8 +406,7 @@ class FitLoop:
         # The handshake is a collective, so this gate must evaluate the
         # same on every rank: at fit start both inputs are env-driven
         # (MXTPU_COLL_*/MXTPU_PROFILE, launcher-forwarded fleet-wide)
-        from .telemetry.tracer import tracer as _tr
-        if _collective.enabled() or _tr.enabled:
+        if _collective.enabled() or _tracer.enabled:
             # the trainer's store is init-lazy (first allreduce); force
             # it now — a string arg ('dist_sync') carries no group size,
             # and skipping the handshake on a real group would report
@@ -429,253 +435,217 @@ class FitLoop:
                 consumed = self._position_iter(epoch, skip_batches)
                 data_it = iter(self._iter)
                 while True:
-                    if bd is not None:
-                        bd.begin_step(result.step)
-                    # efficiency window: opened the way the breakdown
-                    # opens its ledger window — dispatch sites note the
-                    # step's programs, end_step divides their FLOPs by
-                    # wall and peak. One cached env check when off; a
-                    # fast-forwarded replay batch simply re-opens it.
-                    _efficiency.begin_step()
-                    # data_wait: blocked on the input pipeline (staging
-                    # iterators emit nested h2d spans; exclusive-time
-                    # accounting charges each second once)
-                    try:
-                        with _segment("data_wait"):
-                            batch = next(data_it)
-                    except StopIteration:
-                        break
-                    if consumed < skip_batches:
-                        consumed += 1  # fast-forward: replayed, not trained
-                        continue
-                    if plan is not None:
-                        plan.begin_step(result.step)
-                        plan.maybe_kill()  # ChaosKilled propagates (abrupt)
-                        rz = plan.resize_target()
-                        if rz is not None:
-                            # resize@N[:M]: graceful kill with a
-                            # resumable exit — the final checkpoint's
-                            # topology record carries the target world
-                            # for the relaunch harness
-                            self._final_resize(cm, result, epoch,
-                                               consumed, rz["world"])
-                    # numerics sampling clock (one cached flag check off)
-                    _numerics.mark_step(result.step)
-                    if self._preempted is not None:
-                        self._final_exit(cm, result, epoch, consumed)
-                    if tuner is not None:
-                        tuner.on_step_begin(result.step)
-                    x = batch.data[0]
-                    y = batch.label[0] if batch.label else None
-                    from . import autograd
-                    bs = batch_size if batch_size is not None \
-                        else x.shape[0]
-                    import jax
-                    # comm/backward overlap: the scope itself goes
-                    # inactive for a step whose grads the chaos plan
-                    # will poison AFTER backward (clean grads must not
-                    # ship early) — pass OUR chaos clock, the
-                    # trainer's own step() counter never advances
-                    # under FitLoop
-                    ov = overlap_scope(chaos_step=result.step) \
-                        if overlap_scope is not None \
-                        else contextlib.nullcontext()
-                    with _segment("compute"):
-                        with autograd.record():
-                            out = self._net(x)
-                            loss = self._loss_fn(out, y) \
-                                if y is not None else self._loss_fn(out)
-                            scaled = loss * self._loss_scale \
-                                if self._loss_scale != 1.0 else loss
-                        with ov:
-                            scaled.backward()
-                    if plan is not None:
-                        plan.poison_grads(self._trainer._params)
-                    with _segment("comm"):
-                        self._trainer.allreduce_grads()
-                    # fetch the finiteness verdict and the loss in ONE
-                    # device-to-host transfer: the sentinel must not
-                    # add a second blocking sync to every step
-                    with _segment("compute"):
-                        loss_dev = loss.mean()._data
-                    fused_flag = None
-                    if self._skip_nonfinite and \
-                            hasattr(self._trainer,
-                                    "update_with_sentinel"):
-                        # aggregated fast path: the finiteness check is
-                        # ONE fused reduction inside the compiled step
-                        # and the update is where-guarded on device — a
-                        # non-finite step already left params/state
-                        # untouched, only the host counters need
-                        # rolling back
-                        with _segment("optimizer"):
-                            fused_flag = \
-                                self._trainer.update_with_sentinel(
-                                    bs * self._loss_scale,
-                                    ignore_stale_grad=self
-                                    ._ignore_stale_grad)
-                    # the blocking fetch realizes the whole async step
-                    # (forward/backward dominate): charged to compute.
-                    # Sampled numerics stats (MXTPU_NUMERICS) ride the
-                    # SAME transfer — the single-sync contract holds
-                    # with the plane on
-                    nstats = getattr(self._trainer,
-                                     "last_numerics_stats", None)
-                    nvals = None
-                    if fused_flag is not None:
-                        with _segment("compute"):
-                            if nstats:
-                                ok, lval, nvals = jax.device_get(
-                                    (fused_flag, loss_dev,
-                                     [m for _, m in nstats]))
-                            else:
-                                ok, lval = jax.device_get(
-                                    (fused_flag, loss_dev))
-                                # an EMPTY parked list (distributed ZeRO
-                                # rank owning zero params on a sampled
-                                # step) must still reach record_step —
-                                # its stats merge is a collective
-                                nvals = [] if nstats is not None else None
-                        finite, loss_val = bool(ok), float(lval)
-                        if not finite:
-                            self._trainer.rollback_step()
-                    elif self._skip_nonfinite:
-                        # fused path declined: per-param fallback stats
-                        # (one small extra dispatch, still one transfer)
-                        nstats = _numerics.fallback_collect(self._trainer)
-                        with _segment("compute"):
-                            if nstats:
-                                ok, lval, nvals = jax.device_get(
-                                    (self._grads_finite_flag(), loss_dev,
-                                     [m for _, m in nstats]))
-                            else:
-                                ok, lval = jax.device_get(
-                                    (self._grads_finite_flag(), loss_dev))
-                        finite, loss_val = bool(ok), float(lval)
-                    else:
-                        finite = True
-                        nstats = None
-                        with _segment("compute"):
-                            loss_val = float(jax.device_get(loss_dev))
-                    if nvals is not None:
+                    with _span("mx.fit.step", "step",
+                               _NO_PROGRAMS) as root:
+                        if bd is not None:
+                            bd.begin_step(result.step)
+                        # efficiency window: opened the way the breakdown
+                        # opens its ledger window — dispatch sites note the
+                        # step's programs, end_step divides their FLOPs by
+                        # wall and peak. One cached env check when off; a
+                        # fast-forwarded replay batch simply re-opens it.
+                        _efficiency.begin_step()
+                        # data_wait: blocked on the input pipeline (staging
+                        # iterators emit nested h2d spans; exclusive-time
+                        # accounting charges each second once)
                         try:
-                            _numerics.record_step(
-                                result.step,
-                                [(names, v) for (names, _), v
-                                 in zip(nstats, nvals)],
-                                loss_scale=self._loss_scale,
-                                finite=finite, trainer=self._trainer)
-                        except Exception as e:
-                            _LOG.warning("numerics record failed: %s", e)
-                    if not finite:
-                        # sentinel: skip the update entirely — params and
-                        # optimizer state stay at the pre-step values —
-                        # and back off the loss scale
-                        result.skipped_steps.append(result.step)
-                        # provenance BEFORE the grads are zeroed below:
-                        # the plane names the first parameter that went
-                        # non-finite and writes the forensics record —
-                        # the extra syncs land only on this already-lost
-                        # step, never on a clean one
-                        if _numerics.enabled():
-                            try:
-                                _numerics.nonfinite_step(
-                                    result.step, self._trainer,
-                                    loss_scale=self._loss_scale)
-                            except Exception as e:
-                                _LOG.warning(
-                                    "numerics provenance failed: %s", e)
-                        old_scale = self._loss_scale
-                        self._loss_scale = max(
-                            self._loss_scale * self._scale_backoff, 2e-5)
-                        _numerics.note_loss_scale(
-                            result.step, old_scale, self._loss_scale,
-                            "backoff")
-                        good_streak = 0
-                        # zero (not just mark stale) the grad buffers: a
-                        # grad_req='add' buffer would otherwise accumulate
-                        # onto the NaN/Inf bytes next backward and stall
-                        # the sentinel forever
-                        for p in self._trainer._params:
-                            p.zero_grad()
-                        _LOG.warning(
-                            "step %d: non-finite gradients — update "
-                            "skipped, loss scale -> %g",
-                            result.step, self._loss_scale)
-                    else:
-                        if fused_flag is None:  # fused path already updated
+                            with _segment("data_wait"):
+                                batch = next(data_it)
+                        except StopIteration:
+                            root.set(trained=False)
+                            break
+                        if consumed < skip_batches:
+                            # fast-forward: replayed, not trained
+                            consumed += 1
+                            root.set(trained=False)
+                            continue
+                        if plan is not None:
+                            plan.begin_step(result.step)
+                            # ChaosKilled propagates (abrupt)
+                            plan.maybe_kill()
+                            rz = plan.resize_target()
+                            if rz is not None:
+                                # resize@N[:M]: graceful kill with a
+                                # resumable exit — the final checkpoint's
+                                # topology record carries the target world
+                                # for the relaunch harness
+                                self._final_resize(cm, result, epoch,
+                                                   consumed, rz["world"])
+                        # numerics sampling clock (one cached flag check off)
+                        _numerics.mark_step(result.step)
+                        if self._preempted is not None:
+                            self._final_exit(cm, result, epoch, consumed)
+                        if tuner is not None:
+                            tuner.on_step_begin(result.step)
+                        x = batch.data[0]
+                        y = batch.label[0] if batch.label else None
+                        from . import autograd
+                        bs = batch_size if batch_size is not None \
+                            else x.shape[0]
+                        import jax
+                        # comm/backward overlap: the scope itself goes
+                        # inactive for a step whose grads the chaos plan
+                        # will poison AFTER backward (clean grads must not
+                        # ship early) — pass OUR chaos clock, the
+                        # trainer's own step() counter never advances
+                        # under FitLoop
+                        ov = overlap_scope(chaos_step=result.step) \
+                            if overlap_scope is not None \
+                            else contextlib.nullcontext()
+                        with _segment("compute"):
+                            with autograd.record():
+                                out = self._net(x)
+                                loss = self._loss_fn(out, y) \
+                                    if y is not None else self._loss_fn(out)
+                                scaled = loss * self._loss_scale \
+                                    if self._loss_scale != 1.0 else loss
+                            with ov:
+                                scaled.backward()
+                        if plan is not None:
+                            plan.poison_grads(self._trainer._params)
+                        with _segment("comm"):
+                            self._trainer.allreduce_grads()
+                        # fetch the finiteness verdict and the loss in ONE
+                        # device-to-host transfer: the sentinel must not
+                        # add a second blocking sync to every step
+                        with _segment("compute"):
+                            loss_dev = loss.mean()._data
+                        fused_flag = None
+                        if self._skip_nonfinite and \
+                                hasattr(self._trainer,
+                                        "update_with_sentinel"):
+                            # aggregated fast path: the finiteness check is
+                            # ONE fused reduction inside the compiled step
+                            # and the update is where-guarded on device — a
+                            # non-finite step already left params/state
+                            # untouched, only the host counters need
+                            # rolling back
                             with _segment("optimizer"):
-                                self._trainer.update(
-                                    bs * self._loss_scale,
-                                    ignore_stale_grad=self._ignore_stale_grad)
-                            self._record_late_numerics(result.step, finite)
-                        good_streak += 1
-                        if self._scale_growth and \
-                                good_streak % self._scale_growth == 0 and \
-                                self._loss_scale < self._max_scale:
+                                fused_flag = \
+                                    self._trainer.update_with_sentinel(
+                                        bs * self._loss_scale,
+                                        ignore_stale_grad=self
+                                        ._ignore_stale_grad)
+                        # the blocking fetch realizes the whole async step
+                        # (forward/backward dominate): charged to compute.
+                        # Sampled numerics stats (MXTPU_NUMERICS) ride the
+                        # SAME transfer — the single-sync contract holds
+                        # with the plane on
+                        nstats = getattr(self._trainer,
+                                         "last_numerics_stats", None)
+                        nvals = None
+                        if fused_flag is not None:
+                            with _segment("compute"), \
+                                    _span("mx.fit.fetch", "step", _FETCH):
+                                if nstats:
+                                    ok, lval, nvals = jax.device_get(
+                                        (fused_flag, loss_dev,
+                                         [m for _, m in nstats]))
+                                else:
+                                    ok, lval = jax.device_get(
+                                        (fused_flag, loss_dev))
+                                    # an EMPTY parked list (distributed ZeRO
+                                    # rank owning zero params on a sampled
+                                    # step) must still reach record_step —
+                                    # its stats merge is a collective
+                                    nvals = [] if nstats is not None else None
+                            finite, loss_val = bool(ok), float(lval)
+                            if not finite:
+                                self._trainer.rollback_step()
+                        elif self._skip_nonfinite:
+                            # fused path declined: per-param fallback stats
+                            # (one small extra dispatch, still one transfer)
+                            nstats = _numerics.fallback_collect(self._trainer)
+                            with _segment("compute"), \
+                                    _span("mx.fit.fetch", "step", _FETCH):
+                                if nstats:
+                                    ok, lval, nvals = jax.device_get(
+                                        (self._grads_finite_flag(), loss_dev,
+                                         [m for _, m in nstats]))
+                                else:
+                                    ok, lval = jax.device_get(
+                                        (self._grads_finite_flag(), loss_dev))
+                            finite, loss_val = bool(ok), float(lval)
+                        else:
+                            finite = True
+                            nstats = None
+                            with _segment("compute"), \
+                                    _span("mx.fit.fetch", "step", _FETCH):
+                                loss_val = float(jax.device_get(loss_dev))
+                        if nvals is not None:
+                            try:
+                                _numerics.record_step(
+                                    result.step,
+                                    [(names, v) for (names, _), v
+                                     in zip(nstats, nvals)],
+                                    loss_scale=self._loss_scale,
+                                    finite=finite, trainer=self._trainer)
+                            except Exception as e:
+                                _LOG.warning("numerics record failed: %s", e)
+                        if not finite:
+                            # sentinel: skip the update entirely — params and
+                            # optimizer state stay at the pre-step values —
+                            # and back off the loss scale
+                            result.skipped_steps.append(result.step)
+                            if fused_flag is None:
+                                # no trainer call ends this step: the
+                                # per-parameter path skips its update
+                                _tracer.end_step()
+                            # provenance BEFORE the grads are zeroed below:
+                            # the plane names the first parameter that went
+                            # non-finite and writes the forensics record —
+                            # the extra syncs land only on this already-lost
+                            # step, never on a clean one
+                            if _numerics.enabled():
+                                try:
+                                    _numerics.nonfinite_step(
+                                        result.step, self._trainer,
+                                        loss_scale=self._loss_scale)
+                                except Exception as e:
+                                    _LOG.warning(
+                                        "numerics provenance failed: %s", e)
                             old_scale = self._loss_scale
-                            self._loss_scale = min(self._loss_scale * 2.0,
-                                                   self._max_scale)
+                            self._loss_scale = max(
+                                self._loss_scale * self._scale_backoff, 2e-5)
                             _numerics.note_loss_scale(
                                 result.step, old_scale, self._loss_scale,
-                                "growth")
-                    result.losses.append(loss_val)
-                    consumed += 1
-                    result.step += 1
-                    if cm is not None and \
-                            result.step % self._ckpt_every == 0:
-                        with _segment("checkpoint"):
-                            self._save(cm, result.step, epoch, consumed)
-                    if self._on_step_end is not None:
-                        self._on_step_end(result.step - 1, loss_val)
-                    # close the efficiency window (result.step already
-                    # incremented — report the step that RAN). Goodput:
-                    # a sentinel-skipped step moved no model forward, so
-                    # its samples are not useful ones
-                    _efficiency.end_step(
-                        step=result.step - 1, samples=int(bs),
-                        useful=finite,
-                        tokens_per_sample=self._tokens_per_sample)
-                    if bd is not None:
-                        rec = bd.end_step()
-                        if tuner is not None:
-                            # result.step already incremented: report the
-                            # step that RAN (result.step - 1), matching
-                            # on_step_begin, the breakdown record index,
-                            # and the step:N trace marker — locked_at is
-                            # then the last step under probe knobs, and
-                            # locked_at+1 the first fully-locked record
-                            tuner.on_step_end(result.step - 1, rec,
-                                              breakdown=bd)
-                            if tuner.locked and \
-                                    not self._collect_breakdown:
-                                # the breakdown existed only to score the
-                                # probes: the caller's opt-out resumes
-                                # now that the tuner is quiescent
-                                bd.uninstall()
-                                bd = None
-                    # memory pressure: the deterministic mem_pressure
-                    # chaos event and the MXTPU_MEM_BUDGET watermark both
-                    # fire a ranked forensics dump (result.step already
-                    # incremented — report the step that RAN). A dump
-                    # failure (disk full at OOM time) must not take down
-                    # the training step that still works
-                    try:
-                        _memory.check_pressure(step=result.step - 1,
-                                               plan=plan)
-                    except Exception as e:
-                        _LOG.warning("memory pressure check failed: %s", e)
-                    # comm health: every rank runs the SAME cadence (the
-                    # digest exchange is itself a collective); a failed
-                    # check is diagnosed, never fatal to the step loop
-                    if coll_every > 0 and \
-                            result.step % coll_every == 0:
-                        try:
-                            _collective.health_check(
-                                getattr(self._trainer, "_kvstore", None),
-                                breakdown=bd)
-                        except Exception as e:
-                            _LOG.warning("comm health check failed: %s", e)
+                                "backoff")
+                            good_streak = 0
+                            # zero (not just mark stale) the grad buffers: a
+                            # grad_req='add' buffer would otherwise accumulate
+                            # onto the NaN/Inf bytes next backward and stall
+                            # the sentinel forever
+                            for p in self._trainer._params:
+                                p.zero_grad()
+                            _LOG.warning(
+                                "step %d: non-finite gradients — update "
+                                "skipped, loss scale -> %g",
+                                result.step, self._loss_scale)
+                        else:
+                            if fused_flag is None:
+                                # (the fused path already updated)
+                                with _segment("optimizer"):
+                                    self._trainer.update(
+                                        bs * self._loss_scale,
+                                        ignore_stale_grad=self
+                                        ._ignore_stale_grad)
+                                self._record_late_numerics(result.step, finite)
+                            good_streak += 1
+                            if self._scale_growth and \
+                                    good_streak % self._scale_growth == 0 and \
+                                    self._loss_scale < self._max_scale:
+                                old_scale = self._loss_scale
+                                self._loss_scale = min(self._loss_scale * 2.0,
+                                                       self._max_scale)
+                                _numerics.note_loss_scale(
+                                    result.step, old_scale, self._loss_scale,
+                                    "growth")
+                        root.set(finite=finite)
+                        consumed += 1
+                        with _span("mx.fit.close", "step", _NO_PROGRAMS):
+                            bd = self._close_step(
+                                result, loss_val, finite, int(bs), cm,
+                                epoch, consumed, bd, tuner, plan, coll_every)
                 skip_batches = 0
                 result.epoch = epoch + 1
                 pos_epoch, pos_batch = epoch + 1, 0
@@ -747,6 +717,62 @@ class FitLoop:
             except Exception as e:
                 _LOG.warning("run report failed: %s", e)
         return result
+
+    def _close_step(self, result: FitResult, loss_val: float, finite: bool,
+                    samples: int, cm, epoch: int, consumed: int, bd, tuner,
+                    plan, coll_every: int):
+        """What a step owes once its loss is on the host (the span
+        ``mx.fit.close``): the record, the checkpoint that is due, the
+        caller's hook, the efficiency and breakdown windows, the tuner, the
+        memory and comm-health checks. Returns the breakdown that stays
+        installed (None once the tuner no longer needs a probe-only one)."""
+        result.losses.append(loss_val)
+        result.step += 1
+        if cm is not None and result.step % self._ckpt_every == 0:
+            with _segment("checkpoint"):
+                self._save(cm, result.step, epoch, consumed)
+        if self._on_step_end is not None:
+            self._on_step_end(result.step - 1, loss_val)
+        # close the efficiency window (result.step already incremented —
+        # report the step that RAN). Goodput: a sentinel-skipped step moved
+        # no model forward, so its samples are not useful ones
+        _efficiency.end_step(
+            step=result.step - 1, samples=samples, useful=finite,
+            tokens_per_sample=self._tokens_per_sample)
+        if bd is not None:
+            rec = bd.end_step()
+            if tuner is not None:
+                # result.step already incremented: report the step that RAN
+                # (result.step - 1), matching on_step_begin, the breakdown
+                # record index, and the step:N trace marker — locked_at is
+                # then the last step under probe knobs, and locked_at+1 the
+                # first fully-locked record
+                tuner.on_step_end(result.step - 1, rec, breakdown=bd)
+                if tuner.locked and not self._collect_breakdown:
+                    # the breakdown existed only to score the probes: the
+                    # caller's opt-out resumes now that the tuner is
+                    # quiescent
+                    bd.uninstall()
+                    bd = None
+        # memory pressure: the deterministic mem_pressure chaos event and
+        # the MXTPU_MEM_BUDGET watermark both fire a ranked forensics dump
+        # (result.step already incremented — report the step that RAN). A
+        # dump failure (disk full at OOM time) must not take down the
+        # training step that still works
+        try:
+            _memory.check_pressure(step=result.step - 1, plan=plan)
+        except Exception as e:
+            _LOG.warning("memory pressure check failed: %s", e)
+        # comm health: every rank runs the SAME cadence (the digest exchange
+        # is itself a collective); a failed check is diagnosed, never fatal
+        # to the step loop
+        if coll_every > 0 and result.step % coll_every == 0:
+            try:
+                _collective.health_check(
+                    getattr(self._trainer, "_kvstore", None), breakdown=bd)
+            except Exception as e:
+                _LOG.warning("comm health check failed: %s", e)
+        return bd
 
     def _final_resize(self, cm, result: FitResult, epoch: int,
                       consumed: int, to_world: Optional[int]) -> None:

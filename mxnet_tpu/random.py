@@ -30,6 +30,12 @@ _seed_value = None
 # per-invocation-fresh stream.
 _trace_stack = threading.local()
 
+#: executables one eager ``next_key()`` launches: its two ``fold_in``s, each
+#: behind the cast of its Python int to uint32. The span round a caller
+#: (``mx.cached_op.prepare``) owns them by this number; a test holds it to
+#: the launches it counts.
+NEXT_KEY_PROGRAMS = 4
+
 
 def _jr():
     import jax.random as jr
@@ -80,6 +86,7 @@ def next_key():
         _counter += 1
         # distinguished fold so the eager stream cannot collide with a
         # trace-key stream even when a caller pushes the root key itself
+        # (NEXT_KEY_PROGRAMS counts what these two calls launch)
         return _jr().fold_in(_jr().fold_in(_key, 0xEA6E4), _counter)
 
 

@@ -15,7 +15,8 @@ Segments (the canonical set; producers may add their own names):
 ===============  ======================================================
 data_wait        blocked on the input pipeline (iterator next())
 h2d              host->device staging of batch arrays
-compute          forward + backward + device sync of the loss
+compute          forward + backward, and the blocking fetch of loss and
+                 flag (inside it, the span ``mx.fit.fetch``)
 optimizer        parameter update (incl. the fused sentinel reduction)
 comm             gradient allreduce / kvstore push-pull after backward
 comm_overlapped  collectives launched DURING backward by the overlap

@@ -9,9 +9,13 @@ straight serialization (:mod:`.chrome_trace`). ``pid`` is the worker rank
 
 :func:`span` is the one way to write a span. Every span it records carries
 in its ``args`` an ``id``, the ``parent`` id (the span open around it on the
-same thread; absent on a root) and the thread's ``step`` number at entry
-(:func:`end_step` advances it: ``Trainer.step`` and ``SPMDTrainer.step`` do),
-so a reader can group the ring by step and compute self times.
+same thread; absent on a root) and the thread's ``step`` number at entry,
+so a reader can group the ring by step and compute self times. A step ends
+where its outermost span closes: :func:`end_step` called while a span is open
+on the thread advances the number when the outermost open span exits
+(``FitLoop``'s ``mx.fit.step`` round the trainer's update), and at once with
+none open (``Trainer.step`` and ``SPMDTrainer.step`` call it after their span
+has closed; so does every caller while tracing is off).
 
 The tracer follows the device trace: it counts as on while a JAX profiler
 session runs, whoever started it, and every span is then also entered as a
@@ -22,7 +26,8 @@ When the session stops the tracer is what it was before.
 Overhead contract: when tracing is off, :func:`span` costs the on-flag test
 and the profiler's (about 20 ns) and returns a shared no-op context manager:
 no clock reads, no allocation. The test-suite holds this to <1% on a tight
-step loop.
+step loop, and by counting on the step engine's own sites
+(tests/test_step_spans.py).
 
 ``MXTPU_PROFILE`` grammar (comma-separated tokens):
 
@@ -92,11 +97,13 @@ _NOOP = _NoopSpan()
 
 class _Thread(threading.local):
     """Per-thread span state: the ids of the open spans, innermost last,
-    and the step counter."""
+    the step counter, and whether the step ends when the outermost of them
+    closes."""
 
     def __init__(self):
         self.open: List[int] = []
         self.step = 0
+        self.ending = False
 
 
 class _Span:
@@ -133,7 +140,11 @@ class _Span:
         t1 = time.perf_counter()
         if self._annotation is not None:
             self._annotation.__exit__(*a)
-        self._tr._thread.open.pop()
+        thread = self._tr._thread
+        thread.open.pop()
+        if thread.ending and not thread.open:
+            thread.ending = False
+            thread.step += 1
         args = self._args
         args["id"], args["step"] = self._id, self._step
         if self._parent is not None:
@@ -261,9 +272,15 @@ class Tracer:
         return _Span(self, name, category, args, in_session)
 
     def end_step(self) -> None:
-        """Advance this thread's step number: the spans that follow belong
-        to the next step."""
-        self._thread.step += 1
+        """End this thread's step: the spans that follow belong to the
+        next. Inside an open span the number advances when the outermost
+        open span exits, so that a root span round a whole step carries one
+        number with everything in it, whoever called this in its middle."""
+        thread = self._thread
+        if thread.open:
+            thread.ending = True
+        else:
+            thread.step += 1
 
     def record(self, name: str, category: str, t_start: float,
                t_end: float, args: Optional[dict] = None) -> None:

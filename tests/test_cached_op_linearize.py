@@ -402,11 +402,24 @@ def test_spans_say_what_was_kept_and_recomputed():
         for _ in range(2):
             _recorded(net, x)[0].backward()
 
-    fwd = _span_args(two_steps, "mx.cached_op.forward")
-    vjp = _span_args(two_steps, "mx.cached_op.vjp")
+    tracer.clear()
+    tracer.enable()
+    try:
+        two_steps()
+    finally:
+        tracer.disable()
+    fwd, vjp, launch = [
+        [e["args"] for e in tracer.events() if e["name"] == name]
+        for name in ("mx.cached_op.forward", "mx.cached_op.vjp",
+                     "mx.cached_op.launch")]
+    tracer.clear()
     assert [a["recycled"] for a in fwd] == [False, True]
     assert len(vjp) == 2
-    assert all(a["programs"] == 1 for a in fwd + vjp)
+    assert all(a["programs"] == 1 for a in vjp)
+    # the replay's children own its launches: the program, and on the first
+    # step the allocation of the residual set it donates
+    assert all(a["programs"] == 0 for a in fwd)
+    assert [a["programs"] for a in launch] == [2, 1]
     # the two convolution outputs and each BatchNorm's two per-channel
     # sums; nothing after the Dense layer's product needs it
     want = 2 * 4 * 4 * 6 * 6 * 4 + 2 * 2 * 4 * 4
