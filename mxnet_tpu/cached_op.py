@@ -228,9 +228,9 @@ _Signature = namedtuple("_Signature", [
 
 # What a recorded entry's forward program hands to its backward, worked
 # out once from the traced program (CachedOp._linearize):
-#   n_outs, n_state   how the program's flat outputs split: the block's
-#                     outputs, the mutated state, then the residuals the
-#                     program itself wrote (the set CachedOp._arena recycles)
+#   n_outs, n_state   how the program's flat outputs split: the residuals
+#                     the program itself wrote (the set CachedOp._arena
+#                     recycles), then the block's outputs, the mutated state
 #   res_src           one int a leaf of the vjp closure: >= 0 a position in
 #                     the program's outputs, < 0 the ~position of a flat
 #                     argument (params, key, inputs) that is passed through
@@ -238,12 +238,21 @@ _Signature = namedtuple("_Signature", [
 #   n_inputs, diff_pos  how many inputs the node has (params + inputs), and
 #                     the positions among them of what the closure
 #                     differentiates with respect to
-#   arena_avals       abstract values of the recycled set
+#   arena_avals       abstract values of the recycled set, as handed over:
+#                     a buffer the forward's compiler keeps in another
+#                     dimension order than the default is handed over
+#                     transposed to that order
+#   turn_back         one entry a leaf of the closure: None, or the
+#                     permutation that gives a handed-over buffer its own
+#                     shape again (the backward program applies it)
 #   residual_bytes    bytes of that set
+#   relaid, relaid_bytes  how many of its buffers are handed over
+#                     transposed, and their bytes
 #   policy            residual_policy_name the program was built under
 _Linearized = namedtuple("_Linearized", [
     "n_outs", "n_state", "res_src", "closure_treedef", "n_inputs",
-    "diff_pos", "arena_avals", "residual_bytes", "policy"])
+    "diff_pos", "arena_avals", "turn_back", "residual_bytes", "relaid",
+    "relaid_bytes", "policy"])
 
 
 class _CacheEntry:
@@ -344,8 +353,48 @@ def trace_rw_for(block) -> "_RWLock":
     return rw
 
 
-def _apply_closure(closure, cots):
-    return closure(cots)
+def _backward_program(turn_back):
+    """A recorded entry's backward program: the transpose ``closure``
+    applied to ``cots``, once the leaves that were handed over in the
+    forward compiler's dimension order (``_Linearized.turn_back``) have
+    their own shape again. The compiler reads such a leaf where it lies:
+    the transpose of an argument is a change of name, not a pass over it."""
+    import jax
+
+    def _apply_closure(closure, cots):
+        leaves, treedef = jax.tree_util.tree_flatten(closure)
+        closure = jax.tree_util.tree_unflatten(
+            treedef, [a if t is None else a.transpose(t)
+                      for a, t in zip(leaves, turn_back)])
+        return closure(cots)
+
+    return jax.jit(_apply_closure)
+
+
+def _kept_order(aval, fmt) -> Optional[Tuple[int, ...]]:
+    """The dimension order, major to minor, that a compiler left free chose
+    for a buffer of ``aval``, where handing the buffer over transposed to
+    that order says the same thing: the order is not the one its device
+    gives an array of that shape anyway (on a TPU the default depends on
+    the shape: not every default is row-major), and the transposed array's
+    default layout is the chosen one, tiles and all. None otherwise."""
+    from jax.experimental.layout import Layout
+    if fmt.layout is None:  # a backend that reports no layouts
+        return None
+    d = next(iter(fmt.sharding.device_set))
+
+    def default(shape):
+        return Layout.from_pjrt_layout(
+            d.client.get_default_layout(aval.dtype, shape, d))
+
+    order = fmt.layout.major_to_minor
+    if order == default(aval.shape).major_to_minor:
+        return None
+    turned = default(tuple(aval.shape[i] for i in order))
+    if turned.major_to_minor != tuple(range(len(order))) \
+            or turned.tiling != fmt.layout.tiling:
+        return None
+    return order
 
 
 def _on_tape(x) -> bool:
@@ -410,7 +459,7 @@ class _CachedOpGrad:
         if entry.vjp_jitted is None:
             # the program only applies the transpose: tracing it runs none
             # of the block's Python, so it needs no trace lock
-            entry.vjp_jitted = jax.jit(_apply_closure)
+            entry.vjp_jitted = _backward_program(entry.linear.turn_back)
             entry.vjp_abstract = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                 (closure, cotangents))
@@ -463,6 +512,12 @@ class CachedOp:
         # and every later one's) share one set.
         self._arena: List[tuple] = []
         self._arena_lock = threading.Lock()
+        # the last residual set whose layouts were read from a compile,
+        # and the order each buffer is handed over in: the next signature
+        # with the same residuals (again the first step's and every later
+        # one's) takes it as read, keeps sharing the set, and compiles its
+        # forward once
+        self._turned: Tuple[Optional[tuple], tuple] = (None, ())
 
     def cache_info(self) -> CacheInfo:
         """Hit/miss/eviction counters + occupancy of the signature cache
@@ -825,7 +880,8 @@ class CachedOp:
         what it returns: residuals that are the program's own arguments
         (parameters, the batch) or its outputs are taken from there on
         the host and never donated; the rest is the set the arena
-        recycles. Runs under the trace write lock (the trace swaps
+        recycles, each buffer in the dimension order the compiler keeps
+        it in (below). Runs under the trace write lock (the trace swaps
         Parameter storage)."""
         import jax
         from jax.extend.core import Literal, jaxpr_as_fun
@@ -879,10 +935,79 @@ class CachedOp:
                     out_pos[v] = len(emit)
                     emit.append(j)
                 res_src.append(out_pos[v])
-        arena_avals = tuple(
-            jax.ShapeDtypeStruct(jaxpr.outvars[j].aval.shape,
-                                 jaxpr.outvars[j].aval.dtype)
-            for j in emit[n_outs + n_state:])
+        n = n_outs + n_state
+        n_arena = len(emit) - n
+        # the program returns the set first: jax pairs a donated argument
+        # with the first output of its shape and type, and that has to be
+        # the buffer written over it, not an output or a moving statistic
+        # that happens to look like it
+        emit = emit[n:] + emit[:n]
+        res_src = [src if src < 0 else src - n if src >= n else src + n_arena
+                   for src in res_src]
+        run = jaxpr_as_fun(closed)
+
+        def build(turns, **layouts):
+            def program(params, key, ins, arena):
+                # ``arena`` is donated and otherwise unused: XLA writes
+                # this call's residuals over the set a freed graph gave
+                # back
+                del arena
+                out = run(*params, key, *ins)
+                return [out[j] if t is None else out[j].transpose(t)
+                        for j, t in zip(emit, turns)]
+
+            return jax.jit(program, donate_argnums=(3,), keep_unused=True,
+                           **layouts)
+
+        def avals(turns):
+            shapes = [jaxpr.outvars[j].aval for j in emit[:n_arena]]
+            return tuple(jax.ShapeDtypeStruct(
+                a.shape if t is None else tuple(a.shape[i] for i in t),
+                a.dtype) for a, t in zip(shapes, turns))
+
+        # What the program writes for its backward alone need not have the
+        # default layout, which a program's outputs have: where the fusion
+        # that makes a buffer writes another dimension order, the forward
+        # would copy it into the default and the backward re-lay it as it
+        # reads. So the program is compiled once with the set's layouts
+        # left to the compiler, on the donated arguments and the outputs
+        # alike, only to read what it chose; the program that runs hands
+        # over, transposed to the chosen order, each buffer the compiler
+        # kept out of the default, and the backward names it back
+        # (``_backward_program``): in the default layout of the transposed
+        # shape the bytes lie as the fusion writes them, and neither side
+        # copies. Every array keeps a default layout, so nothing else has
+        # to know: the set is matched by its abstract values, and whatever
+        # lowers either program again lowers the same one. (The executable
+        # compiled with free layouts cannot itself be the one that runs:
+        # loaded from the persistent compile cache it returns its results
+        # under the default layout's name.)
+        asis = (None,) * len(emit)
+        own, kept = avals(asis), asis[:n_arena]
+        if self._turned[0] == own:
+            kept = self._turned[1]
+        elif any(len(a.shape) > 1 for a in own):  # something can be turned
+            from jax.experimental.layout import Format, Layout
+            free = Format(Layout.AUTO)
+            chosen = build(
+                asis, in_shardings=(None, None, None, (free,) * n_arena),
+                out_shardings=[free] * n_arena + [None] * n,
+            ).lower(param_arrays, rng_key, tuple(in_arrays),
+                    own).compile().input_formats[0][3]
+            kept = tuple(map(_kept_order, own, chosen))
+            self._turned = own, kept
+        turns = kept + asis[n_arena:]
+        arena_avals = avals(turns)
+        entry.jitted = build(turns)
+
+        def back(src):
+            t = turns[src] if src >= 0 else None
+            return t and tuple(sorted(range(len(t)), key=t.__getitem__))
+
+        def nbytes(some):
+            return sum(a.size * a.dtype.itemsize for a in some)
+
+        relaid = [a for a, t in zip(arena_avals, turns) if t is not None]
         n_params = len(param_arrays)
         entry.linear = _Linearized(
             n_outs, n_state, tuple(res_src),
@@ -890,21 +1015,8 @@ class CachedOp:
             n_params + len(in_arrays),
             tuple([i for i, m in enumerate(param_mask) if m]
                   + [n_params + i for i, m in enumerate(in_mask) if m]),
-            arena_avals,
-            sum(a.size * a.dtype.itemsize for a in arena_avals),
-            policy_name)
-
-        run = jaxpr_as_fun(closed)
-
-        def program(params, key, ins, arena):
-            # ``arena`` is donated and otherwise unused: XLA writes this
-            # call's residuals over the set a freed graph gave back
-            del arena
-            out = run(*params, key, *ins)
-            return [out[j] for j in emit]
-
-        entry.jitted = jax.jit(program, donate_argnums=(3,),
-                               keep_unused=True)
+            arena_avals, tuple(back(src) for src in res_src),
+            nbytes(arena_avals), len(relaid), nbytes(relaid), policy_name)
 
     def _take_arena(self, entry: _CacheEntry, like) -> Tuple[tuple, bool]:
         """A residual set for ``entry``'s forward to donate, and whether
@@ -1052,11 +1164,13 @@ class CachedOp:
                     # the donated set's handles die here, inside the span
                     # of the call that consumed them, not as the frame goes
                     del arena
-                    n = lin.n_outs + lin.n_state
-                    out_arrays, state = flat_out[:lin.n_outs], \
-                        flat_out[lin.n_outs:n]
+                    n = len(lin.arena_avals)  # the set comes first
+                    out_arrays = flat_out[n:n + lin.n_outs]
+                    state = flat_out[n + lin.n_outs:]
                     sp.set(residual_bytes=lin.residual_bytes,
-                           recycled=recycled)
+                           recycled=recycled,
+                           residuals_relaid=lin.relaid,
+                           residuals_relaid_bytes=lin.relaid_bytes)
                     if not recycled:  # _take_arena ran its allocation
                         launch.set(programs=2)
                 entry.warm = True
@@ -1100,7 +1214,7 @@ class CachedOp:
                      for src in lin.res_src])
                 autograd._record_custom(
                     _CachedOpGrad(self, entry, closure,
-                                  tuple(flat_out[n:])),
+                                  tuple(flat_out[:n])),
                     [p._data for p in params] + list(flat_in),
                     tuple(out_nds))
 

@@ -437,3 +437,225 @@ def test_cost_and_memory_analysis_still_resolve():
     assert mem["alias_bytes"] == entry.linear.residual_bytes
     assert op.entry_cost_stats(key_sig, entry)["flops"] > 0
     assert op.entry_vjp_cost_stats(entry)["flops"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the residual set is handed over in the dimension order the compiler keeps
+
+def _formats(tree):
+    import jax
+    return [a.format for a in jax.tree_util.tree_leaves(tree)]
+
+
+def _fresh_formats(avals):
+    import jax.numpy as jnp
+    return [jnp.zeros(a.shape, a.dtype).format for a in avals]
+
+
+def test_the_set_is_handed_over_in_the_order_the_forward_compiler_keeps():
+    """XLA's CPU backend keeps an NCHW convolution's product channels-last,
+    so this net's two products cross to the backward as (N, H, W, C); every
+    end of the hand-over agrees on that: the donated arguments, the
+    residual outputs, the set ``_take_arena`` allocates, the leaves the
+    closure holds and the backward program's inputs, all in the default
+    layout of the turned shape; the backward names them back."""
+    import jax
+    net, x = _hybrid_residual()
+    loss, node = _recorded(net, x)
+    op, entry, lin = node.op, node.entry, node.entry.linear
+    assert sorted(a.shape for a in lin.arena_avals) == \
+        [(4,)] * 4 + [(4, 6, 6, 4)] * 2
+    want = _fresh_formats(lin.arena_avals)
+    (key_sig, _), = op._cache.snapshot_items()
+    fwd = op._lower_signature(key_sig, entry)
+    n = len(lin.arena_avals)
+    assert list(fwd.input_formats[0][3]) == want
+    assert list(fwd.output_formats[:n]) == want
+    assert _formats(node.owned) == want
+    assert [(a.shape, a.dtype) for a in node.owned] == \
+        [(a.shape, a.dtype) for a in lin.arena_avals]
+    fresh, recycled = op._take_arena(entry, x._data)
+    assert not recycled and _formats(fresh) == want
+
+    leaves = jax.tree_util.tree_leaves(node.closure)
+    owned = {id(a) for a in node.owned}
+    assert sum(id(a) in owned for a in leaves) == n  # the set itself
+    assert len(lin.turn_back) == len(leaves)
+    turned = [(a, t) for a, t in zip(leaves, lin.turn_back) if t is not None]
+    assert len(turned) == lin.relaid == 2
+    for a, t in turned:
+        assert id(a) in owned and a.shape == (4, 6, 6, 4)
+        assert a.transpose(t).shape == (4, 4, 6, 6)
+    loss.backward()
+    bwd = entry.vjp_jitted.lower(*entry.vjp_abstract).compile()
+    assert jax.tree_util.tree_leaves(bwd.input_formats[0][0]) == \
+        _formats(leaves)
+
+
+def test_the_next_signature_with_the_same_residuals_compiles_once(
+        monkeypatch):
+    """A cast net's first step runs under another cache key than every
+    later one; the second key finds the first's reading of the layouts on
+    the op, hands its residuals over the same way (so that the two go on
+    sharing one set) and compiles its forward once, not twice."""
+    import jax
+    from jax import stages
+    net, x = _hybrid_residual()
+    net.cast("bfloat16")
+    x = x.astype("bfloat16")
+    compiles = []
+    real = stages.Lowered.compile
+
+    def counted(self, *a, **kw):
+        compiles.append(self)
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(stages.Lowered, "compile", counted)
+    for _ in range(3):
+        _recorded(net, x)[0].backward()
+    op = net._cached_op
+    first, second = [e.linear for _, e in op._cache.snapshot_items()]
+    assert len(compiles) == 1  # the first key's, with the layouts left free
+    assert first.arena_avals == second.arena_avals and first.relaid == 2
+    assert op._turned[1] == tuple(
+        (0, 2, 3, 1) if len(a.shape) == 4 else None
+        for a in first.arena_avals)
+    assert len(op._arena) == 1
+
+
+def test_a_set_in_another_order_is_not_written_over():
+    """A recycled set is matched on its abstract values, and those carry
+    the order each buffer is handed over in: a set an entry laid out
+    otherwise (planted: the same buffers under the products' own shapes)
+    is not donated to this entry's program."""
+    import jax
+    net, x = _hybrid_residual()
+    loss, node = _recorded(net, x)
+    loss.backward()
+    op, entry = node.op, node.entry
+    (avals, kept), = op._arena
+    assert avals == entry.linear.arena_avals
+    planted = tuple(
+        jax.ShapeDtypeStruct((4, 4, 6, 6), a.dtype) if len(a.shape) == 4
+        else a for a in avals)
+    assert planted != avals
+    op._arena[:] = [(planted, kept)]
+    fresh, recycled = op._take_arena(entry, x._data)
+    assert not recycled and fresh is not kept
+    assert op._arena == []  # dropped, as a set of other shapes is
+    assert not any(a.is_deleted() for a in kept)
+    op._arena.append((avals, kept))
+    assert op._take_arena(entry, x._data) == (kept, True)
+
+
+def test_five_trainer_steps_write_over_one_set(recwarn):
+    """The set of step n is the donated argument of step n + 1: recycled
+    from the second step on, consumed by the program (no "donated buffers
+    were not usable"), one set for the loop's life."""
+    mx.random.seed(3)
+    net, (x,) = _residual()
+    net.initialize(mx.init.Xavier())
+    net(x)
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.05, "momentum": 0.9})
+    label = nd.array(_rs(8).randint(0, 3, 4).astype("float32"))
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    sets = []
+
+    def step():
+        with autograd.record():
+            out = net(x)
+            sets.append(out._tape_entry.node.custom.owned)
+            loss = loss_fn(out, label)
+        loss.backward()
+        trainer.step(4)
+
+    def five():
+        for _ in range(5):
+            step()
+
+    fwd = _span_args(five, "mx.cached_op.forward")
+    assert [a["recycled"] for a in fwd] == [False] + [True] * 4
+    for donated in sets[:-1]:
+        assert all(a.is_deleted() for a in donated)
+    assert not any(a.is_deleted() for a in sets[-1])
+    assert len(net._cached_op._arena) == 1
+    assert not [w for w in recwarn.list if "donated" in str(w.message)]
+
+
+@pytest.mark.parametrize("make", ["conv", "dense"])
+def test_the_forward_span_counts_the_residuals_handed_over_turned(make):
+    """``residuals_relaid`` and its bytes are set once from the plan, like
+    ``residual_bytes``: the convolutional net's two products here (XLA's
+    CPU backend keeps them channels-last), none of a net of matrix
+    products."""
+    if make == "conv":
+        net, x = _hybrid_residual()
+    else:
+        net, (x,) = _dense()
+        net.initialize(mx.init.Xavier())
+        net.hybridize()
+
+    def two_steps():
+        for _ in range(2):
+            _recorded(net, x)[0].backward()
+
+    fwd = _span_args(two_steps, "mx.cached_op.forward")
+    count, nbytes = (2, 2 * 4 * 4 * 6 * 6 * 4) if make == "conv" else (0, 0)
+    assert [a["residuals_relaid"] for a in fwd] == [count] * 2
+    assert [a["residuals_relaid_bytes"] for a in fwd] == [nbytes] * 2
+    (_, entry), = net._cached_op._cache.snapshot_items()
+    assert (entry.linear.relaid, entry.linear.relaid_bytes) == (count, nbytes)
+
+
+def test_a_buffer_is_turned_only_where_that_says_what_the_compiler_chose():
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
+    from mxnet_tpu.cached_op import _kept_order
+    a = jnp.zeros((2, 3, 4, 5), jnp.float32)
+    aval = jax.ShapeDtypeStruct(a.shape, a.dtype)
+    tiling = a.format.layout.tiling
+
+    def chose(order, tiling=tiling):
+        return Format(Layout(order, tiling), a.sharding)
+
+    assert _kept_order(aval, a.format) is None          # the default
+    assert _kept_order(aval, Format(None, a.sharding)) is None
+    assert _kept_order(aval, chose((0, 2, 3, 1))) == (0, 2, 3, 1)
+    # tiles that the turned shape's default layout does not have: a
+    # transpose could not say it, so the buffer keeps its own shape
+    assert _kept_order(aval, chose((0, 2, 3, 1), ((2, 5),))) is None
+
+
+def test_arguments_over_several_devices_are_handed_over_the_same_way():
+    """Nothing in the mechanism is one device's: the layouts are read from
+    a compile over the arguments' own sharding, the program that runs is a
+    jit, and its gradients are the one-device call's."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    one, x = _hybrid_residual()
+    _recorded(one, x)[0].backward()
+    want = [p.grad().asnumpy() for p in one.collect_params().values()
+            if p.grad_req != "null"]
+
+    net, x = _hybrid_residual()  # the same seed, the same parameters
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    for p in net.collect_params().values():
+        p._data._rebind(jax.device_put(p._data._data,
+                                       NamedSharding(mesh, P())))
+        if p.grad_req != "null":
+            p._grad._rebind(jax.device_put(p._grad._data,
+                                           NamedSharding(mesh, P())))
+    xs = nd.from_jax(jax.device_put(x._data, NamedSharding(mesh, P("dp"))))
+    for _ in range(2):
+        loss, node = _recorded(net, xs)
+        loss.backward()
+    assert node.op.memory_analysis()
+    got = [p.grad().asnumpy() for p in net.collect_params().values()
+           if p.grad_req != "null"]
+    for a, b in zip(want, got):
+        np.testing.assert_allclose(b, a, rtol=2e-4, atol=2e-5)

@@ -244,6 +244,97 @@ def test_epilogue_rows_counts_tiles_and_temporaries(c, itemsize, n_tiles,
     assert pk._epilogue_rows(24, c, n_tiles, n_f32, False, itemsize) == 24
 
 
+def _relayouts(compiled, shapes):
+    """Shape and minor-to-major order of every ``copy`` and ``transpose``
+    left in the compiled program whose result has one of ``shapes``."""
+    import re
+    def ints(text):
+        return tuple(int(d) for d in text.split(","))
+
+    found = re.findall(
+        r"= \w+\[([\d,]+)\]\{([\d,]+)[^=]* (?:copy|transpose)\(",
+        compiled.as_text())
+    return [(ints(shape), ints(order)) for shape, order in found
+            if ints(shape) in shapes]
+
+
+def test_recorded_bottleneck_hands_its_residuals_over_as_the_chip_keeps_them(
+        one_chip):
+    """ResNet-50's first bottleneck at b256 (then the pooling and a Dense
+    layer, so that its cotangent is made inside the backward as in the
+    net), hybridized and recorded, through ``CachedOp``'s own lowering.
+    The chip's compiler keeps the two ``bf16[256,56,56,256]`` products in
+    ``{3,0,2,1}`` (PR 32's trace: the forward copied each into the default
+    layout, 1.23 ms apiece), so they cross to the backward as
+    ``[56,56,256,256]``: the forward program then holds no copy and no
+    transpose of either shape, every buffer of the set is written over the
+    donated one, and the backward holds none either, where the one that is
+    handed the products under their own shape (every backward before
+    PR 40) re-lays them as it reads."""
+    import re
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import cached_op as co, nd
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.gluon.model_zoo.vision.resnet import BottleneckV1
+    from mxnet_tpu.util import residual_policy_name
+
+    net = nn.HybridSequential()
+    net.add(BottleneckV1(256, 1, downsample=True, in_channels=64,
+                         layout="NHWC"),
+            nn.GlobalAvgPool2D(layout="NHWC"), nn.Dense(10))
+    net.initialize(mx.init.Xavier())
+    net(nd.zeros((1, 56, 56, 64)))  # resolves the deferred shapes
+    net.cast("bfloat16")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    op, entry = co.CachedOp(net), co._CacheEntry()
+    params = tuple(sds(p.shape, jnp.bfloat16) for p in op._params())
+    key = sds((2,), jnp.uint32)
+    x = sds((B, 56, 56, 64), jnp.bfloat16)
+    op._in_treedef = jax.tree_util.tree_structure((0,))
+    record = (residual_policy_name(None),
+              tuple(p.grad_req != "null" for p in op._params()), (False,))
+    op._linearize(entry, True, record, params, key, [x])
+    lin = entry.linear
+    own, turned = (B, 56, 56, 256), (56, 56, B, 256)
+    assert sorted(a.shape for a in lin.arena_avals if len(a.shape) == 4) \
+        == [turned] * 2 + [(B, 56, 56, 64)] * 2
+    assert lin.relaid == 2 and lin.relaid_bytes == 2 * B * 56 * 56 * 256 * 2
+
+    arena = tuple(sds(a.shape, a.dtype) for a in lin.arena_avals)
+    fwd = entry.jitted.lower(params, key, (x,), arena).compile()
+    n = len(arena)
+    assert _relayouts(fwd, {own, turned}) == []
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}",
+                         fwd.as_text().split("\n", 1)[0])
+    first = len(params) + 2  # the set follows params, key and the batch
+    assert sorted((int(o), int(i)) for o, i in aliased) == \
+        [(k, first + k) for k in range(n)]
+
+    # the backward, as CachedOp lowers it, and as it was handed the set
+    # before: every buffer under its own shape
+    def backward(turn_back):
+        flat_out = [sds(o.shape, o.dtype)
+                    for o in jax.tree_util.tree_leaves(fwd.out_info)]
+        flat_args = params + (key, x)
+        leaves = [flat_out[s] if s >= 0 else flat_args[~s]
+                  for s in lin.res_src]
+        if not any(turn_back):
+            leaves = [sds(own, a.dtype) if a.shape == turned else a
+                      for a in leaves]
+        closure = jax.tree_util.tree_unflatten(lin.closure_treedef, leaves)
+        return co._backward_program(turn_back).lower(
+            closure, tuple(flat_out[n:n + lin.n_outs])).compile()
+
+    before = _relayouts(backward((None,) * len(lin.turn_back)),
+                        {own, turned})
+    assert len(before) >= 2 and set(before) == {(own, (3, 0, 2, 1))}
+    assert _relayouts(backward(lin.turn_back), {own, turned}) == []
+
+
 def _describe_step(one_chip):
     """The b256 ResNet-50 NHWC bf16 SPMDTrainer step and its arguments as
     shapes on the described chip."""

@@ -301,7 +301,10 @@ def test_default_policy_keeps_batchnorm_statistics():
     with autograd.pause():
         net(x)
     net.hybridize()
-    assert _owned_shapes(_plan(net, x)) == [(2, 4, 5, 5), (4,), (4,)]
+    # the product in whichever dimension order the compiler keeps it (XLA's
+    # CPU backend channels-last: handed over as (2, 5, 5, 4)), and the sums
+    assert [tuple(sorted(s)) for s in _owned_shapes(_plan(net, x))] == \
+        [(2, 4, 5, 5), (4,), (4,)]
     entry = _recorded_entry(net)
     bwd = entry.vjp_jitted.lower(*entry.vjp_abstract).as_text()
     assert bwd.count("stablehlo.reduce(") == 2   # sum_dy, sum_dy_xhat
