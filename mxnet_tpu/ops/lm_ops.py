@@ -3,7 +3,9 @@ DeepSeek-V2 family: RMSNorm, rotary embedding, multi-head latent attention
 (MLA), the gated FFN and the dropless expert layer. The ``nemotron_h``
 family: the Mamba-2 mixer (causal depthwise convolution, the selective
 state-space recurrence by chunks, a gated grouped RMSNorm), grouped-KV
-attention and the squared-ReLU FFN.
+attention and the squared-ReLU FFN. The ``olmo_hybrid`` family: the Gated
+DeltaNet mixer (the gated delta rule by chunks, per-head L2 norms, a
+per-head norm then gate) and attention with QK-norm.
 
 Pure JAX functions, registered like every other op, so one definition
 serves eager NDArray calls, the autograd tape, hybridized blocks and
@@ -74,19 +76,20 @@ def relu2_ffn(x, w_in, w_out):
 FFN_ACTIVATIONS = {"swiglu": (swiglu, swiglu_ffn), "relu2": (relu2, relu2_ffn)}
 
 
-def causal_conv1d(x, weight, bias):
+def causal_conv1d(x, weight, bias=None):
     """Depthwise causal convolution along T: ``y[t, c] = bias[c] + sum_j
     weight[c, j] x[t - (W - 1) + j, c]`` with zeros before the sequence.
-    x: (B, T, C); weight: (C, W); bias: (C,). W shifted multiply-adds that
-    XLA fuses into one pass (W is 4), in float32."""
+    x: (B, T, C); weight: (C, W); bias: (C,) or None (no bias). W shifted
+    multiply-adds that XLA fuses into one pass (W is 4), in float32."""
     import jax.numpy as jnp
     t, width = x.shape[1], weight.shape[1]
     padded = jnp.pad(x.astype(jnp.float32),
                      ((0, 0), (width - 1, 0), (0, 0)))
     w = weight.astype(jnp.float32)
-    y = bias.astype(jnp.float32)
+    y = None if bias is None else bias.astype(jnp.float32)
     for j in range(width):
-        y = y + padded[:, j:j + t] * w[:, j]
+        term = padded[:, j:j + t] * w[:, j]
+        y = term if y is None else y + term
     return y.astype(x.dtype)
 
 
@@ -214,6 +217,153 @@ def mamba2_mixer(x, w_in, conv_weight, conv_bias, dt_bias, a_log, d, norm,
         return jnp.dot(y, w_out)
 
 
+def gated_delta_rule_chunked(q, k, v, g, beta, chunk=64, decay_dtype=None):
+    """The gated delta rule (Gated DeltaNet) by chunks of ``chunk`` steps.
+
+        S_t = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    per head, ``S`` (dk, dv), ``S_0 = 0``. q, k: (B, T, H, dk); v: (B, T, H,
+    dv); g: (B, T, H), the log of the decay (<= 0); beta: (B, T, H), in (0,
+    2) where the transition may have negative eigenvalues -> o (B, T, H, dv)
+    float32.
+
+    Written as ``S_t = exp(g_t) S_{t-1} + k_t u_t^T``, the value a step
+    writes is ``u_t = beta_t (v_t - exp(g_t) S_{t-1}^T k_t)``. Inside a
+    chunk handed the state ``S``, with ``G`` the running sum of ``g`` there
+    and ``Gamma_ts = exp(G_t - G_s)`` for s <= t, the writes solve one unit
+    lower-triangular system (the "UT transform"): ``(I + A) U = diag(beta)
+    V - diag(beta exp(G)) K S`` with ``A = tril(diag(beta) (Gamma * K K^T),
+    -1)``, so ``U = U' - W S`` with ``W = R diag(beta exp(G)) K``, ``U' = R
+    diag(beta) V``, ``R = (I + A)^-1``. Then ``O = diag(exp(G)) Q S + (Q K^T
+    * Gamma) U`` and the state handed on is ``exp(G_end) S + (exp(G_end -
+    G) K)^T U``. R, W, U' and the masked products are computed for every
+    chunk at once; a ``lax.scan`` over the chunks carries the (dk, dv)
+    state and computes U and O. Decays, running sums, the solve and the
+    state are float32 whatever ``q`` is (``decay_dtype`` is for a precision
+    check that wants to see a lower one fail); the products take ``k``'s
+    dtype and accumulate in float32. A ``T`` that ``chunk`` does not divide
+    is padded with steps of ``beta = 0`` and ``g = 0``, which neither decay
+    nor write."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    slow = jnp.dtype(decay_dtype or f32)          # decays, running sums, state
+    bsz, t, h, dk = k.shape
+    dv = v.shape[-1]
+    c = min(chunk, t)
+    pad = -t % c
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))
+            for z in (q, k, v, g, beta))
+    n = (t + pad) // c
+    low = k.dtype
+
+    def chunks(z):                       # (B, T, H, ...) -> (n, B, H, C, ...)
+        z = z.reshape((bsz, n, c, h) + z.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(z, 3, 2), 1, 0)
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a.astype(low), b.astype(low),
+                          preferred_element_type=f32)
+
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    beta = chunks(beta).astype(f32)                            # (n, B, H, C)
+    run = jnp.cumsum(chunks(g).astype(slow), axis=-1)                  # G
+    end = run[..., -1:]
+    causal = jnp.tril(jnp.ones((c, c), bool))
+    gamma = jnp.exp(jnp.where(causal, run[..., :, None] - run[..., None, :],
+                              -jnp.inf)).astype(f32)
+    a = jnp.where(jnp.tril(causal, -1), beta[..., :, None] * gamma
+                  * dot("...sd,...rd->...sr", k, k), 0.0)
+    # R = (I + A)^-1: the unit diagonal is implied, A's own diagonal is 0
+    solve = jax.lax.linalg.triangular_solve(
+        a, jnp.broadcast_to(jnp.eye(c, dtype=f32), a.shape), left_side=True,
+        lower=True, unit_diagonal=True)
+    grown = jnp.exp(run).astype(f32)
+    w = dot("...sr,...rd->...sd", solve, k * (beta * grown)[..., None])
+    u = dot("...sr,...rd->...sd", solve, v * beta[..., None])
+    qk = dot("...sd,...rd->...sr", q, k) * gamma
+    qg = q * grown[..., None]
+    kt = k * jnp.exp(end - run).astype(f32)[..., None]
+    keep = jnp.exp(end[..., 0]).astype(f32)                       # (n, B, H)
+
+    def carry(state, xs):
+        w_c, u_c, qk_c, qg_c, kt_c, keep_c = xs
+        new = u_c - dot("bhsd,bhde->bhse", w_c, state)                   # U
+        o = dot("bhsd,bhde->bhse", qg_c, state) \
+            + dot("bhsr,bhre->bhse", qk_c, new)
+        state = state * keep_c[..., None, None] \
+            + dot("bhsd,bhse->bhde", kt_c, new)
+        return state.astype(slow), o
+
+    _, o = jax.lax.scan(carry, jnp.zeros((bsz, h, dk, dv), slow),
+                        (w, u, qk, qg, kt, keep))         # (n, B, H, C, dv)
+    o = o.transpose(1, 0, 3, 2, 4).reshape(bsz, n * c, h, dv)
+    return o[:, :t]
+
+
+def l2_norm(x, eps=1e-6):
+    """x / sqrt(sum(x^2) + eps) over the last axis, in float32, the result
+    in ``x``'s dtype."""
+    import jax
+    import jax.numpy as jnp
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True)
+                                + eps)).astype(x.dtype)
+
+
+def norm_then_gate(y, z, weight, eps=1e-6):
+    """RMSNorm(y) * weight * silu(z) over the last axis: the norm BEFORE the
+    gate (``gated_group_rms_norm``'s reverse order); the statistics in
+    float32, the result in ``z``'s dtype."""
+    import jax
+    import jax.numpy as jnp
+    normed = rms_norm(y.astype(jnp.float32), weight, eps)
+    return (normed * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+
+
+def gated_deltanet_mixer(x, w_q, w_k, w_v, conv_q, conv_k, conv_v, w_a, a_log,
+                         dt_bias, w_b, w_g, norm, w_o, heads=1, key_dim=1,
+                         value_dim=1, neg_eigval=True, chunk=64, eps=1e-6):
+    """The Gated DeltaNet mixer over x (B, T, D), ``heads`` key and value
+    heads.
+
+    ``q, k, v = silu(causal_conv1d(x w))`` for each of w_q, w_k, w_v (no
+    bias); per head ``q = l2_norm(q) / sqrt(key_dim)``, ``k = l2_norm(k)``;
+    ``beta = sigmoid(x w_b)``, doubled where ``neg_eigval`` (the transition
+    then has eigenvalues down to -1); ``g = -exp(a_log) softplus(x w_a +
+    dt_bias)``; ``o = gated_delta_rule_chunked(q, k, v, g, beta)``; per head
+    ``o = norm_then_gate(o, x w_g)`` (one ``value_dim`` weight for all
+    heads); ``w_o`` back to D."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    bsz, t, _ = x.shape
+
+    def heads_of(y, dim):
+        return y.reshape(bsz, t, heads, dim)
+
+    def branch(w, conv, dim):
+        return heads_of(jax.nn.silu(causal_conv1d(jnp.dot(x, w), conv)), dim)
+
+    with jax.named_scope("mx.gdn"):
+        q = l2_norm(branch(w_q, conv_q, key_dim).astype(f32)) * key_dim ** -0.5
+        k = l2_norm(branch(w_k, conv_k, key_dim))
+        v = branch(w_v, conv_v, value_dim)
+        beta = jax.nn.sigmoid(jnp.dot(x, w_b).astype(f32))
+        if neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            jnp.dot(x, w_a).astype(f32) + dt_bias.astype(f32))
+        with jax.named_scope("mx.delta_rule"):
+            o = gated_delta_rule_chunked(q.astype(x.dtype), k, v, g, beta,
+                                         chunk)
+        o = norm_then_gate(o, heads_of(jnp.dot(x, w_g), value_dim), norm, eps)
+        return jnp.dot(o.reshape(bsz, t, heads * value_dim), w_o)
+
+
 def gqa_attention(x, w_q, w_k, w_v, w_o, heads=1, kv_heads=1, head_dim=1):
     """Causal grouped-KV attention over x (B, T, D), no position embedding:
     ``heads`` query heads, query head ``h`` reads KV head ``h // (heads /
@@ -239,6 +389,31 @@ def gqa_attention(x, w_q, w_k, w_v, w_o, heads=1, kv_heads=1, head_dim=1):
             k.reshape(bsz * heads, t, head_dim),
             v.reshape(bsz * heads, t, head_dim), causal=True,
             scale=head_dim ** -0.5)
+        o = o.reshape(bsz, heads, t, head_dim).transpose(0, 2, 1, 3)
+        return jnp.dot(o.reshape(bsz, t, heads * head_dim), w_o)
+
+
+def qk_norm_attention(x, w_q, q_norm, w_k, k_norm, w_v, w_o, heads=1,
+                      head_dim=1, eps=1e-6):
+    """Causal multi-head attention over x (B, T, D) with QK-norm, no
+    position embedding: ``q = RMSNorm(x w_q)``, ``k = RMSNorm(x w_k)``, each
+    norm over the whole projection (all heads at once), ``v = x w_v``;
+    softmax of ``q.k / sqrt(head_dim)`` per head through
+    ``blocked_attention``; ``w_o`` back to D."""
+    import jax
+    import jax.numpy as jnp
+    from .pallas_kernels import blocked_attention
+    bsz, t, _ = x.shape
+
+    def split(y):                         # (B, T, H*d) -> (B*H, T, d)
+        return y.reshape(bsz, t, heads, head_dim).transpose(0, 2, 1, 3) \
+            .reshape(bsz * heads, t, head_dim)
+
+    with jax.named_scope("mx.attention"):
+        q = rms_norm(jnp.dot(x, w_q), q_norm, eps)
+        k = rms_norm(jnp.dot(x, w_k), k_norm, eps)
+        o = blocked_attention(split(q), split(k), split(jnp.dot(x, w_v)),
+                              causal=True, scale=head_dim ** -0.5)
         o = o.reshape(bsz, heads, t, head_dim).transpose(0, 2, 1, 3)
         return jnp.dot(o.reshape(bsz, t, heads * head_dim), w_o)
 
@@ -316,6 +491,24 @@ def _gqa_attention_op(x, w_q, w_k, w_v, w_o, heads=1, kv_heads=1,
                       head_dim=1):
     return gqa_attention(x, w_q, w_k, w_v, w_o, heads=heads,
                          kv_heads=kv_heads, head_dim=head_dim)
+
+
+@register("_contrib_gated_deltanet_mixer")
+def _gated_deltanet_mixer_op(x, w_q, w_k, w_v, conv_q, conv_k, conv_v, w_a,
+                             a_log, dt_bias, w_b, w_g, norm, w_o, heads=1,
+                             key_dim=1, value_dim=1, neg_eigval=True,
+                             chunk=64, eps=1e-6):
+    return gated_deltanet_mixer(
+        x, w_q, w_k, w_v, conv_q, conv_k, conv_v, w_a, a_log, dt_bias, w_b,
+        w_g, norm, w_o, heads=heads, key_dim=key_dim, value_dim=value_dim,
+        neg_eigval=neg_eigval, chunk=chunk, eps=eps)
+
+
+@register("_contrib_qk_norm_attention")
+def _qk_norm_attention_op(x, w_q, q_norm, w_k, k_norm, w_v, w_o, heads=1,
+                          head_dim=1, eps=1e-6):
+    return qk_norm_attention(x, w_q, q_norm, w_k, k_norm, w_v, w_o,
+                             heads=heads, head_dim=head_dim, eps=eps)
 
 
 @register("_contrib_dropless_moe", num_outputs=3, aux_inputs=(2,))

@@ -1053,6 +1053,9 @@ COVERED_ELSEWHERE = {
     # the nemotron_h family's mixers (ops/lm_ops.py)
     **{op: "tests/test_nemotron_h.py" for op in [
         "_contrib_mamba2_mixer", "_contrib_gqa_attention"]},
+    # the olmo_hybrid family's mixers (ops/lm_ops.py)
+    **{op: "tests/test_olmo_hybrid.py" for op in [
+        "_contrib_gated_deltanet_mixer", "_contrib_qk_norm_attention"]},
     # pallas fused conv epilogues (fwd+grad parity, fallback, fold)
     **{op: "tests/test_fused_epilogue.py" for op in [
         "_contrib_fused_bn_relu", "_contrib_fused_bn_add_relu"]},
