@@ -217,16 +217,16 @@ def mamba2_mixer(x, w_in, conv_weight, conv_bias, dt_bias, a_log, d, norm,
         return jnp.dot(y, w_out)
 
 
-def gated_delta_rule_chunked(q, k, v, g, beta, chunk=64, decay_dtype=None):
+def gated_delta_rule_chunked(q, k, v, g, beta, chunk=64):
     """The gated delta rule (Gated DeltaNet) by chunks of ``chunk`` steps.
 
         S_t = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T
         o_t = S_t^T q_t
 
-    per head, ``S`` (dk, dv), ``S_0 = 0``. q, k: (B, T, H, dk); v: (B, T, H,
-    dv); g: (B, T, H), the log of the decay (<= 0); beta: (B, T, H), in (0,
-    2) where the transition may have negative eigenvalues -> o (B, T, H, dv)
-    float32.
+    per head, ``S`` (dk, dv), ``S_0 = 0``. Heads first: q, k: (B, H, T, dk);
+    v: (B, H, T, dv); g: (B, H, T), the log of the decay (<= 0); beta: (B, H,
+    T), in (0, 2) where the transition may have negative eigenvalues -> o
+    (B, H, T, dv) float32.
 
     Written as ``S_t = exp(g_t) S_{t-1} + k_t u_t^T``, the value a step
     writes is ``u_t = beta_t (v_t - exp(g_t) S_{t-1}^T k_t)``. Inside a
@@ -237,32 +237,63 @@ def gated_delta_rule_chunked(q, k, v, g, beta, chunk=64, decay_dtype=None):
     -1)``, so ``U = U' - W S`` with ``W = R diag(beta exp(G)) K``, ``U' = R
     diag(beta) V``, ``R = (I + A)^-1``. Then ``O = diag(exp(G)) Q S + (Q K^T
     * Gamma) U`` and the state handed on is ``exp(G_end) S + (exp(G_end -
-    G) K)^T U``. R, W, U' and the masked products are computed for every
-    chunk at once; a ``lax.scan`` over the chunks carries the (dk, dv)
-    state and computes U and O. Decays, running sums, the solve and the
-    state are float32 whatever ``q`` is (``decay_dtype`` is for a precision
-    check that wants to see a lower one fail); the products take ``k``'s
-    dtype and accumulate in float32. A ``T`` that ``chunk`` does not divide
-    is padded with steps of ``beta = 0`` and ``g = 0``, which neither decay
-    nor write."""
+    G) K)^T U``. The forward is one kernel, ``mx_delta_rule``
+    (``pallas_kernels.delta_rule``); the backward is ``jax.vjp`` of the same
+    algebra as XLA ops (``_delta_rule_xla``), recomputed from the five
+    inputs. Decays, running sums, the solve and the state are float32
+    whatever ``q`` is; the products take ``k``'s dtype and accumulate in
+    float32. A ``T`` that ``chunk`` does not divide is padded with steps of
+    ``beta = 0`` and ``g = 0``, which neither decay nor write. ``chunk`` is
+    a power of two, as the kernel's lane masks and its solve by doubling
+    need: another raises ``ValueError``."""
+    import jax
+    from .pallas_kernels import delta_rule
+    if chunk < 1 or chunk & (chunk - 1):
+        raise ValueError(f"chunk must be a power of two, not {chunk}")
+
+    @jax.custom_vjp
+    def op(*args):
+        return delta_rule(*args, chunk)
+
+    def op_fwd(*args):
+        return delta_rule(*args, chunk), args
+
+    def op_bwd(args, do):
+        return jax.vjp(lambda *a: _delta_rule_xla(*a, chunk), *args)[1](do)
+
+    op.defvjp(op_fwd, op_bwd)
+    return op(q, k, v, g, beta)
+
+
+def _heads_first(z):
+    """(B, T, H, ...) <-> (B, H, T, ...)."""
+    return z.swapaxes(1, 2)
+
+
+def _delta_rule_xla(q, k, v, g, beta, chunk=64, decay_dtype=None):
+    """``gated_delta_rule_chunked`` as XLA ops, over the same heads-first
+    operands and for any ``chunk``: R, W, U' and the masked products for
+    every chunk at once, then a ``lax.scan`` over the chunks that carries
+    the (dk, dv) state and computes U and O. The kernel's backward;
+    ``decay_dtype`` runs the decays, running sums and state in a lower
+    precision, for a check that wants to see one fail."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
     slow = jnp.dtype(decay_dtype or f32)          # decays, running sums, state
-    bsz, t, h, dk = k.shape
+    bsz, h, t, dk = k.shape
     dv = v.shape[-1]
     c = min(chunk, t)
     pad = -t % c
     if pad:
         q, k, v, g, beta = (
-            jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))
+            jnp.pad(z, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 3))
             for z in (q, k, v, g, beta))
     n = (t + pad) // c
     low = k.dtype
 
-    def chunks(z):                       # (B, T, H, ...) -> (n, B, H, C, ...)
-        z = z.reshape((bsz, n, c, h) + z.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(z, 3, 2), 1, 0)
+    def chunks(z):                       # (B, H, T, ...) -> (n, B, H, C, ...)
+        return jnp.moveaxis(z.reshape((bsz, h, n, c) + z.shape[3:]), 2, 0)
 
     def dot(spec, a, b):
         return jnp.einsum(spec, a.astype(low), b.astype(low),
@@ -300,8 +331,8 @@ def gated_delta_rule_chunked(q, k, v, g, beta, chunk=64, decay_dtype=None):
 
     _, o = jax.lax.scan(carry, jnp.zeros((bsz, h, dk, dv), slow),
                         (w, u, qk, qg, kt, keep))         # (n, B, H, C, dv)
-    o = o.transpose(1, 0, 3, 2, 4).reshape(bsz, n * c, h, dv)
-    return o[:, :t]
+    o = o.transpose(1, 2, 0, 3, 4).reshape(bsz, h, n * c, dv)
+    return o[:, :, :t]
 
 
 def l2_norm(x, eps=1e-6):
@@ -357,10 +388,12 @@ def gated_deltanet_mixer(x, w_q, w_k, w_v, conv_q, conv_k, conv_v, w_a, a_log,
             beta = 2.0 * beta
         g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
             jnp.dot(x, w_a).astype(f32) + dt_bias.astype(f32))
+        q, k, v, g, beta = map(_heads_first, (q, k, v, g, beta))
         with jax.named_scope("mx.delta_rule"):
             o = gated_delta_rule_chunked(q.astype(x.dtype), k, v, g, beta,
                                          chunk)
-        o = norm_then_gate(o, heads_of(jnp.dot(x, w_g), value_dim), norm, eps)
+        o = norm_then_gate(_heads_first(o), heads_of(jnp.dot(x, w_g),
+                                                     value_dim), norm, eps)
         return jnp.dot(o.reshape(bsz, t, heads * value_dim), w_o)
 
 

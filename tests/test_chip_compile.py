@@ -150,6 +150,29 @@ def test_blocked_attention_compiles_for_v5e(one_chip, bh, t, dk, dv, dtype,
     assert _n_kernels(jax.jit(bwd).lower(q, q, v, v, lse, v).compile()) == 2
 
 
+def test_delta_rule_forward_compiles_for_v5e_as_one_kernel(one_chip,
+                                                           monkeypatch):
+    """The chunked delta rule's forward at Olmo-Hybrid-7B's widths (30
+    heads, 96 x 192, chunk 64, 8,192 tokens): one kernel, whose blocks, the
+    heads' state and the chunk matrices fit the VMEM it asks for, and no
+    XLA loop beside it. (A tracer here lowers for the CPU: the kernel is
+    told it is on the chip.)"""
+    from mxnet_tpu.ops import lm_ops, pallas_kernels as pk
+    monkeypatch.setattr(pk, "_interpret_for", lambda x: False)
+    b, h, t, dk, dv = 1, 30, 8192, 96, 192
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *a: lm_ops.gated_delta_rule_chunked(
+        *a, chunk=64)).lower(
+            sds(b, h, t, dk), sds(b, h, t, dk), sds(b, h, t, dv),
+            sds(b, h, t, dtype=jnp.float32),
+            sds(b, h, t, dtype=jnp.float32)).compile()
+    assert _n_kernels(compiled) == 1
+    assert " while(" not in compiled.as_text()
+
+
 def test_mamba2_mixer_compiles_for_v5e_without_a_square_or_a_state_a_step(
         one_chip):
     """The Mamba-2 mixer of Nemotron-3-Nano at its published widths and

@@ -119,9 +119,34 @@ def _rule_inputs(t, h, dk, dv, seed=0, beta_max=2.0, log_decay=-2.0):
     return dict(q=q, k=k, v=v, g=g, beta=beta)
 
 
-def _chunked(x, chunk, **kw):
-    return lm_ops.gated_delta_rule_chunked(x["q"], x["k"], x["v"], x["g"],
-                                           x["beta"], chunk, **kw)
+# the rule's forward: the kernel (what the model runs), and the XLA
+# formulation its backward is differentiated through
+PATHS = {"kernel": lm_ops.gated_delta_rule_chunked,
+         "xla": lm_ops._delta_rule_xla}
+
+
+def _chunked(x, chunk, path="kernel", **kw):
+    """The rule over the (B, T, H) inputs, handed to it heads first."""
+    first = (x[n].swapaxes(1, 2) for n in ("q", "k", "v", "g", "beta"))
+    return PATHS[path](*first, chunk, **kw).swapaxes(1, 2)
+
+
+def _eqns(jaxpr):
+    """The equations of a jaxpr and of those it holds."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _kernel_calls(jaxpr, name):
+    """The Pallas calls named ``name`` in a jaxpr and in those it holds (the
+    printed jaxpr names a kernel shared by several calls once)."""
+    return sum(eqn.primitive.name == "pallas_call"
+               and eqn.params.get("name") == name for eqn in _eqns(jaxpr))
 
 
 @jax.jit
@@ -142,12 +167,14 @@ RULES = {  # (T, heads, dk, dv, chunk, input options)
 }
 
 
-@pytest.mark.parametrize("case", RULES)
-def test_chunked_rule_matches_the_step_by_step_scan(case):
+@pytest.mark.parametrize("case, path", [
+    pytest.param(case, path, id=case if path == "kernel" else f"{case}-{path}")
+    for path in PATHS for case in RULES])
+def test_chunked_rule_matches_the_step_by_step_scan(case, path):
     t, h, dk, dv, chunk, opts = RULES[case]
     x = _rule_inputs(t, h, dk, dv, **opts)
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(_chunked, static_argnums=1)(x, chunk)
+        got = jax.jit(_chunked, static_argnums=(1, 2))(x, chunk, path)
         want = _stepwise(x)
     assert got.shape == (2, t, h, dv) and got.dtype == jnp.float32
     assert float(jnp.std(want)) > 0.05
@@ -179,6 +206,23 @@ def test_chunked_rule_gradients(case, wrt):
     assert rel(got, want) < 2e-5
 
 
+def test_kernel_and_xla_formulation_agree_in_bfloat16():
+    """On the same bf16 q, k and v the kernel casts where the XLA
+    formulation does: the two agree far inside the band that bf16 puts
+    both in against the float32 rule."""
+    t, h, dk, dv, chunk, opts = RULES["chunk64"]
+    x = _rule_inputs(t, h, dk, dv, seed=6)
+    low = dict(x, **{n: x[n].astype(jnp.bfloat16) for n in ("q", "k", "v")})
+    with jax.default_matmul_precision("highest"):
+        want = _stepwise(x)
+    kernel, xla = (jax.jit(_chunked, static_argnums=(1, 2))(low, chunk, path)
+                   for path in PATHS)
+    assert kernel.dtype == jnp.float32
+    assert TIGHT < rel(kernel, want) < LOOSE
+    assert TIGHT < rel(xla, want) < LOOSE
+    assert rel(kernel, xla) < LOOSE / 100
+
+
 def test_rule_with_bfloat16_decay_and_state_is_told_from_float32():
     """The running sums of the decay and the carried state in bfloat16 leave
     the band float32 stays in by two orders of magnitude."""
@@ -187,7 +231,7 @@ def test_rule_with_bfloat16_decay_and_state_is_told_from_float32():
     with jax.default_matmul_precision("highest"):
         want = _stepwise(x)
         exact = rel(_chunked(x, chunk), want)
-        low = rel(_chunked(x, chunk, decay_dtype=jnp.bfloat16), want)
+        low = rel(_chunked(x, chunk, "xla", decay_dtype=jnp.bfloat16), want)
     assert exact < 1e-5 and low > 1e-3
 
 
@@ -201,16 +245,36 @@ def test_negative_eigenvalues_change_the_result():
     assert rel(half, full) > 0.05
 
 
-def test_rule_keeps_no_step_by_step_state():
-    """No state for every step and no (T, T) array: the largest array of the
-    rule is of the order of T x chunk or chunks x dk x dv."""
+@pytest.mark.parametrize("path", PATHS)
+def test_rule_keeps_no_step_by_step_state(path):
+    """No state for every step and no (T, T) array, in the forward or the
+    backward, the XLA formulation's loops and the kernel's body included:
+    the largest array of the rule is of the order of T x chunk or chunks x
+    dk x dv."""
     t, h, dk, dv, chunk = 512, 2, 16, 32, 32
     x = _rule_inputs(t, h, dk, dv)
-    jaxpr = jax.make_jaxpr(lambda v: _chunked(dict(x, v=v), chunk))(x["v"])
-    largest = max(int(np.prod(v.aval.shape)) for eqn in jaxpr.eqns
-                  for v in eqn.outvars if hasattr(v.aval, "shape"))
+    cot = jax.random.normal(jax.random.PRNGKey(9), (2, t, h, dv))
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda v: jnp.sum(
+        _chunked(dict(x, v=v), chunk, path) * cot)))(x["v"]).jaxpr
+    sizes = [int(np.prod(v.aval.shape)) for eqn in _eqns(jaxpr)
+             for v in eqn.outvars if hasattr(v.aval, "shape")]
+    if path == "kernel":
+        assert _kernel_calls(jaxpr, "mx_delta_rule") == 1
+    assert any(eqn.primitive.name == "scan" for eqn in _eqns(jaxpr))
     per_step_state, square = 2 * t * h * dk * dv, 2 * h * t * t
-    assert largest <= 2 * t * h * max(chunk, dv) < min(per_step_state, square)
+    assert max(sizes) <= 2 * t * h * max(chunk, dv) < min(per_step_state,
+                                                           square)
+
+
+def test_a_chunk_that_is_not_a_power_of_two_is_refused():
+    """The kernel's lane masks and its solve by doubling need a chunk that
+    is a power of two; the XLA formulation takes any."""
+    t, h, dk, dv = 48, 2, 16, 24
+    x = _rule_inputs(t, h, dk, dv)
+    with pytest.raises(ValueError, match="power of two"):
+        _chunked(x, 24)
+    with jax.default_matmul_precision("highest"):
+        assert rel(_chunked(x, 24, "xla"), _stepwise(x)) < 1e-5
 
 
 # ---------------------------------------------------------------------------
@@ -350,8 +414,9 @@ def test_causal_prefix_property():
 
 def test_forward_names_its_parts():
     """The hybridized forward outside ``autograd.record()`` runs the linear
-    layers under ``mx.gdn`` with the rule under ``mx.delta_rule``, and the
-    full layer under ``mx.attention`` through the attention kernel."""
+    layers under ``mx.gdn`` with the rule under ``mx.delta_rule`` through
+    the delta rule's kernel, and the full layer under ``mx.attention``
+    through the attention kernel."""
     net = make_net()
     net.hybridize()
     tokens, _ = batch()
@@ -361,9 +426,11 @@ def test_forward_names_its_parts():
         first, functional(make_net())(params_of(net), tokens), rtol=1e-5,
         atol=1e-5)
     forward = functional(net)
-    jaxpr = str(jax.make_jaxpr(forward)(params_of(net), tokens))
-    assert jaxpr.count("name=mx_attention_fwd") == TOY["layer_types"].count(
-        "full_attention")
+    closed = jax.make_jaxpr(forward)(params_of(net), tokens)
+    assert str(closed).count("name=mx_attention_fwd") == TOY[
+        "layer_types"].count("full_attention")
+    assert _kernel_calls(closed.jaxpr, "mx_delta_rule") == TOY[
+        "layer_types"].count("linear_attention")
     hlo = jax.jit(forward).lower(params_of(net), tokens).as_text(
         debug_info=True)
     for scope in ("mx.gdn", "mx.delta_rule", "mx.attention", "mx.lm_head"):
