@@ -74,7 +74,8 @@ class DroplessMoE(HybridBlock):
     """The expert layer for the experts held here
     (``parallel.moe.dropless_moe_ffn``): SwiGLU experts, or with
     ``activation="relu2"`` experts of one product in; the shared expert is
-    ``shared_width`` wide (``width`` by default). ``bias`` is the router's
+    ``shared_width`` wide (``width`` by default; 0: no shared expert, and
+    no ``shared_in``/``shared_out`` parameters). ``bias`` is the router's
     selection bias: no gradient; in training mode each forward moves it by
     ``gamma * sign(mean load - load)``. ``load`` (tokens routed to each of
     the router's experts in the last step) and ``tokens_here`` ((token,
@@ -92,7 +93,7 @@ class DroplessMoE(HybridBlock):
         self._attrs = dict(k=top_k, experts_held=tuple(experts_held),
                            scaling=float(scaling), activation=activation)
         self._gamma = gamma
-        shared = shared_width or width
+        shared = width if shared_width is None else shared_width
         halves = 2 if activation == "swiglu" else 1
         get = self.params.get
         self.gate = get("gate", shape=(hidden, router_experts))
@@ -100,19 +101,22 @@ class DroplessMoE(HybridBlock):
                         grad_req="null", differentiable=False)
         self.w_in = get("w_in", shape=(held, hidden, halves * width))
         self.w_out = get("w_out", shape=(held, width, hidden))
-        self.shared_in = get("shared_in", shape=(hidden, halves * shared))
-        self.shared_out = get("shared_out", shape=(shared, hidden))
+        if shared:
+            self.shared_in = get("shared_in", shape=(hidden, halves * shared))
+            self.shared_out = get("shared_out", shape=(shared, hidden))
         self.load = get("load", shape=(router_experts,), init="zeros",
                         grad_req="null", differentiable=False)
         self.tokens_here = get("tokens_here", shape=(1,), init="zeros",
                                grad_req="null", differentiable=False)
 
-    def hybrid_forward(self, F, x, gate, bias, w_in, w_out, shared_in,
-                       shared_out, load, tokens_here):
+    def hybrid_forward(self, F, x, gate, bias, w_in, w_out, load,
+                       tokens_here, **shared):
         from .... import autograd
         from ....parallel.moe import balance_bias_update
         y, new_load, here = F.contrib.dropless_moe(
-            x, gate, bias, w_in, w_out, shared_in, shared_out, **self._attrs)
+            x, gate, bias, w_in, w_out,
+            *(shared[k] for k in ("shared_in", "shared_out") if k in shared),
+            **self._attrs)
         if autograd.is_training():
             with autograd.pause():
                 load._rebind(new_load._data)

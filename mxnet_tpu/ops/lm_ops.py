@@ -5,7 +5,9 @@ family: the Mamba-2 mixer (causal depthwise convolution, the selective
 state-space recurrence by chunks, a gated grouped RMSNorm), grouped-KV
 attention and the squared-ReLU FFN. The ``olmo_hybrid`` family: the Gated
 DeltaNet mixer (the gated delta rule by chunks, per-head L2 norms, a
-per-head norm then gate) and attention with QK-norm.
+per-head norm then gate) and attention with QK-norm. The ``mimo_v2``
+family: attention from a fused QKV projection with partial rotary
+embedding, full or in a sliding window with a sink.
 
 Pure JAX functions, registered like every other op, so one definition
 serves eager NDArray calls, the autograd tape, hybridized blocks and
@@ -30,11 +32,15 @@ def rms_norm(x, weight, eps=1e-5):
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, theta=10000.0):
+def rope(x, theta=10000.0, dims=None):
     """Rotary embedding over the last axis of ``x`` (..., T, dim), position
     = index along T. Pairs are (i, i + dim/2) ("rotate half"); angles in
-    float32."""
+    float32. ``dims``: over the first ``dims`` of the axis alone, as if they
+    were the whole of it; the rest pass unchanged (partial rotary)."""
     import jax.numpy as jnp
+    if dims is not None and dims < x.shape[-1]:
+        return jnp.concatenate([rope(x[..., :dims], theta), x[..., dims:]],
+                               axis=-1)
     t, dim = x.shape[-2], x.shape[-1]
     half = dim // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
@@ -451,6 +457,53 @@ def qk_norm_attention(x, w_q, q_norm, w_k, k_norm, w_v, w_o, heads=1,
         return jnp.dot(o.reshape(bsz, t, heads * head_dim), w_o)
 
 
+def fused_qkv_attention(x, w_qkv, w_o, *sink, heads=1, kv_heads=1,
+                        qk_dim=1, v_dim=1, rope_dim=None, theta=10000.0,
+                        window=None, value_scale=1.0):
+    """Causal grouped-KV attention over x (B, T, D) from one fused
+    projection, full or in a sliding window with a sink (MiMo-V2's two
+    kinds of layer).
+
+    ``[q | k | v] = x w_qkv``: ``heads`` query heads and ``kv_heads`` key
+    heads ``qk_dim`` wide, ``kv_heads`` value heads ``v_dim`` wide; the
+    rotary embedding (``rope``, base ``theta``) over the first ``rope_dim``
+    dims of each q and k head; query head ``h`` reads KV head ``h //
+    (heads / kv_heads)``; softmax of ``q.k / sqrt(qk_dim)`` over keys ``j
+    <= i``, or with ``window`` over ``i - window < j <= i``; ``sink``: one
+    logit a head, ``(heads,)``, of a key with no value, in the softmax's
+    denominator; ``o = value_scale * sum_j p_ij v_j``; ``w_o`` from heads x
+    v_dim back to D. A window layer runs under the scope ``mx.swa``, a full
+    one under ``mx.full_attn``. The kernels (``blocked_attention``, which
+    walks only the band's tiles under a window) see the KV heads repeated
+    to the query heads' count."""
+    import jax
+    import jax.numpy as jnp
+    from .pallas_kernels import blocked_attention
+    bsz, t, _ = x.shape
+    rep = heads // kv_heads
+    n_q, n_k = heads * qk_dim, kv_heads * qk_dim
+
+    def split(y, n, d):                       # (B, T, n*d) -> (B, n, T, d)
+        return y.reshape(bsz, t, n, d).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("mx.full_attn" if window is None else "mx.swa"):
+        qkv = jnp.dot(x, w_qkv)
+        q = rope(split(qkv[..., :n_q], heads, qk_dim), theta, rope_dim)
+        k = rope(split(qkv[..., n_q:n_q + n_k], kv_heads, qk_dim), theta,
+                 rope_dim)
+        v = split(qkv[..., n_q + n_k:], kv_heads, v_dim)
+        k, v = (jnp.repeat(z, rep, axis=1) for z in (k, v))
+        o = blocked_attention(
+            q.reshape(bsz * heads, t, qk_dim),
+            k.reshape(bsz * heads, t, qk_dim),
+            v.reshape(bsz * heads, t, v_dim), causal=True,
+            scale=qk_dim ** -0.5, window=window,
+            sink=jnp.tile(sink[0].astype(jnp.float32), bsz) if sink else None)
+        o = o.reshape(bsz, heads, t, v_dim).transpose(0, 2, 1, 3) \
+            * jnp.asarray(value_scale, o.dtype)
+        return jnp.dot(o.reshape(bsz, t, heads * v_dim), w_o)
+
+
 def mla_attention(x, w_qa, q_norm, w_qb, w_kva, kv_norm, w_kvb, w_o,
                   heads=1, nope=0, rope_dim=0, v_dim=0, theta=10000.0,
                   eps=1e-5):
@@ -526,6 +579,18 @@ def _gqa_attention_op(x, w_q, w_k, w_v, w_o, heads=1, kv_heads=1,
                          kv_heads=kv_heads, head_dim=head_dim)
 
 
+@register("_contrib_fused_qkv_attention")
+def _fused_qkv_attention_op(x, w_qkv, w_o, *sink, heads=1, kv_heads=1,
+                            qk_dim=1, v_dim=1, rope_dim=None, theta=10000.0,
+                            window=None, value_scale=1.0):
+    """``fused_qkv_attention``; ``sink``: the (heads,) sink logits of a
+    window layer, or nothing."""
+    return fused_qkv_attention(
+        x, w_qkv, w_o, *sink, heads=heads, kv_heads=kv_heads, qk_dim=qk_dim,
+        v_dim=v_dim, rope_dim=rope_dim, theta=theta, window=window,
+        value_scale=value_scale)
+
+
 @register("_contrib_gated_deltanet_mixer")
 def _gated_deltanet_mixer_op(x, w_q, w_k, w_v, conv_q, conv_k, conv_v, w_a,
                              a_log, dt_bias, w_b, w_g, norm, w_o, heads=1,
@@ -545,16 +610,16 @@ def _qk_norm_attention_op(x, w_q, q_norm, w_k, k_norm, w_v, w_o, heads=1,
 
 
 @register("_contrib_dropless_moe", num_outputs=3, aux_inputs=(2,))
-def _dropless_moe_op(x, gate, bias, w_in, w_out, shared_in, shared_out,
-                     k=1, experts_held=None, scaling=1.0,
-                     activation="swiglu"):
+def _dropless_moe_op(x, gate, bias, w_in, w_out, *shared, k=1,
+                     experts_held=None, scaling=1.0, activation="swiglu"):
     """``parallel.moe.dropless_moe_ffn``: (y, load over all experts, pairs
-    computed here), the two counters as float32."""
+    computed here), the two counters as float32. ``shared``: the shared
+    expert's (w_in, w_out), or nothing for a layer without one."""
     import jax.numpy as jnp
     from ..parallel.moe import dropless_moe_ffn
     y, stats = dropless_moe_ffn(
         x, {"gate": gate, "bias": bias, "w_in": w_in, "w_out": w_out,
-            "shared_in": shared_in, "shared_out": shared_out},
+            **dict(zip(("shared_in", "shared_out"), shared))},
         k, experts_held, scaling, activation=activation)
     return (y, stats["load"].astype(jnp.float32),
             stats["tokens_here"].astype(jnp.float32))

@@ -154,6 +154,10 @@ _MASKED = -0.7 * 3.0e38  # not -inf: exp(-inf - -inf) is NaN
 # kernel stays under the 16 MB the compiler gives it.
 _ATTENTION_STRETCH_VMEM = 4 * 1024 * 1024
 _ATTENTION_STRETCH_TILES = 4  # variants of the body grow with its square
+# rows of BH a grid step of the band's forward takes: a step of one head's
+# 128 x 128 tile costs the chip more to run than its products and copies
+# take (PERF.md, section 7, item 12)
+_BAND_HEADS = 8
 
 
 class _Walk(NamedTuple):
@@ -192,8 +196,24 @@ class _Walk(NamedTuple):
         return tuple((j, False) for j in range(place)) + ((place, True),)
 
 
+class _Band(NamedTuple):
+    """The walk of a sliding window of ``window`` keys a query, its own
+    included (query i reads keys i - window < j <= i). A grid step owns a
+    block of queries (of keys in dK/dV) and visits ONE tile of the other
+    operand: the tiles from ``reach`` tiles before its own up to its own,
+    so a block takes ``reach + 1`` grid steps, whatever T is. Every visited
+    tile is masked on both of the band's edges. The forward's grid step
+    takes ``_BAND_HEADS`` rows of BH at once, the backward's one."""
+    block: int
+    reach: int
+
+    @property
+    def stretch(self):
+        return self.block
+
+
 def _attention_walk(t: int, dk: int, dv: int, itemsize: int,
-                    backward: bool = False) -> _Walk:
+                    backward: bool = False, window=None):
     """Block and stretch from the shapes alone. A block is the largest of
     512/256/128 rows that divides T (512x512 float32 logits are 1 MB of
     VMEM); a T they do not divide is one block (the small shapes of the
@@ -204,6 +224,14 @@ def _attention_walk(t: int, dk: int, dv: int, itemsize: int,
     that held the stretch's variants of both took 10 s longer to load from
     the compile cache (2 s with stretches of two tiles) for 6 - 12% less
     of their time (``PERF.md``, section 6, PR 37)."""
+    # Under a window the walk is a _Band in all three passes: a block of 128
+    # rows where the window is no wider (a window of 128 then touches two
+    # tiles of 128 x 128, twice the band's logits, where tiles of 512 would
+    # compute eight times them), else of 256.
+    if window is not None:
+        sizes = (128, 256) if window <= 128 else (256, 128)
+        block = next((b for b in sizes if t % b == 0), t)
+        return _Band(block, min(-(-(window - 1) // block), t // block - 1))
     block = next((b for b in (512, 256, 128) if t % b == 0), t)
     most = 1 if backward else min(
         _ATTENTION_STRETCH_TILES,
@@ -214,20 +242,30 @@ def _attention_walk(t: int, dk: int, dv: int, itemsize: int,
 
 @functools.lru_cache(maxsize=None)
 def _build_blocked_attention(t: int, dk: int, dv: int, causal: bool,
-                             scale: float, dtype: str, interpret: bool):
+                             scale: float, dtype: str, interpret: bool,
+                             window=None, sink: bool = False):
     """(fwd, bwd) over (BH, T, dk) queries and keys and (BH, T, dv)
-    values. ``fwd(q, k, v) -> (o, lse)`` with ``lse`` (BH, T) float32;
-    ``bwd(q, k, v, o, lse, do) -> (dq, dk, dv)``."""
+    values. ``fwd(q, k, v[, sink]) -> (o, lse)`` with ``lse`` (BH, T)
+    float32 (the sink's term included); ``bwd(q, k, v, o, lse, do) -> (dq,
+    dk, dv)``. ``window``: causal attention to the last ``window`` keys,
+    on a ``_Band`` walk, under kernel names of its own; ``sink`` (a window
+    only): ``fwd`` takes (BH,) float32 sink logits."""
     import jax.numpy as jnp
     itemsize = jnp.dtype(dtype).itemsize
-    fwd, _ = _attention_passes(t, dk, dv, causal, scale, interpret,
-                               _attention_walk(t, dk, dv, itemsize))
-    _, bwd = _attention_passes(t, dk, dv, causal, scale, interpret,
-                               _attention_walk(t, dk, dv, itemsize, True))
+    if window is None:
+        walks = (_attention_walk(t, dk, dv, itemsize),
+                 _attention_walk(t, dk, dv, itemsize, True))
+    else:
+        walks = (_attention_walk(t, dk, dv, itemsize, window=window),) * 2
+    fwd, _ = _attention_passes(t, dk, dv, causal, scale, interpret, walks[0],
+                               window, sink)
+    _, bwd = _attention_passes(t, dk, dv, causal, scale, interpret, walks[1],
+                               window)
     return fwd, bwd
 
 
-def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
+def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
+                      sink=False):
     """``_build_blocked_attention``'s (fwd, bwd) with both passes on one
     walk."""
     import jax
@@ -236,7 +274,10 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
     from jax.experimental.pallas import tpu as pltpu
 
     blk = walk.block
-    n, n_s = t // blk, t // walk.stretch
+    band = isinstance(walk, _Band)
+    n = t // blk
+    n_s = walk.reach + 1 if band else t // walk.stretch
+    name = "mx_attention_window_" if band else "mx_attention_"
     f32 = jnp.float32
     nt = (((1,), (1,)), ((), ()))  # a @ b.T
 
@@ -246,9 +287,18 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
     def logits(a, b, on_diagonal, transposed):
         """a @ b.T * scale, (blk, blk); on the diagonal tile the
         entries whose key comes after their query are masked.
-        ``transposed``: rows are keys."""
+        ``transposed``: rows are keys. On a band ``on_diagonal`` is the
+        (traced) count of tiles the query tile lies after the key tile, and
+        every entry outside the window is masked."""
         s = dot(a, b, nt) * scale
-        if on_diagonal:
+        if band:
+            r = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+            c = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+            # the query's position less the key's
+            ahead = (c - r if transposed else r - c) + on_diagonal * blk
+            s = jnp.where(jnp.logical_and(ahead >= 0, ahead < window), s,
+                          _MASKED)
+        elif on_diagonal:
             r = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
             c = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
             s = jnp.where(r <= c if transposed else r >= c, s, _MASKED)
@@ -269,7 +319,15 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
     def on_stretch(i, s, body, keys_own=False):
         """Run ``body(visits)`` where own block ``i`` and stretch ``s`` have
         anything unmasked: one straight-line variant for a stretch wholly
-        live and one for each place of the diagonal."""
+        live and one for each place of the diagonal. On a band, step ``s``
+        visits the one tile ``reach - s`` tiles before its own block (in
+        dK/dV: ``s`` tiles after), where there is one."""
+        if band:
+            apart = s if keys_own else walk.reach - s
+            other = i + apart if keys_own else i - apart
+            pl.when(jnp.logical_and(other >= 0, other < n))(
+                lambda: body(((0, apart),)))
+            return
         if not causal:
             body(walk.visits(None, keys_own))
             return
@@ -281,15 +339,24 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
                 lambda at=at: body(walk.visits(at, keys_own)))
 
     # blocks named by (head, own block, stretch); the stretch is clamped to
-    # the causal range so a dead step copies nothing
+    # the causal range (on a band, to the sequence) so a dead step copies
+    # nothing
     def own(width):
         return pl.BlockSpec((1, blk, width), lambda b, i, s: (b, i, 0))
 
     def walked(shape, index, keys_own=False):
+        if band:
+            return pl.BlockSpec(shape, (lambda b, i, s: index(
+                b, jnp.minimum(i + s, n - 1) if keys_own
+                else jnp.maximum(i - walk.reach + s, 0))))
         clamp = jnp.maximum if keys_own else jnp.minimum
         return pl.BlockSpec(
             shape, (lambda b, i, s: index(b, clamp(s, walk.diagonal(i)[0])))
             if causal else (lambda b, i, s: index(b, s)))
+
+    def last(i):
+        """The grid step that finishes own block ``i`` (queries own)."""
+        return walk.diagonal(i)[0] if causal and not band else n_s - 1
 
     def walked_rows(width, keys_own=False):
         return walked((1, walk.stretch, width), lambda b, s: (b, s, 0),
@@ -310,8 +377,9 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
     lanes = 128 if blk % 128 == 0 and dv % 128 == 0 else 1
 
     def across(a, width):
-        """(blk, lanes) against (blk, width) operands."""
-        return jnp.tile(a, (1, width // lanes)) if lanes > 1 else a
+        """(..., blk, lanes) against (..., blk, width) operands."""
+        return jnp.tile(a, (1,) * (a.ndim - 1) + (width // lanes,)) \
+            if lanes > 1 else a
 
     def fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s):
         qi, si = pl.program_id(1), pl.program_id(2)
@@ -338,7 +406,7 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
 
         on_stretch(qi, si, update)
 
-        @pl.when(si == (walk.diagonal(qi)[0] if causal else n_s - 1))
+        @pl.when(si == last(qi))
         def _():
             o_ref[0] = (acc_s[...] / across(l_s[...], dv)).astype(o_ref.dtype)
             lse_ref[0] = (m_s[...] + jnp.log(l_s[...]))[:, :1]
@@ -355,6 +423,81 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
                             pltpu.VMEM((blk, lanes), f32),
                             pltpu.VMEM((blk, dv), f32)],
             name="mx_attention_fwd", **kw)(q, k, v)
+        return o, lse[..., 0]
+
+    # ---- forward of a band: _BAND_HEADS rows of BH a grid step -----------
+    def band_fwd_kernel(q_ref, k_ref, v_ref, *refs):
+        if sink:  # the sink is a logit with no value: it starts m and l
+            sink_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
+        else:
+            o_ref, lse_ref, m_s, l_s, acc_s = refs
+        qi, si = pl.program_id(1), pl.program_id(2)
+        batched = ((0,), (0,))
+
+        @pl.when(si == 0)
+        def _():
+            if sink:
+                m_s[...] = jnp.broadcast_to(sink_ref[...], m_s.shape)
+                l_s[...] = jnp.ones_like(l_s)
+            else:
+                m_s[...] = jnp.full_like(m_s, -jnp.inf)
+                l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        apart = walk.reach - si  # tiles between the keys' and the queries'
+
+        @pl.when(qi >= apart)
+        def _():
+            s = dot(q_ref[...], k_ref[...], (((2,), (2,)), batched)) * scale
+            r = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+            c = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+            ahead = r - c + apart * blk
+            s = jnp.where(jnp.logical_and(ahead >= 0, ahead < window)[None],
+                          s, _MASKED)
+            m = m_s[...]
+            m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - across(m_new, blk))
+            l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=2, keepdims=True)
+            acc_s[...] = across(alpha, dv) * acc_s[...] + dot(
+                p.astype(v_ref.dtype), v_ref[...], (((2,), (1,)), batched))
+            m_s[...] = m_new
+
+        @pl.when(si == n_s - 1)
+        def _():
+            o_ref[...] = (acc_s[...] / across(l_s[...], dv)).astype(
+                o_ref.dtype)
+            lse_ref[...] = (m_s[...] + jnp.log(l_s[...]))[..., :1]
+
+    def band_fwd(q, k, v, *sinks):
+        bh = q.shape[0]
+        hb = max(h for h in range(1, _BAND_HEADS + 1) if bh % h == 0)
+
+        def rows(width, tile):
+            return pl.BlockSpec((hb, blk, width),
+                                lambda b, i, s: (b, tile(i, s), 0))
+
+        def keys(width):
+            return rows(width, lambda i, s: jnp.maximum(i - walk.reach + s,
+                                                        0))
+
+        def queries(width):
+            return rows(width, lambda i, s: i)
+
+        # a head's sink, (BH,) float32, as a row of the running maximum
+        o, lse = pl.pallas_call(
+            band_fwd_kernel, grid=(bh // hb, n, n_s),
+            in_specs=[queries(dk), keys(dk), keys(dv)] + [pl.BlockSpec(
+                (hb, 1, lanes), lambda b, i, s: (b, 0, 0))] * len(sinks),
+            out_specs=[queries(dv), queries(1)],
+            out_shape=[jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
+                       jax.ShapeDtypeStruct((bh, t, 1), f32)],
+            scratch_shapes=[pltpu.VMEM((hb, blk, lanes), f32),
+                            pltpu.VMEM((hb, blk, lanes), f32),
+                            pltpu.VMEM((hb, blk, dv), f32)],
+            name="mx_attention_window_fwd", **kw)(
+                q, k, v, *(jnp.broadcast_to(z.astype(f32)[:, None, None],
+                                            (bh, 1, lanes)) for z in sinks))
         return o, lse[..., 0]
 
     # ---- backward: dQ ----------------------------------------------------
@@ -383,7 +526,7 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
 
         on_stretch(qi, si, update)
 
-        @pl.when(si == (walk.diagonal(qi)[0] if causal else n_s - 1))
+        @pl.when(si == last(qi))
         def _():
             dq_ref[0] = acc_s[...].astype(dq_ref.dtype)
 
@@ -392,7 +535,7 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
                    dv_ref, dk_s, dv_s):
         ki, si = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(si == (walk.diagonal(ki)[0] if causal else 0))
+        @pl.when(si == (walk.diagonal(ki)[0] if causal and not band else 0))
         def _():
             dk_s[...] = jnp.zeros_like(dk_s)
             dv_s[...] = jnp.zeros_like(dv_s)
@@ -430,7 +573,7 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
             out_specs=own(dk),
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             scratch_shapes=[pltpu.VMEM((blk, dk), f32)],
-            name="mx_attention_dq", **kw)(
+            name=name + "dq", **kw)(
                 q, k, v, do, lse[..., None], delta[..., None])
         row = walked((1, 1, walk.stretch), lambda b, s: (b, 0, s), True)
         dk_, dv_ = pl.pallas_call(
@@ -442,14 +585,15 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk):
                        jax.ShapeDtypeStruct(v.shape, v.dtype)],
             scratch_shapes=[pltpu.VMEM((blk, dk), f32),
                             pltpu.VMEM((blk, dv), f32)],
-            name="mx_attention_dkv", **kw)(
+            name=name + "dkv", **kw)(
                 q, k, v, do, lse[:, None, :], delta[:, None, :])
         return dq, dk_, dv_
 
-    return fwd, bwd
+    return (band_fwd if band else fwd), bwd
 
 
-def blocked_attention(q, k, v, causal: bool = True, scale=None):
+def blocked_attention(q, k, v, causal: bool = True, scale=None, window=None,
+                      sink=None):
     """Softmax attention blocked over queries and keys in both passes.
 
     q, k: (BH, T, dk); v: (BH, T, dv) -> (BH, T, dv). Differentiable; the
@@ -457,30 +601,51 @@ def blocked_attention(q, k, v, causal: bool = True, scale=None):
     probabilities block by block. Under ``jax.checkpoint`` with
     ``save_only_these_names("mx.attention")`` those two are what a layer
     keeps, so a recomputed layer does not run the forward kernel again.
+
+    ``window``: query i attends to keys i - window < j <= i alone (causal
+    only); the kernels then visit only the key tiles that meet that band
+    (``_Band``) and are named ``mx_attention_window_fwd``, ``_dq``,
+    ``_dkv``. ``sink`` (with a window): (BH,) logits, one a row of BH, of a
+    key with no value, ``p_ij = exp(l_ij) / (exp(sink) + sum_j'
+    exp(l_ij'))``; its gradient is ``-sum_i p_sink,i (do_i . o_i)``.
     """
     import jax
+    import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
 
     _, t, dk = q.shape
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
     sc = float(scale) if scale is not None else 1.0 / math.sqrt(dk)
+    if sink is not None and window is None:
+        raise ValueError("a sink comes with a window")
     fwd, bwd = _build_blocked_attention(
-        t, dk, v.shape[-1], bool(causal), sc, str(q.dtype), _interpret_for(q))
+        t, dk, v.shape[-1], bool(causal), sc, str(q.dtype), _interpret_for(q),
+        None if window is None else int(window), sink is not None)
+    sinks = () if sink is None else (sink,)
 
     @jax.custom_vjp
-    def op(q, k, v):
-        return fwd(q, k, v)[0]
+    def op(q, k, v, *sinks):
+        return fwd(q, k, v, *sinks)[0]
 
-    def op_fwd(q, k, v):
-        o, lse = fwd(q, k, v)
+    def op_fwd(q, k, v, *sinks):
+        o, lse = fwd(q, k, v, *sinks)
         o = checkpoint_name(o, "mx.attention")
         lse = checkpoint_name(lse, "mx.attention")
-        return o, (q, k, v, o, lse)
+        return o, (q, k, v, o, lse) + sinks
 
     def op_bwd(res, do):
-        return bwd(*res, do)
+        grads = bwd(*res[:5], do)
+        if not sinks:
+            return grads
+        o, lse, sink = res[3:]
+        f32 = jnp.float32
+        delta = jnp.sum(o.astype(f32) * do.astype(f32), axis=-1)
+        taken = jnp.exp(sink.astype(f32)[:, None] - lse)       # p_sink
+        return grads + (-jnp.sum(taken * delta, axis=1).astype(sink.dtype),)
 
     op.defvjp(op_fwd, op_bwd)
-    return op(q, k, v)
+    return op(q, k, v, *sinks)
 
 
 @register("_contrib_blocked_attention")

@@ -181,20 +181,24 @@ def init_dropless_moe_params(key, d_model: int, d_ff: int, n_experts: int,
     """``dropless_moe_ffn``'s parameters: a router over all ``n_experts``, a
     selection bias (no gradient), expert weights for the experts held (all
     of them by default; gated for ``swiglu``, one product in for ``relu2``)
-    and one shared expert of width ``shared_ff`` (``d_ff`` by default)."""
+    and one shared expert of width ``shared_ff`` (``d_ff`` by default; 0:
+    none, and no ``shared_in``/``shared_out``)."""
     import jax
     import jax.numpy as jnp
     held = n_experts if experts_held is None else len(experts_held)
     gated = activation == "swiglu"
     k_gate, k_ffn, k_shared = jax.random.split(key, 3)
-    shared = init_expert_ffn(k_shared, 1, d_model, shared_ff or d_ff, gated,
-                             dtype)
-    return {
+    params = {
         "gate": init_router(k_gate, d_model, n_experts),
         "bias": jnp.zeros((n_experts,), jnp.float32),
         **init_expert_ffn(k_ffn, held, d_model, d_ff, gated, dtype),
-        "shared_in": shared["w_in"][0], "shared_out": shared["w_out"][0],
     }
+    width = d_ff if shared_ff is None else shared_ff
+    if width:
+        shared = init_expert_ffn(k_shared, 1, d_model, width, gated, dtype)
+        params.update(shared_in=shared["w_in"][0],
+                      shared_out=shared["w_out"][0])
+    return params
 
 
 def route_topk(x, gate, bias, k: int, scaling: float = 1.0):
@@ -361,7 +365,8 @@ def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
     experts THAT ARE HELD HERE (``experts_held``: their ids among the
     router's, each once; None: all, the whole layer). ``E`` is a SwiGLU
     (``w_in`` holds gate and up, 2F wide) or, with ``activation="relu2"``,
-    ``relu(x w_in)^2 w_out``; the shared expert's width is its weights' own.
+    ``relu(x w_in)^2 w_out``; the shared expert's width is its weights' own,
+    and a layer whose ``params`` hold no ``shared_in`` has none.
     Routing is over all of the router's experts and drops nothing, whatever
     it sends here: the pairs held here are sorted by expert into whole tiles
     of ``tile`` rows, and ONE loop with a traced trip count runs over the
@@ -401,7 +406,10 @@ def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
         y = _routed_experts_op(act, tile)(
             xf, params["w_in"], params["w_out"], w, pair, tile_group,
             n_active)
-        shared = ffn(xf, params["shared_in"], params["shared_out"])
+        shared = ffn(xf, params["shared_in"], params["shared_out"]) \
+            if "shared_in" in params else None
     stats = {"load": load.astype(jnp.int32),
              "tokens_here": here.astype(jnp.int32), "tiles_run": n_active}
-    return (y + shared).reshape(b, t, d), stats
+    if shared is not None:
+        y = y + shared
+    return y.reshape(b, t, d), stats

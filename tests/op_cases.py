@@ -1056,6 +1056,8 @@ COVERED_ELSEWHERE = {
     # the olmo_hybrid family's mixers (ops/lm_ops.py)
     **{op: "tests/test_olmo_hybrid.py" for op in [
         "_contrib_gated_deltanet_mixer", "_contrib_qk_norm_attention"]},
+    # the mimo_v2 family's attention (ops/lm_ops.py)
+    "_contrib_fused_qkv_attention": "tests/test_mimo_v2.py",
     # pallas fused conv epilogues (fwd+grad parity, fallback, fold)
     **{op: "tests/test_fused_epilogue.py" for op in [
         "_contrib_fused_bn_relu", "_contrib_fused_bn_add_relu"]},

@@ -150,6 +150,32 @@ def test_blocked_attention_compiles_for_v5e(one_chip, bh, t, dk, dv, dtype,
     assert _n_kernels(jax.jit(bwd).lower(q, q, v, v, lse, v).compile()) == 2
 
 
+@pytest.mark.parametrize("bh,t", [(64, 32768), (8, 2048)])
+def test_window_attention_with_a_sink_compiles_for_v5e(one_chip, bh, t):
+    """The window variant's three kernels at MiMo-V2.5's window layers (64
+    query heads, q and k 192 wide, v 128, a window of 128 keys with a sink
+    logit a head, 32,768 tokens): tiles of 128 rows, two grid steps a block,
+    and the sink's row of the running maximum lower and fit VMEM, under the
+    window's own kernel names."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    dk, dv = 192, 128
+    fwd, bwd = pk._build_blocked_attention(t, dk, dv, True, dk ** -0.5,
+                                           "bfloat16", False, 128, True)
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, v = sds(bh, t, dk), sds(bh, t, dv)
+    compiled = jax.jit(fwd).lower(q, q, v, sds(bh, dtype=jnp.float32)) \
+        .compile()
+    assert _n_kernels(compiled) == 1
+    assert "mx_attention_window_fwd" in compiled.as_text()
+    compiled = jax.jit(bwd).lower(q, q, v, v, sds(bh, t, dtype=jnp.float32),
+                                  v).compile()
+    assert _n_kernels(compiled) == 2
+    assert "mx_attention_window_dkv" in compiled.as_text()
+
+
 def test_delta_rule_forward_compiles_for_v5e_as_one_kernel(one_chip,
                                                            monkeypatch):
     """The chunked delta rule's forward at Olmo-Hybrid-7B's widths (30
