@@ -407,26 +407,22 @@ def gqa_attention(x, w_q, w_k, w_v, w_o, heads=1, kv_heads=1, head_dim=1):
     """Causal grouped-KV attention over x (B, T, D), no position embedding:
     ``heads`` query heads, query head ``h`` reads KV head ``h // (heads /
     kv_heads)``; softmax of ``q.k / sqrt(head_dim)``. The attention kernels
-    (``blocked_attention``) see one key and value head a query head: the KV
-    heads are repeated on the way in, and their gradients summed over the
-    group on the way back by the repeat's transpose."""
+    (``blocked_attention``) take the keys and values at the KV heads' count
+    and read a group's KV head for each of its query heads; they sum its
+    gradients over the group."""
     import jax
     import jax.numpy as jnp
     from .pallas_kernels import blocked_attention
     bsz, t, _ = x.shape
-    rep = heads // kv_heads
 
-    def split(y, n):                              # (B, T, n*d) -> (B, n, T, d)
-        return y.reshape(bsz, t, n, head_dim).transpose(0, 2, 1, 3)
+    def split(y, n):                      # (B, T, n*d) -> (B*n, T, d)
+        return y.reshape(bsz, t, n, head_dim).transpose(0, 2, 1, 3) \
+            .reshape(bsz * n, t, head_dim)
 
     with jax.named_scope("mx.gqa"):
-        q = split(jnp.dot(x, w_q), heads)
-        k = jnp.repeat(split(jnp.dot(x, w_k), kv_heads), rep, axis=1)
-        v = jnp.repeat(split(jnp.dot(x, w_v), kv_heads), rep, axis=1)
         o = blocked_attention(
-            q.reshape(bsz * heads, t, head_dim),
-            k.reshape(bsz * heads, t, head_dim),
-            v.reshape(bsz * heads, t, head_dim), causal=True,
+            split(jnp.dot(x, w_q), heads), split(jnp.dot(x, w_k), kv_heads),
+            split(jnp.dot(x, w_v), kv_heads), causal=True,
             scale=head_dim ** -0.5)
         o = o.reshape(bsz, heads, t, head_dim).transpose(0, 2, 1, 3)
         return jnp.dot(o.reshape(bsz, t, heads * head_dim), w_o)
@@ -474,13 +470,13 @@ def fused_qkv_attention(x, w_qkv, w_o, *sink, heads=1, kv_heads=1,
     denominator; ``o = value_scale * sum_j p_ij v_j``; ``w_o`` from heads x
     v_dim back to D. A window layer runs under the scope ``mx.swa``, a full
     one under ``mx.full_attn``. The kernels (``blocked_attention``, which
-    walks only the band's tiles under a window) see the KV heads repeated
-    to the query heads' count."""
+    walks only the band's tiles under a window) take the keys and values at
+    the KV heads' count and read a group's KV head for each of its query
+    heads."""
     import jax
     import jax.numpy as jnp
     from .pallas_kernels import blocked_attention
     bsz, t, _ = x.shape
-    rep = heads // kv_heads
     n_q, n_k = heads * qk_dim, kv_heads * qk_dim
 
     def split(y, n, d):                       # (B, T, n*d) -> (B, n, T, d)
@@ -492,11 +488,10 @@ def fused_qkv_attention(x, w_qkv, w_o, *sink, heads=1, kv_heads=1,
         k = rope(split(qkv[..., n_q:n_q + n_k], kv_heads, qk_dim), theta,
                  rope_dim)
         v = split(qkv[..., n_q + n_k:], kv_heads, v_dim)
-        k, v = (jnp.repeat(z, rep, axis=1) for z in (k, v))
         o = blocked_attention(
             q.reshape(bsz * heads, t, qk_dim),
-            k.reshape(bsz * heads, t, qk_dim),
-            v.reshape(bsz * heads, t, v_dim), causal=True,
+            k.reshape(bsz * kv_heads, t, qk_dim),
+            v.reshape(bsz * kv_heads, t, v_dim), causal=True,
             scale=qk_dim ** -0.5, window=window,
             sink=jnp.tile(sink[0].astype(jnp.float32), bsz) if sink else None)
         o = o.reshape(bsz, heads, t, v_dim).transpose(0, 2, 1, 3) \

@@ -143,7 +143,10 @@ def _flash_attention_op(q, k, v, causal=False, scale=None):
 # (logits transposed, so the saved log-sum-exp broadcasts as a row), dQ
 # walks the key tiles for one query block. Query/key head size and value
 # head size may differ (MLA: 256/256, with the 64 rope dimensions shared by
-# all heads already concatenated).
+# all heads already concatenated). Keys and values may have fewer rows of BH
+# than the queries (grouped KV heads): a query row reads its group's KV row
+# through the index maps, and no KV head is ever copied out to the queries'
+# count.
 # ---------------------------------------------------------------------------
 
 _MASKED = -0.7 * 3.0e38  # not -inf: exp(-inf - -inf) is NaN
@@ -203,7 +206,8 @@ class _Band(NamedTuple):
     operand: the tiles from ``reach`` tiles before its own up to its own,
     so a block takes ``reach + 1`` grid steps, whatever T is. Every visited
     tile is masked on both of the band's edges. The forward's grid step
-    takes ``_BAND_HEADS`` rows of BH at once, the backward's one."""
+    takes up to ``_BAND_HEADS`` rows of BH at once, whole KV groups or a
+    part of one, the backward's one."""
     block: int
     reach: int
 
@@ -244,10 +248,11 @@ def _attention_walk(t: int, dk: int, dv: int, itemsize: int,
 def _build_blocked_attention(t: int, dk: int, dv: int, causal: bool,
                              scale: float, dtype: str, interpret: bool,
                              window=None, sink: bool = False):
-    """(fwd, bwd) over (BH, T, dk) queries and keys and (BH, T, dv)
-    values. ``fwd(q, k, v[, sink]) -> (o, lse)`` with ``lse`` (BH, T)
-    float32 (the sink's term included); ``bwd(q, k, v, o, lse, do) -> (dq,
-    dk, dv)``. ``window``: causal attention to the last ``window`` keys,
+    """(fwd, bwd) over (BH, T, dk) queries, (BH_kv, T, dk) keys and
+    (BH_kv, T, dv) values, BH_kv dividing BH: query row ``r`` reads KV row
+    ``r // (BH / BH_kv)``. ``fwd(q, k, v[, sink]) -> (o, lse)`` with ``lse``
+    (BH, T) float32 (the sink's term included); ``bwd(q, k, v, o, lse, do)
+    -> (dq, dk, dv)``. ``window``: causal attention to the last ``window`` keys,
     on a ``_Band`` walk, under kernel names of its own; ``sink`` (a window
     only): ``fwd`` takes (BH,) float32 sink logits."""
     import jax.numpy as jnp
@@ -338,11 +343,18 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
             pl.when(jnp.logical_and(s == s_d, place == at))(
                 lambda at=at: body(walk.visits(at, keys_own)))
 
+    def kv_rows(q, k):
+        """(rep, the KV row of query row b): ``rep`` query rows share a KV
+        row, as ``jnp.repeat(k, rep, axis=0)`` would lay them out. At rep 1
+        the index maps are the identity's."""
+        rep = q.shape[0] // k.shape[0]
+        return rep, (lambda b: b) if rep == 1 else (lambda b: b // rep)
+
     # blocks named by (head, own block, stretch); the stretch is clamped to
     # the causal range (on a band, to the sequence) so a dead step copies
     # nothing
-    def own(width):
-        return pl.BlockSpec((1, blk, width), lambda b, i, s: (b, i, 0))
+    def own(width, head=lambda b: b):
+        return pl.BlockSpec((1, blk, width), lambda b, i, s: (head(b), i, 0))
 
     def walked(shape, index, keys_own=False):
         if band:
@@ -358,9 +370,9 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
         """The grid step that finishes own block ``i`` (queries own)."""
         return walk.diagonal(i)[0] if causal and not band else n_s - 1
 
-    def walked_rows(width, keys_own=False):
-        return walked((1, walk.stretch, width), lambda b, s: (b, s, 0),
-                      keys_own)
+    def walked_rows(width, keys_own=False, head=lambda b: b):
+        return walked((1, walk.stretch, width),
+                      lambda b, s: (head(b), s, 0), keys_own)
 
     column = pl.BlockSpec((1, blk, 1), lambda b, i, s: (b, i, 0))
     params = None if interpret else pltpu.CompilerParams(
@@ -413,9 +425,11 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
 
     def fwd(q, k, v):
         bh = q.shape[0]
+        _, kv = kv_rows(q, k)
         o, lse = pl.pallas_call(
             fwd_kernel, grid=(bh, n, n_s),
-            in_specs=[own(dk), walked_rows(dk), walked_rows(dv)],
+            in_specs=[own(dk), walked_rows(dk, head=kv),
+                      walked_rows(dv, head=kv)],
             out_specs=[own(dv), column],
             out_shape=[jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
                        jax.ShapeDtypeStruct((bh, t, 1), f32)],
@@ -433,6 +447,18 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
             o_ref, lse_ref, m_s, l_s, acc_s = refs
         qi, si = pl.program_id(1), pl.program_id(2)
         batched = ((0,), (0,))
+        # the step's query rows in groups of ``per_kv`` that share a KV
+        # tile: a group's products with it are one product, its rows stacked
+        groups = k_ref.shape[0]
+        per_kv = q_ref.shape[0] // groups
+
+        def stacked(x):           # (hb, blk, w) -> (groups, per_kv blk, w)
+            return x if per_kv == 1 else x.reshape(groups, per_kv * blk,
+                                                   x.shape[2])
+
+        def unstacked(x):         # and back
+            return x if per_kv == 1 else x.reshape(groups * per_kv, blk,
+                                                   x.shape[2])
 
         @pl.when(si == 0)
         def _():
@@ -448,7 +474,8 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
 
         @pl.when(qi >= apart)
         def _():
-            s = dot(q_ref[...], k_ref[...], (((2,), (2,)), batched)) * scale
+            s = unstacked(dot(stacked(q_ref[...]), k_ref[...],
+                              (((2,), (2,)), batched))) * scale
             r = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
             c = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
             ahead = r - c + apart * blk
@@ -459,8 +486,9 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - across(m_new, blk))
             l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=2, keepdims=True)
-            acc_s[...] = across(alpha, dv) * acc_s[...] + dot(
-                p.astype(v_ref.dtype), v_ref[...], (((2,), (1,)), batched))
+            acc_s[...] = across(alpha, dv) * acc_s[...] + unstacked(dot(
+                stacked(p.astype(v_ref.dtype)), v_ref[...],
+                (((2,), (1,)), batched)))
             m_s[...] = m_new
 
         @pl.when(si == n_s - 1)
@@ -471,18 +499,21 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
 
     def band_fwd(q, k, v, *sinks):
         bh = q.shape[0]
-        hb = max(h for h in range(1, _BAND_HEADS + 1) if bh % h == 0)
-
-        def rows(width, tile):
-            return pl.BlockSpec((hb, blk, width),
-                                lambda b, i, s: (b, tile(i, s), 0))
+        rep = bh // k.shape[0]
+        # a step's rows are whole KV groups or a part of one, so that its
+        # keys and values are whole rows of theirs
+        hb = max(h for h in range(1, _BAND_HEADS + 1)
+                 if bh % h == 0 and (rep % h == 0 or h % rep == 0))
+        steps = max(1, rep // hb)                  # grid steps a KV row
+        kv = (lambda b: b) if steps == 1 else (lambda b: b // steps)
 
         def keys(width):
-            return rows(width, lambda i, s: jnp.maximum(i - walk.reach + s,
-                                                        0))
+            return pl.BlockSpec((max(1, hb // rep), blk, width),
+                                lambda b, i, s: (kv(b), jnp.maximum(
+                                    i - walk.reach + s, 0), 0))
 
         def queries(width):
-            return rows(width, lambda i, s: i)
+            return pl.BlockSpec((hb, blk, width), lambda b, i, s: (b, i, 0))
 
         # a head's sink, (BH,) float32, as a row of the running maximum
         o, lse = pl.pallas_call(
@@ -565,28 +596,34 @@ def _attention_passes(t, dk, dv, causal, scale, interpret, walk, window=None,
 
     def bwd(q, k, v, o, lse, do):
         bh = q.shape[0]
+        rep, kv = kv_rows(q, k)
         delta = jnp.sum(o.astype(f32) * do.astype(f32), axis=-1)   # (BH, T)
         dq = pl.pallas_call(
             dq_kernel, grid=(bh, n, n_s),
-            in_specs=[own(dk), walked_rows(dk), walked_rows(dv), own(dv),
-                      column, column],
+            in_specs=[own(dk), walked_rows(dk, head=kv),
+                      walked_rows(dv, head=kv), own(dv), column, column],
             out_specs=own(dk),
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             scratch_shapes=[pltpu.VMEM((blk, dk), f32)],
             name=name + "dq", **kw)(
                 q, k, v, do, lse[..., None], delta[..., None])
         row = walked((1, 1, walk.stretch), lambda b, s: (b, 0, s), True)
+        # a query row's share of its KV row's gradients, summed over the
+        # group below, as the transpose of a repeat of the KV rows sums them
         dk_, dv_ = pl.pallas_call(
             dkv_kernel, grid=(bh, n, n_s),
-            in_specs=[walked_rows(dk, True), own(dk), own(dv),
+            in_specs=[walked_rows(dk, True), own(dk, kv), own(dv, kv),
                       walked_rows(dv, True), row, row],
             out_specs=[own(dk), own(dv)],
-            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            out_shape=[jax.ShapeDtypeStruct((bh, t, dk), k.dtype),
+                       jax.ShapeDtypeStruct((bh, t, dv), v.dtype)],
             scratch_shapes=[pltpu.VMEM((blk, dk), f32),
                             pltpu.VMEM((blk, dv), f32)],
             name=name + "dkv", **kw)(
                 q, k, v, do, lse[:, None, :], delta[:, None, :])
+        if rep > 1:
+            dk_, dv_ = (z.reshape(-1, rep, t, z.shape[2]).sum(axis=1)
+                        for z in (dk_, dv_))
         return dq, dk_, dv_
 
     return (band_fwd if band else fwd), bwd
@@ -596,7 +633,12 @@ def blocked_attention(q, k, v, causal: bool = True, scale=None, window=None,
                       sink=None):
     """Softmax attention blocked over queries and keys in both passes.
 
-    q, k: (BH, T, dk); v: (BH, T, dv) -> (BH, T, dv). Differentiable; the
+    q: (BH, T, dk); k: (BH_kv, T, dk); v: (BH_kv, T, dv) -> (BH, T, dv).
+    BH_kv divides BH, and query row ``r`` reads KV row ``r // (BH /
+    BH_kv)``: for rows ``b * heads + h`` and ``b * kv_heads + g``, query
+    head ``h`` reads KV head ``h // (heads / kv_heads)``, with no copy of a
+    KV head made (the kernels' index maps name the group's row; ``dk`` and
+    ``dv`` are summed over the group). Differentiable; the
     backward keeps the output and the (BH, T) log-sum-exp and recomputes the
     probabilities block by block. Under ``jax.checkpoint`` with
     ``save_only_these_names("mx.attention")`` those two are what a layer
@@ -613,7 +655,10 @@ def blocked_attention(q, k, v, causal: bool = True, scale=None, window=None,
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
 
-    _, t, dk = q.shape
+    bh, t, dk = q.shape
+    if k.shape[0] != v.shape[0] or bh % k.shape[0]:
+        raise ValueError(f"{bh} query rows cannot share {k.shape[0]} key "
+                         f"and {v.shape[0]} value rows in equal groups")
     if window is not None and not causal:
         raise ValueError("a window is causal")
     sc = float(scale) if scale is not None else 1.0 / math.sqrt(dk)

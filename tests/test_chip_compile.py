@@ -176,6 +176,65 @@ def test_window_attention_with_a_sink_compiles_for_v5e(one_chip, bh, t):
     assert "mx_attention_window_dkv" in compiled.as_text()
 
 
+@pytest.mark.parametrize("bh,kv,t,dk,dv,window", [
+    (64, 8, 32768, 192, 128, 128),   # MiMo-V2.5's window layers, a sink
+    (64, 4, 32768, 192, 128, None),  # its full layers
+    (32, 2, 8192, 128, 128, None),   # Nemotron-3-Nano's attention, trained
+])
+def test_attention_kernels_compile_for_v5e_with_kv_at_the_kv_heads(
+        one_chip, bh, kv, t, dk, dv, window):
+    """The kernels as the grouped-KV layers hand them K and V, at the KV
+    heads' rows: forward and backward lower and fit VMEM with a grid step's
+    query rows reading their group's KV row."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    fwd, bwd = pk._build_blocked_attention(t, dk, dv, True, dk ** -0.5,
+                                           "bfloat16", False, window,
+                                           window is not None)
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, k, v, o = sds(bh, t, dk), sds(kv, t, dk), sds(kv, t, dv), sds(bh, t, dv)
+    sinks = (sds(bh, dtype=jnp.float32),) if window else ()
+    assert _n_kernels(jax.jit(fwd).lower(q, k, v, *sinks).compile()) == 1
+    compiled = jax.jit(bwd).lower(q, k, v, o, sds(bh, t, dtype=jnp.float32),
+                                  o).compile()
+    assert _n_kernels(compiled) == 2
+
+
+@pytest.mark.parametrize("kv,window", [(8, 128), (4, None)])
+def test_mimo_attention_layers_compile_for_v5e_with_no_kv_head_copied(
+        one_chip, monkeypatch, kv, window):
+    """MiMo-V2.5's window and full attention layers (``fused_qkv_attention``
+    at 32,768 tokens, 64 query heads on 8 or 4 KV heads) as compiled for a
+    v5e: the kernel takes K and V at the KV heads, and no array holds them
+    copied out to 64 heads (a repeat's ``bf16[8,8,32768,192]``, 1.6 GB of
+    such copies a layer). (A tracer lowers for the CPU: the kernel is told
+    it is on the chip.)"""
+    import re
+    from mxnet_tpu.ops import lm_ops, pallas_kernels as pk
+    monkeypatch.setattr(pk, "_interpret_for", lambda x: False)
+    t, d, heads, qk, vd = 32768, 4096, 64, 192, 128
+    rep = heads // kv
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(1, t, d), sds(d, (heads + kv) * qk + kv * vd),
+            sds(heads * vd, d)) + ((sds(heads, dtype=jnp.float32),)
+                                   if window else ())
+    text = jax.jit(lambda *a: lm_ops.fused_qkv_attention(
+        *a, heads=heads, kv_heads=kv, qk_dim=qk, v_dim=vd, rope_dim=64,
+        window=window, value_scale=0.707)).lower(*args).compile().as_text()
+    kernel = [line for line in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernel) == 1
+    operands = kernel[0].split("operand_layout_constraints=")[1]
+    assert re.findall(r"bf16\[([0-9,]+)\]", operands)[:3] == [
+        f"{heads},{t},{qk}", f"{kv},{t},{qk}", f"{kv},{t},{vd}"]
+    assert not re.search(rf"\[{kv},{rep},{t},", text)
+
+
 def test_delta_rule_forward_compiles_for_v5e_as_one_kernel(one_chip,
                                                            monkeypatch):
     """The chunked delta rule's forward at Olmo-Hybrid-7B's widths (30

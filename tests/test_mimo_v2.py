@@ -1,6 +1,7 @@
 """The ``mimo_v2`` language model (MiMo-V2.5's) at a toy size on the CPU:
 the blocked attention's window-and-sink variant against a plain masked
-softmax, the Gluon block against the plain reference of the benchmark
+softmax and, with grouped KV rows, against the KV rows repeated, the Gluon
+block against the plain reference of the benchmark
 (``benchmark/chip/models/mimo_v2_5.py``), the expert layer without a shared
 expert and its share over sixteen ranks, what the other models' attention
 and expert layer keep, the benchmark's configuration, its FLOPs and its
@@ -9,6 +10,8 @@ kernel's costs, and a rehearsal of the benchmark's cell.
 Ops exercised here (tests/op_cases.py COVERED_ELSEWHERE):
 _contrib_fused_qkv_attention.
 """
+import base64
+import hashlib
 import importlib.util
 import json
 import os
@@ -28,6 +31,7 @@ from mxnet_tpu.gluon.model_zoo.text import config_keys
 from mxnet_tpu.ndarray.ndarray import from_jax
 from mxnet_tpu.ops import lm_ops
 from mxnet_tpu.ops.pallas_kernels import (_attention_walk, _Band,
+                                          _build_blocked_attention,
                                           blocked_attention)
 from mxnet_tpu.parallel import moe
 
@@ -105,6 +109,54 @@ def test_window_and_sink_match_a_masked_softmax(bh, t, dk, dv, window):
     assert float(jnp.abs(got[3]).max()) > 1e-2   # the sink does take mass
 
 
+@pytest.mark.parametrize("kv_rows,rep,window", [
+    (3, 1, 16),     # a KV row a query row
+    (8, 2, 16),     # 16 query rows, a grid step 8 of them: four groups a step
+    (2, 3, 130),    # 6 query rows, a step two groups of three
+    (2, 8, 16),     # a step is one group, as in MiMo's window layers (64 on 8)
+    (1, 16, 5),     # a group is two steps
+])
+def test_grouped_kv_rows_give_what_repeated_rows_give(kv_rows, rep, window):
+    """The window's kernels with K and V at their KV rows against the same
+    kernels handed each KV row repeated for its group: the output, the
+    log-sum-exp and the gradients of q, k, v and the sink."""
+    t, dk, dv = 256, 24, 16
+    bh = kv_rows * rep
+    ks = jax.random.split(jax.random.PRNGKey(rep), 5)
+    q = jax.random.normal(ks[0], (bh, t, dk))
+    k = jax.random.normal(ks[1], (kv_rows, t, dk))
+    v = jax.random.normal(ks[2], (kv_rows, t, dv))
+    g = jax.random.normal(ks[3], (bh, t, dv))
+    sink = 1.0 + jax.random.normal(ks[4], (bh,))
+
+    def repeated(z):
+        return jnp.repeat(z, rep, axis=0)
+
+    fwd, _ = _build_blocked_attention(t, dk, dv, True, dk ** -0.5,
+                                      "float32", True, window, True)
+    for got, want in zip(fwd(q, k, v, sink),
+                         fwd(q, repeated(k), repeated(v), sink)):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+    def loss(q, k, v, sink, kv=lambda z: z):
+        return jnp.sum(g * blocked_attention(q, kv(k), kv(v), window=window,
+                                             sink=sink))
+
+    got = jax.grad(loss, (0, 1, 2, 3))(q, k, v, sink)
+    want = jax.grad(loss, (0, 1, 2, 3))(q, k, v, sink, repeated)
+    for name, a, b in zip(("q", "k", "v", "sink"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+def test_kv_rows_that_do_not_divide_the_query_rows_are_refused():
+    q, k = jnp.zeros((6, 256, 16)), jnp.zeros((4, 256, 16))
+    with pytest.raises(ValueError):
+        blocked_attention(q, k, k)
+    with pytest.raises(ValueError):
+        blocked_attention(q, k[:3], k[:2])
+
+
 @pytest.mark.parametrize("window", [256, 1000])
 def test_a_window_as_long_as_the_sequence_is_causal_attention(window):
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
@@ -146,12 +198,60 @@ def test_the_window_walk_visits_the_band_alone():
     assert _attention_walk(48, 16, 16, 4, window=7) == _Band(48, 0)
 
 
+def _tpu_text(fn, *args):
+    """What ``jax.jit(fn)`` lowers to for a TPU, each kernel's Mosaic module
+    printed in place of its serialized body without source locations
+    (those name this file's lines). Lowering for a TPU needs none: this
+    runs on the CPU."""
+    import re
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)) \
+        .as_text()
+
+    def printed(body):
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            return ir.Module.parse(base64.b64decode(body.group(1))) \
+                .operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]*)\\22', printed,
+                  text)
+
+
+# sha256 (first 16 digits) of ``_tpu_text`` of the forward and of the
+# backward at a KV row a query row, as they were before the kernels took K
+# and V at the KV heads: GLM's and Olmo's programs are those. A deliberate
+# change to a kernel rewrites them.
+TPU_TEXTS = {
+    (256, None): ("bf890550fc01ddb5", "faf120f52962dfc5"),
+    (256, 128): ("2b517ebc46c19eef", "c1ebaa58b9a20a74"),
+    (128, None): ("ed3b6010efaae735", "ebad386fa3707ad5"),
+    (128, 128): ("c5621467237e3867", "0fe2399558cfab2a"),
+}
+
+
 @pytest.mark.parametrize("t,dk,dv", [(8192, 256, 256), (8192, 128, 128)])
 def test_without_a_window_the_walk_and_the_kernels_are_as_they_were(t, dk,
                                                                      dv):
     """The GLM (MLA, 256), Nemotron and Olmo (128) shapes: the forward's
-    block of 512 and stretch of four tiles, one tile a step backward, and
-    the three kernels' names."""
+    block of 512 and stretch of four tiles, one tile a step backward, the
+    three kernels' names and, with as many KV rows as query rows, the
+    causal and the window's passes as lowered for a TPU (``TPU_TEXTS``)."""
+    bf = jnp.bfloat16
+    for window in (None, 128):
+        fwd, bwd = _build_blocked_attention(
+            t, dk, dv, True, dk ** -0.5, "bfloat16", False, window,
+            window is not None)
+        q, v = (jax.ShapeDtypeStruct((4, t, d), bf) for d in (dk, dv))
+        lse = jax.ShapeDtypeStruct((4, t), jnp.float32)
+        sinks = (jax.ShapeDtypeStruct((4,), jnp.float32),) if window else ()
+        texts = (_tpu_text(fwd, q, q, v, *sinks),
+                 _tpu_text(bwd, q, q, v, v, lse, v))
+        assert "mosaic" in texts[0] and "mosaic" in texts[1]
+        assert tuple(hashlib.sha256(x.encode()).hexdigest()[:16]
+                     for x in texts) == TPU_TEXTS[dk, window]
     assert _attention_walk(t, dk, dv, 2) == (512, 2048)
     assert _attention_walk(t, dk, dv, 2, backward=True) == (512, 512)
     q = jnp.zeros((1, 256, 16))

@@ -419,6 +419,41 @@ def test_grouped_kv_attention_matches_repeated_kv_heads(heads, kv):
         assert a.shape == b.shape and rel(a, b) < 2e-5
 
 
+@pytest.mark.parametrize("kv_rows,rep", [(3, 1), (2, 2), (2, 8), (1, 16)])
+def test_causal_kernels_read_a_kv_row_for_its_whole_group(kv_rows, rep):
+    """The causal kernels with K and V at their KV rows against the same
+    kernels handed each KV row repeated for its group: the output, the
+    log-sum-exp and the gradients of q, k and v, over two blocks of 512
+    (the stretch that holds the diagonal, and the backward's steps above
+    it, which copy nothing)."""
+    from mxnet_tpu.ops.pallas_kernels import (_build_blocked_attention,
+                                              blocked_attention)
+    t, dk, dv = 1024, 16, 8
+    bh = kv_rows * rep
+    ks = jax.random.split(jax.random.PRNGKey(rep), 4)
+    q = jax.random.normal(ks[0], (bh, t, dk))
+    k = jax.random.normal(ks[1], (kv_rows, t, dk))
+    v = jax.random.normal(ks[2], (kv_rows, t, dv))
+    g = jax.random.normal(ks[3], (bh, t, dv))
+
+    def repeated(z):
+        return jnp.repeat(z, rep, axis=0)
+
+    fwd, _ = _build_blocked_attention(t, dk, dv, True, dk ** -0.5,
+                                      "float32", True)
+    for got, want in zip(fwd(q, k, v), fwd(q, repeated(k), repeated(v))):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+    def loss(q, k, v, kv=lambda z: z):
+        return jnp.sum(g * blocked_attention(q, kv(k), kv(v)))
+
+    got = jax.grad(loss, (0, 1, 2))(q, k, v)
+    want = jax.grad(loss, (0, 1, 2))(q, k, v, repeated)
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
+
+
 # ---------------------------------------------------------------------------
 # the expert layer with squared-ReLU experts
 
