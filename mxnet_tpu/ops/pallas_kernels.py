@@ -894,6 +894,145 @@ def delta_rule(q, k, v, g, beta, chunk: int):
     return o[:, :, :t]
 
 
+# ---------------------------------------------------------------------------
+# The routed experts' combine (``parallel/moe.py:_add_rows``): a tile's rows
+# added into the rows of an (N, 1, D) float32 accumulator that stays in HBM,
+# by DMA. One grid step a tile: it starts a copy of every row it adds to,
+# waits on them all, adds, starts the copies back and waits on those, so the
+# step's fixed cost is paid once a tile and a tile's copies are in flight
+# together (XLA's scatter-add runs a row at a time: 0.40 us a row of 2,688
+# float32 on a v5e, PERF.md section 6). The accumulator is (N, 1, D)
+# and not (N, D): the TPU lays the first out in tiles of 1 x 128, so a row is
+# one run of memory that a DMA can take, and the second in tiles of 8 x 128,
+# whose single rows it refuses.
+# ---------------------------------------------------------------------------
+
+_COMBINE_ADD_ROWS = 8  # rows of the add a step: a row of vregs
+
+
+def moe_combine_fits(d: int, dtype) -> bool:
+    """Whether ``moe_combine`` takes rows D wide of ``dtype``."""
+    import jax.numpy as jnp
+    return jnp.dtype(dtype) == jnp.float32 and d % 128 == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _build_moe_combine(n: int, d: int, tile: int, interpret: bool):
+    """``call(index, acc, rows) -> acc``: index (tile,) int32, acc (n, 1, d)
+    float32 updated in place (aliased), rows (tile, d) float32."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    step = math.gcd(tile, _COMBINE_ADD_ROWS)
+
+    def kernel(index_ref, acc_ref, rows_ref, out_ref, buf, sem):
+        def copy(r, back):
+            row = pl.ds(index_ref[r], 1)
+            if back:
+                return pltpu.make_async_copy(buf.at[pl.ds(r, 1)],
+                                             out_ref.at[row], sem)
+            return pltpu.make_async_copy(acc_ref.at[row], buf.at[pl.ds(r, 1)],
+                                         sem)
+
+        def each_row(do):
+            """``do(r)`` for every row whose index is in range, in order:
+            the starts and the waits of one direction count the same
+            copies."""
+            def body(r, carry):
+                @pl.when(index_ref[r] < n)
+                def _():
+                    do(r)
+                return carry
+            jax.lax.fori_loop(0, tile, body, 0)
+
+        def add(j, carry):
+            rows = pl.ds(pl.multiple_of(j * step, step), step)
+            buf[rows, 0, :] = buf[rows, 0, :] + rows_ref[rows]
+            return carry
+
+        each_row(lambda r: copy(r, False).start())
+        each_row(lambda r: copy(r, False).wait())
+        jax.lax.fori_loop(0, tile // step, add, 0)
+        each_row(lambda r: copy(r, True).start())
+        each_row(lambda r: copy(r, True).wait())
+
+    kw = dict(interpret=interpret)
+    if not interpret:
+        # the rows and their copy in scratch, whole: 16 MB at 512 x 4,096
+        kw["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=2 * tile * d * 4 + 4 * 1024 * 1024)
+
+    def call(index, acc, rows):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                          pl.BlockSpec(memory_space=pltpu.VMEM)],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[pltpu.VMEM((tile, 1, d), f32),
+                                pltpu.SemaphoreType.DMA(())]),
+            out_shape=jax.ShapeDtypeStruct((n, 1, d), f32),
+            input_output_aliases={1: 0},
+            name="mx_moe_combine", **kw)(index, acc, rows)
+
+    return call
+
+
+@functools.lru_cache(maxsize=None)
+def _build_moe_zeros(n: int, d: int, interpret: bool):
+    """``call() -> zeros (n, 1, d) float32``, blocks of 256 rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    rows = math.gcd(n, 256)
+
+    def kernel(o_ref):
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    return pl.pallas_call(
+        kernel, grid=(n // rows,),
+        out_specs=pl.BlockSpec((rows, 1, d), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), jnp.float32),
+        name="mx_moe_zeros", interpret=interpret)
+
+
+def moe_zeros(n: int, d: int):
+    """The accumulator ``moe_combine`` adds into, (N, 1, D) float32 zeros.
+    XLA fills an array laid out in tiles of 1 x 128 at two thirds of the
+    rate of one in tiles of 8 x 128 (2.26 ms against 1.41 for 32,768 x
+    4,096 on a v5e, PERF.md section 6); this kernel fills it at the
+    latter's (1.47 ms)."""
+    return _build_moe_zeros(n, d, _interpret_for(None))()
+
+
+def moe_combine(acc, index, rows):
+    """``acc[index, 0] += rows`` for one tile of the routed experts' loop, by
+    row DMAs: acc (N, 1, D) float32, index (tile,) int, rows (tile, D)
+    float32, D a multiple of 128 (``moe_combine_fits``). An index of N or
+    more is a padding row and adds nothing, as XLA's scatter-add with
+    ``mode="drop"`` drops it. Keep ``acc`` (N, 1, D) from one tile to the
+    next: a reshape from (N, D) and back is a copy of the whole of it.
+
+    A tile's copies run at once, so no two of them may touch one row of
+    ``acc``: the indices of a tile must be unique (padding aside); their
+    order does not matter. The routed loop's are unique: a tile holds the
+    rows of one expert, and top-k names an expert at most once for a token,
+    so an expert has at most one row of a token. Tiles that share tokens run
+    one after the other, each tile's copies back done before the next
+    tile's start. So each row of ``acc`` gets the additions that XLA's
+    scatter-add makes, in the same order, and the sums are the same to the
+    bit."""
+    import jax.numpy as jnp
+    n, _, d = acc.shape
+    call = _build_moe_combine(n, d, rows.shape[0], _interpret_for(acc))
+    return call(index.astype(jnp.int32), acc, rows)
+
+
 @register("_contrib_interleaved_matmul_selfatt_qk")
 def _interleaved_qk(qkv, heads=1):
     """(ref: src/operator/contrib/transformer.cc interleaved matmul helpers)

@@ -26,9 +26,12 @@ of each tile) have a place for all N k (token, choice) pairs, so any routing
 fits; everything D or F wide has N rows (the input, the float32 accumulator
 of the result, their gradients) or the rows of one tile (the gathered
 tokens, the hidden activations, the expert's output), made and used inside
-the loop, so the routed path costs what was routed here. Both layers draw
-their router and their expert weights from ``init_router`` /
-``init_expert_ffn``.
+the loop, so the routed path costs what was routed here. A tile adds its
+rows into the accumulator with the kernel ``mx_moe_combine``: one grid step
+a tile, each row's copy in and back by DMA, all of the tile's in flight at
+once, into an accumulator kept (N, 1, D) for it; where D is not a multiple
+of 128, with XLA's scatter-add. Both layers draw their router and their
+expert weights from ``init_router`` / ``init_expert_ffn``.
 """
 from __future__ import annotations
 
@@ -236,12 +239,35 @@ def _take_rows(x, index):
     return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
 
 
+def _accumulator(n: int, d: int, dtype=None):
+    """Zeros (float32 by default) for ``_add_rows`` to add N rows D wide
+    into, which reshape to the (N, D) result: (N, 1, D) where the kernel
+    ``mx_moe_combine`` adds the rows (on the TPU a row of it is one run of
+    memory), (N, D) where XLA's scatter does."""
+    import jax.numpy as jnp
+    from ..ops.pallas_kernels import moe_combine_fits, moe_zeros
+    dtype = dtype or jnp.float32
+    if moe_combine_fits(d, dtype):
+        return moe_zeros(n, d)
+    return jnp.zeros((n, d), dtype)
+
+
 def _add_rows(acc, index, rows):
-    """acc[index] += rows; an index past the end (a padding row) is dropped.
-    XLA's scatter, which on a v5e takes 0.4 us for a row of 2,688 float32
-    and 1.0 us once it is told that the indices are sorted and unique
-    (PERF.md section 6, PR 34): so it is not told."""
-    return acc.at[index].add(rows, mode="drop")
+    """acc[index] += rows, the combine of a tile of the routed loop, into an
+    accumulator of ``_accumulator``; an index past the end (a padding row)
+    is dropped. One of (N, 1, D) takes the kernel ``mx_moe_combine``: one
+    grid step a tile, every row's copy in and back by DMA in flight at once
+    (``ops/pallas_kernels.py:moe_combine``). One of (N, D), where D is not a
+    multiple of 128 or the rows are not float32, takes XLA's scatter, which
+    on a v5e takes 0.4 us for a row of 2,688 float32 and 1.0 us once it is
+    told that the indices are sorted and unique (PERF.md section 6): so it
+    is not told. Under the scope ``mx.moe.combine`` either way."""
+    import jax
+    from ..ops.pallas_kernels import moe_combine
+    with jax.named_scope("mx.moe.combine"):
+        if acc.ndim == 3:
+            return moe_combine(acc, index, rows)
+        return acc.at[index].add(rows, mode="drop")
 
 
 def _dot(a, b, contract):
@@ -284,8 +310,8 @@ def _routed_experts_op(act, tile: int):
             return _add_rows(out, tokens,
                              scale * _dot(h, w_out[group], (1, 0)))
 
-        return jax.lax.fori_loop(0, n_active, body,
-                                 jnp.zeros(x.shape, f32)).astype(x.dtype)
+        acc = jax.lax.fori_loop(0, n_active, body, _accumulator(*x.shape))
+        return acc.reshape(x.shape).astype(x.dtype)
 
     def fwd(*args):
         return op(*args), args
@@ -314,11 +340,11 @@ def _routed_experts_op(act, tile: int):
 
         dx, dw_in, dw_out, dw = jax.lax.fori_loop(
             0, n_active, body,
-            (jnp.zeros(x.shape, f32), jnp.zeros(w_in.shape, f32),
+            (_accumulator(*x.shape), jnp.zeros(w_in.shape, f32),
              jnp.zeros(w_out.shape, f32), jnp.zeros((w.size,), f32)))
-        return (dx.astype(x.dtype), dw_in.astype(w_in.dtype),
-                dw_out.astype(w_out.dtype), dw.reshape(w.shape).astype(w.dtype),
-                None, None, None)
+        return (dx.reshape(x.shape).astype(x.dtype),
+                dw_in.astype(w_in.dtype), dw_out.astype(w_out.dtype),
+                dw.reshape(w.shape).astype(w.dtype), None, None, None)
 
     op.defvjp(fwd, bwd)
     return op
@@ -375,11 +401,12 @@ def dropless_moe_ffn(x, params: Dict[str, Any], k: int,
     tokens from ``x``, runs its expert's two products (operands in ``x``'s
     dtype, float32 accumulation, the pre-activation rounded to ``x``'s
     dtype) and adds its rows, scaled by their routing weights, into an
-    (N, D) float32 accumulator. So the index arrays of the plan are sized
-    for all N k pairs, and every D- or F-wide array by N or by one tile:
-    the layer costs what was routed here. What an absent expert would add is
-    left out, and nothing stands in for the chips that hold it or for their
-    exchange.
+    (N, D) float32 accumulator (``_add_rows``: by DMA, the kernel
+    ``mx_moe_combine``, where D is a multiple of 128). So the index arrays
+    of the plan are sized for all N k pairs, and every D- or F-wide array
+    by N or by one tile: the layer costs what was routed here. What an
+    absent expert would add is left out, and nothing stands in for the chips
+    that hold it or for their exchange.
 
     x: (B, T, D) -> (y (B, T, D), stats) with ``stats["load"]`` the tokens
     routed to each of ALL experts (int32), ``stats["tokens_here"]`` the

@@ -333,6 +333,48 @@ def test_expert_layer_compiles_for_v5e_with_no_array_of_all_pairs(one_chip):
     assert max(sizes) < n * k * f // 2
 
 
+def test_expert_layer_at_mimo_widths_combines_in_place_by_dma_for_v5e(
+        one_chip, monkeypatch):
+    """MiMo-V2.5's expert layer (32,768 tokens, 16 of 256 SwiGLU experts
+    2,048 wide held, eight a token, hidden 4,096) as the scoring cell runs
+    it, compiled for a v5e: the kernel ``mx_moe_zeros`` makes the (N, 1, D)
+    float32 accumulator, the routed loop's body adds a tile's rows into it
+    with the kernel ``mx_moe_combine``, in place: nothing in the body makes
+    another array of its size (a relayout from (N, D) and back, or a copy,
+    is 512 MB a tile). (A tracer lowers for the CPU: the kernels are told
+    they are on the chip.)"""
+    import re
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(pk, "_interpret_for", lambda x: False)
+    n, d, f, router, k, held = 32768, 4096, 2048, 256, 8, 16
+    bf = jnp.bfloat16
+
+    def sds(*shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, gate, w_in, w_out):
+        params = {"gate": gate, "bias": jnp.zeros((router,), jnp.float32),
+                  "w_in": w_in, "w_out": w_out}
+        return moe.dropless_moe_ffn(x, params, k, tuple(range(held)))[0]
+
+    compiled = jax.jit(layer).lower(
+        sds(1, n, d), sds(d, router, dtype=jnp.float32),
+        sds(held, d, 2 * f), sds(held, f, d)).compile()
+    text = compiled.as_text()
+    assert _n_kernels(compiled) == 2 and "mx_moe_zeros" in text
+    loop = next(line for line in text.splitlines() if " while(" in line
+                and re.search(rf"f32\[{n},(?:1,)?{d}\]", line))
+    body_name = re.search(r"body=%([\w.\-]+)", loop).group(1)
+    body = text.split(f"\n%{body_name} ", 1)[1].split("\n}\n", 1)[0]
+    made = re.findall(rf"%\S+ = f32\[{n},(?:1,)?{d}\]\S* ([\w\-]+)\(", body)
+    assert sorted(made) == ["custom-call", "get-tuple-element"]
+    assert 'custom_call_target="tpu_custom_call"' in body
+    assert "mx_moe_combine" in body
+    # the accumulator (512 MB) and the layer's small arrays: no second one
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * n * d * 4
+
+
 @pytest.mark.parametrize("n_tiles,n_f32", [(1, 2), (2, 2), (3, 4), (5, 3)])
 @pytest.mark.parametrize("c,itemsize", [(64, 2), (1024, 2), (2048, 2),
                                         (256, 4)])

@@ -198,15 +198,16 @@ def test_causal_prefix_property():
 D, F, E, K = 32, 24, 16, 4
 
 
-def _layer_params(held=None, seed=1):
-    p = moe.init_dropless_moe_params(jax.random.PRNGKey(seed), D, F, E, held)
+def _layer_params(held=None, seed=1, d=D):
+    p = moe.init_dropless_moe_params(jax.random.PRNGKey(seed), d, F, E, held)
     return {k: v * 8 if k != "bias" else v for k, v in p.items()}
 
 
 def _plain_layer(x, p, held):
     config = {"experts_held": held, "num_experts_per_tok": K,
               "routed_scaling_factor": 1.8}
-    return reference.moe(x.reshape(-1, D), p, config).reshape(x.shape)
+    return reference.moe(x.reshape(-1, x.shape[-1]), p, config).reshape(
+        x.shape)
 
 
 def test_routing_drops_nothing_under_a_biased_router():
@@ -281,11 +282,12 @@ def test_expert_layer_gradients(held):
 # tile. N k = 296 is no multiple of 16; a bias of 5 sends every token to an
 # expert, one of -5 none.
 ROUTED_CASES = {
-    "all_held": (None, {}, 40, 8),
-    "subset": ((0, 1, 2, 3), {}, 40, 8),
-    "ragged_tiles": ((5, 9, 12), {}, 37, 16),
-    "all_to_one_held_expert": ((2, 3), {3: 5.0}, 40, 8),
-    "none_chosen": ((5, 9), {5: -5.0, 9: -5.0}, 40, 8),
+    "all_held": (None, {}, 40, 8, D),
+    "subset": ((0, 1, 2, 3), {}, 40, 8, D),
+    "ragged_tiles": ((5, 9, 12), {}, 37, 16, D),
+    "all_to_one_held_expert": ((2, 3), {3: 5.0}, 40, 8, D),
+    "none_chosen": ((5, 9), {5: -5.0, 9: -5.0}, 40, 8, D),
+    "kernel_combine": ((0, 1, 2, 3), {}, 37, 16, 128),
 }
 
 
@@ -294,10 +296,10 @@ def test_routed_loop_is_the_plain_masked_sum(case):
     """Output, counters and every gradient of the layer against the plain
     masked sum, whatever the routing sends here: everything, a share, rows
     that fill no whole tile, every token on one expert, nothing."""
-    held, bias, t, tile = ROUTED_CASES[case]
+    held, bias, t, tile, d = ROUTED_CASES[case]
     ids = held or tuple(range(E))
-    x = jax.random.normal(jax.random.PRNGKey(6), (2, t, D))
-    p = _layer_params(held)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, t, d))
+    p = _layer_params(held, d=d)
     for e, b in bias.items():
         p["bias"] = p["bias"].at[e].set(b)
 
@@ -311,6 +313,11 @@ def test_routed_loop_is_the_plain_masked_sum(case):
 
     (_, (y, stats)), got = jax.value_and_grad(ours, (0, 1), has_aux=True)(x, p)
     (_, want_y), want = jax.value_and_grad(plain, (0, 1), has_aux=True)(x, p)
+    # rows of whole lanes: the kernel adds them, in the forward and the
+    # backward loop; else XLA's scatter, in neither
+    program = str(jax.make_jaxpr(jax.value_and_grad(
+        ours, (0, 1), has_aux=True))(x, p))
+    assert program.count("mx_moe_combine") == (2 if d % 128 == 0 else 0)
     np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
     here, tiles = int(stats["tokens_here"]), int(stats["tiles_run"])
     assert here == int(stats["load"][np.asarray(ids)].sum())

@@ -460,8 +460,8 @@ def test_causal_kernels_read_a_kv_row_for_its_whole_group(kv_rows, rep):
 D, F, FS, E, K = 32, 24, 40, 128, 6
 
 
-def _layer_params(held=None, seed=1, experts=E):
-    p = moe.init_dropless_moe_params(jax.random.PRNGKey(seed), D, F, experts,
+def _layer_params(held=None, seed=1, experts=E, d=D):
+    p = moe.init_dropless_moe_params(jax.random.PRNGKey(seed), d, F, experts,
                                      held, activation="relu2", shared_ff=FS)
     p = {k: v * 8 if k != "bias" else v for k, v in p.items()}
     p["bias"] = jax.random.normal(jax.random.PRNGKey(seed + 1),
@@ -472,7 +472,8 @@ def _layer_params(held=None, seed=1, experts=E):
 def _plain_layer(x, p, held, k=K):
     config = {"experts_held": held, "num_experts_per_tok": k,
               "routed_scaling_factor": 2.5}
-    return reference.moe(x.reshape(-1, D), p, config).reshape(x.shape)
+    return reference.moe(x.reshape(-1, x.shape[-1]), p, config).reshape(
+        x.shape)
 
 
 def _ours(x, p, held, k=K):
@@ -549,11 +550,12 @@ def test_eight_of_128_held_leaves_out_exactly_the_absent_terms():
 # a 16-wide router choosing four: held experts, selection bias by expert,
 # tokens a sequence, tile.
 ROUTED_CASES = {
-    "all_held": (None, {}, 40, 8),
-    "subset": ((0, 1, 2, 3), {}, 40, 8),
-    "ragged_tiles": ((5, 9, 12), {}, 37, 16),
-    "all_to_one_held_expert": ((2, 3), {3: 5.0}, 40, 8),
-    "none_chosen": ((5, 9), {5: -5.0, 9: -5.0}, 40, 8),
+    "all_held": (None, {}, 40, 8, D),
+    "subset": ((0, 1, 2, 3), {}, 40, 8, D),
+    "ragged_tiles": ((5, 9, 12), {}, 37, 16, D),
+    "all_to_one_held_expert": ((2, 3), {3: 5.0}, 40, 8, D),
+    "none_chosen": ((5, 9), {5: -5.0, 9: -5.0}, 40, 8, D),
+    "kernel_combine": ((0, 1, 2, 3), {}, 37, 16, 128),
 }
 
 
@@ -563,11 +565,11 @@ def test_relu2_routed_loop_is_the_plain_masked_sum(case):
     experts: output, counters and every gradient against the plain masked
     sum for everything held, a share, rows that fill no whole tile, every
     token on one expert, and no token here."""
-    held, bias, t, tile = ROUTED_CASES[case]
+    held, bias, t, tile, d = ROUTED_CASES[case]
     experts, k = 16, 4
     ids = held or tuple(range(experts))
-    x = jax.random.normal(jax.random.PRNGKey(6), (2, t, D))
-    p = _layer_params(held, experts=experts)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, t, d))
+    p = _layer_params(held, experts=experts, d=d)
     for e, b in bias.items():
         p["bias"] = p["bias"].at[e].set(b)
 
@@ -582,6 +584,11 @@ def test_relu2_routed_loop_is_the_plain_masked_sum(case):
 
     (_, (y, stats)), got = jax.value_and_grad(ours, (0, 1), has_aux=True)(x, p)
     (_, want_y), want = jax.value_and_grad(plain, (0, 1), has_aux=True)(x, p)
+    # rows of whole lanes: the kernel adds them, in the forward and the
+    # backward loop; else XLA's scatter, in neither
+    program = str(jax.make_jaxpr(jax.value_and_grad(
+        ours, (0, 1), has_aux=True))(x, p))
+    assert program.count("mx_moe_combine") == (2 if d % 128 == 0 else 0)
     np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-4)
     here, tiles = int(stats["tokens_here"]), int(stats["tiles_run"])
     assert here == int(stats["load"][np.asarray(ids)].sum())
